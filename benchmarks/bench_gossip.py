@@ -25,7 +25,8 @@ round on this host":
   (``pallas_packed`` — the packed-xla oracle on this host), and sparse
   neighbor-gather (``sparse_packed``).  Each row also reports achieved
   HBM bandwidth (the epilogue moves 5·n·D·4 bytes: read Δ, θ, c; write
-  θ', c') as a fraction of ``benchmarks.roofline.HBM_BW``.
+  θ', c') and, on a TPU, that as a fraction of the chip's HBM peak
+  (``benchmarks.roofline.PEAKS``; a CPU run has no roofline share).
   ``pallas_packed_interpret`` — the actual Pallas kernel through the
   interpreter — is a *parity/smoke* row only: it validates the kernel
   against the oracle but its wall time measures the interpreter, so it
@@ -59,7 +60,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from benchmarks.roofline import HBM_BW
+from benchmarks import roofline
 from repro.configs.base import AlgorithmConfig
 from repro.core import mixing as mixing_lib
 from repro.core import objectives, packing, topology
@@ -236,6 +237,9 @@ def collective_counts_child() -> None:
 
 def _collectives_via_subprocess() -> dict:
     env = dict(os.environ)
+    # CPU-only: the count needs 4 fake devices, and a child that reached for
+    # the accelerator would contend with this process for it
+    env["JAX_PLATFORMS"] = "cpu"
     env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
     env["PYTHONPATH"] = (os.path.join(REPO, "src")
                          + os.pathsep + env.get("PYTHONPATH", "")).rstrip(os.pathsep)
@@ -265,17 +269,22 @@ def run(csv=print, smoke: bool = False) -> dict:
     if not smoke:
         _autotune_block_d(w, dx, x, cx, csv, results)
 
+    # a roofline share exists only on a chip with published peaks
+    dev = jax.devices()[0]
+    hbm = (roofline.peaks(dev.device_kind)["hbm_bytes_per_s"]
+           if dev.platform == "tpu" else None)
     for name, builder in EPILOGUES.items():
         reps = 2 if smoke else 20
         ms = _time_ms(jax.jit(builder(w)), (dx, x, cx), reps)
         gbs = epilogue_bytes / (ms / 1e3) / 1e9
-        frac = gbs / (HBM_BW / 1e9)
-        csv(f"gossip,impl={name},epilogue_ms={ms:.2f},gbs={gbs:.1f},"
-            f"hbm_frac={frac:.3f},n={N_CLIENTS},"
-            f"leaves={results['leaves']},packed_D={spec.dim}")
-        results[name] = {"epilogue_ms": round(ms, 3),
-                         "achieved_gbs": round(gbs, 2),
-                         "hbm_frac": round(frac, 4)}
+        row = {"epilogue_ms": round(ms, 3), "achieved_gbs": round(gbs, 2)}
+        if hbm is not None:
+            row["hbm_frac"] = round(gbs / (hbm / 1e9), 4)
+        share = (f"hbm_frac={row['hbm_frac']:.3f},"
+                 if "hbm_frac" in row else "")
+        csv(f"gossip,impl={name},epilogue_ms={ms:.2f},gbs={gbs:.1f},{share}"
+            f"n={N_CLIENTS},leaves={results['leaves']},packed_D={spec.dim}")
+        results[name] = row
 
     # Pallas-kernel parity (interpret mode): validation, never a speed row —
     # the interpreter's wall time says nothing about the compiled kernel.

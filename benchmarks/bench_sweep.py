@@ -25,7 +25,9 @@ bill its one-time compile to whichever path ran first.
 
 A third comparison proves the persistent compile cache
 (``repro.sweep.cache``): the same batched cell runs in two fresh
-subprocesses sharing one cache directory — ``cold_cache`` pays the real
+subprocesses sharing one cache directory (``results/.bench_sweep_cache``,
+emptied first; jax's layer stays at ``$JAX_COMPILATION_CACHE_DIR`` where
+that is set) — ``cold_cache`` pays the real
 compiles and populates the cache, ``warm_cache`` deserializes executables
 from disk.  The rows record the warm run's compile fraction (the ISSUE-10
 acceptance bar: < 10% of wall) and that its per-point results are
@@ -38,8 +40,8 @@ from __future__ import annotations
 import json
 import os
 import subprocess
+import shutil
 import sys
-import tempfile
 import time
 
 from repro.sweep import grid, run as sweep_run
@@ -77,35 +79,44 @@ def _cache_child(cache_dir: str) -> dict:
     }
 
 
+#: The cold/warm pair's cache root: fixed, so the warm half finds what the
+#: cold half wrote, and cleared before the cold half.
+CACHE_DIR = os.path.join(
+    os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
+    "results", ".bench_sweep_cache")
+
+
 def _cache_pair(csv) -> dict:
     """Run the cell in two fresh subprocesses sharing one cache directory
-    and report cold-vs-warm timing + bit-identity."""
+    and report cold-vs-warm timing + bit-identity.  Called before this
+    process touches a JAX backend, so on an accelerator the children are
+    the only holders of the chip."""
     root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
     env = dict(os.environ)
     env["PYTHONPATH"] = os.pathsep.join(
         p for p in (os.path.join(root, "src"), root,
                     env.get("PYTHONPATH", "")) if p)
     env.pop("REPRO_COMPILE_CACHE", None)  # the child gets an explicit dir
+    shutil.rmtree(CACHE_DIR, ignore_errors=True)
     out: dict = {}
-    with tempfile.TemporaryDirectory(prefix="bench_sweep_cache_") as cdir:
-        for mode in ("cold_cache", "warm_cache"):
-            proc = subprocess.run(
-                [sys.executable, "-m", "benchmarks.bench_sweep",
-                 "--cache-child", cdir],
-                capture_output=True, text=True, cwd=root, env=env)
-            if proc.returncode != 0:
-                raise RuntimeError(
-                    f"cache child ({mode}) failed:\n{proc.stderr}")
-            rec = json.loads(proc.stdout)
-            t = rec["timing"]
-            frac = (t["compile_s"] / t["wall_s"]) if t["wall_s"] else 0.0
-            rec["compile_frac"] = round(frac, 3)
-            csv(f"sweep,mode={mode},B={B},rounds={ROUNDS},"
-                f"wall_s={t['wall_s']},compile_s={t['compile_s']},"
-                f"run_s={t['run_s']},compile_frac={rec['compile_frac']},"
-                f"cache_hits={int(rec['stats']['hits'])},"
-                f"cache_misses={int(rec['stats']['misses'])}")
-            out[mode] = rec
+    for mode in ("cold_cache", "warm_cache"):
+        proc = subprocess.run(
+            [sys.executable, "-m", "benchmarks.bench_sweep",
+             "--cache-child", CACHE_DIR],
+            capture_output=True, text=True, cwd=root, env=env)
+        if proc.returncode != 0:
+            raise RuntimeError(
+                f"cache child ({mode}) failed:\n{proc.stderr}")
+        rec = json.loads(proc.stdout)
+        t = rec["timing"]
+        frac = (t["compile_s"] / t["wall_s"]) if t["wall_s"] else 0.0
+        rec["compile_frac"] = round(frac, 3)
+        csv(f"sweep,mode={mode},B={B},rounds={ROUNDS},"
+            f"wall_s={t['wall_s']},compile_s={t['compile_s']},"
+            f"run_s={t['run_s']},compile_frac={rec['compile_frac']},"
+            f"cache_hits={int(rec['stats']['hits'])},"
+            f"cache_misses={int(rec['stats']['misses'])}")
+        out[mode] = rec
     identical = out["cold_cache"]["results"] == out["warm_cache"]["results"]
     out["bit_identical"] = identical
     out["warm_compile_frac"] = out["warm_cache"]["compile_frac"]
@@ -117,6 +128,9 @@ def _cache_pair(csv) -> dict:
 
 
 def run(csv=print) -> dict:
+    # the cold/warm children first: after this process has run a cell it
+    # holds the backend (on an accelerator, the chip)
+    cache_pair = _cache_pair(csv)
     [cell] = SPEC.cells()
     sweep_run.prepare_trajectory(cell.points[0])  # warm the shared preparer
 
@@ -154,7 +168,6 @@ def run(csv=print) -> dict:
     speedup_run = batched_rps / seq_rps
     csv(f"sweep,summary,speedup_traj_per_s={speedup:.2f}x,"
         f"speedup_run_only={speedup_run:.2f}x")
-    cache_pair = _cache_pair(csv)
     return {
         "B": B, "rounds": ROUNDS, "eval_every": EVAL_EVERY,
         "cache": cache_pair,

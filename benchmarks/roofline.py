@@ -1,11 +1,12 @@
 """Roofline report: derive the three per-device time terms for every
 (arch x shape x mesh) entry of the dry-run JSONL.
 
-  compute_s    = parsed dot FLOPs / 197e12           (bf16 MXU peak, v5e)
-  memory_s     = parsed HBM traffic / 819e9          (HBM bandwidth)
-  collective_s = parsed collective bytes / 50e9      (per-link ICI proxy)
+  compute_s    = parsed dot FLOPs / peak bf16 FLOP/s
+  memory_s     = parsed HBM traffic / HBM bandwidth
+  collective_s = parsed collective bytes / one ICI link's bandwidth (proxy)
 
-FLOPs/traffic/collective bytes come from the loop-aware HLO parse
+with the peaks of the target chip from :data:`PEAKS`.  FLOPs/traffic/
+collective bytes come from the loop-aware HLO parse
 (repro.analysis.hlo_cost) — XLA's own cost_analysis counts while bodies once.
 MODEL_FLOPS uses 6·N·D (train, N=active params) / 2·N·D (inference) per
 device; the ratio against parsed FLOPs measures remat/dispatch overhead.
@@ -13,15 +14,37 @@ device; the ratio against parsed FLOPs measures remat/dispatch overhead.
 from __future__ import annotations
 
 import json
+import os
 from typing import Dict, List, Optional
 
 from repro.configs import registry
 from repro.configs.shapes import SHAPES
 from repro.launch import mesh as mesh_lib
 
-PEAK_FLOPS = 197e12     # bf16 / chip
-HBM_BW = 819e9          # bytes/s / chip
-ICI_BW = 50e9           # bytes/s / link (proxy: all parsed bytes over 1 link)
+#: Published peaks per chip, keyed by ``jax.Device.device_kind``.
+#: Source: Google Cloud documentation, "TPU v5e" — 197 TFLOP/s bf16, 16 GB
+#: of HBM at 819 GB/s, 1,600 Gbit/s of interconnect over 4 links (50 GB/s
+#: per link).
+PEAKS = {
+    "TPU v5 lite": {"bf16_flops": 197e12, "hbm_bytes_per_s": 819e9,
+                    "ici_link_bytes_per_s": 50e9},
+}
+
+#: The chip the dry-run meshes describe.
+DRYRUN_DEVICE_KIND = "TPU v5 lite"
+
+DEFAULT_DRYRUN = os.path.join(
+    os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
+    "results", "dryrun.jsonl")
+
+
+def peaks(device_kind: str) -> Dict[str, float]:
+    """The peaks of ``device_kind``; a device not in :data:`PEAKS` raises."""
+    try:
+        return PEAKS[device_kind]
+    except KeyError:
+        raise ValueError(f"no published peaks for device kind "
+                         f"{device_kind!r}; known: {sorted(PEAKS)}") from None
 
 
 def model_flops_per_device(arch: str, shape_name: str, mesh_kind: str) -> float:
@@ -47,13 +70,15 @@ def load(path: str) -> List[Dict]:
     return [json.loads(l) for l in open(path) if l.strip()]
 
 
-def analyze_entry(r: Dict) -> Optional[Dict]:
+def analyze_entry(r: Dict, device_kind: str = DRYRUN_DEVICE_KIND
+                  ) -> Optional[Dict]:
     if "error" in r:
         return None
+    pk = peaks(device_kind)
     coll = sum(v for k, v in r["collectives"].items() if not k.startswith("n_"))
-    compute_s = r["cost"]["dot_flops"] / PEAK_FLOPS
-    memory_s = r["cost"]["traffic_bytes"] / HBM_BW
-    collective_s = coll / ICI_BW
+    compute_s = r["cost"]["dot_flops"] / pk["bf16_flops"]
+    memory_s = r["cost"]["traffic_bytes"] / pk["hbm_bytes_per_s"]
+    collective_s = coll / pk["ici_link_bytes_per_s"]
     terms = {"compute": compute_s, "memory": memory_s, "collective": collective_s}
     dominant = max(terms, key=terms.get)
     mf = model_flops_per_device(r["arch"], r["shape"], r["mesh"])
@@ -94,7 +119,7 @@ def table(path: str, meshes=("single",)) -> str:
     return "\n".join(out)
 
 
-def run(csv=print, path: str = "/root/repo/results/dryrun.jsonl"):
+def run(csv=print, path: str = DEFAULT_DRYRUN):
     rows = [analyze_entry(r) for r in load(path)]
     rows = [r for r in rows if r]
     for r in rows:
@@ -108,5 +133,5 @@ def run(csv=print, path: str = "/root/repo/results/dryrun.jsonl"):
 if __name__ == "__main__":
     import sys
 
-    path = sys.argv[1] if len(sys.argv) > 1 else "/root/repo/results/dryrun.jsonl"
+    path = sys.argv[1] if len(sys.argv) > 1 else DEFAULT_DRYRUN
     print(table(path, meshes=("single", "multi")))

@@ -8,10 +8,14 @@ Default is a CPU-sized model (~9M params) for a few hundred rounds; pass
 """
 import argparse
 import dataclasses
+import os
 
 from repro.configs.base import ModelConfig
 from repro.configs.registry import ARCHS
 from repro.launch import train as train_lib
+
+RESULTS = os.path.join(os.path.dirname(os.path.dirname(
+    os.path.abspath(__file__))), "results")
 
 SMALL = ModelConfig(
     name="robust-lm-9m", arch_type="dense", num_layers=4, d_model=256,
@@ -44,14 +48,14 @@ def main() -> None:
         # repro.engine chunked execution: one compiled scan per 10 rounds,
         # checkpoints land on chunk boundaries
         engine="scan", chunk=10, mesh="host",
-        out="/root/repo/results/robust_lm.json")
+        out=os.path.join(RESULTS, "robust_lm.json"))
     result = train_lib.train(ns)
     import json
-    import os
 
-    os.makedirs("/root/repo/results", exist_ok=True)
+    os.makedirs(RESULTS, exist_ok=True)
     with open(ns.out, "w") as f:
-        json.dump(result, f, indent=1)
+        json.dump({k: v for k, v in result.items() if k != "state"}, f,
+                  indent=1)
     print(f"[robust_lm] wrote {ns.out}")
 
 

@@ -50,10 +50,13 @@ def mix_dense(tree: Any, w, gossip_dtype=None) -> Any:
         orig = x.dtype
         xc = x.astype(gossip_dtype) if gossip_dtype is not None else x
         # einsum in the gossip dtype (keeps the all-gathered operand narrow),
-        # accumulate in f32.
+        # accumulate in f32.  HIGHEST: at its default precision a TPU rounds
+        # f32 operands to bf16, which would round every parameter to bf16
+        # each round and erase the local steps' smaller updates.
         mixed = jnp.einsum(
             "ij,j...->i...", w.astype(xc.dtype), xc,
             preferred_element_type=jnp.float32,
+            precision=jax.lax.Precision.HIGHEST,
         )
         return mixed.astype(orig)
 

@@ -290,7 +290,8 @@ def sparse_mix(sp: SparseTopology, buf, gossip_dtype=None) -> jnp.ndarray:
     gathered = jnp.take(bg, sp.neighbor_idx, axis=0)      # (n, max_deg, D)
     mixed = (swg.astype(jnp.float32)[:, None] * bg.astype(jnp.float32)
              + jnp.einsum("nm,nmd->nd", nwg, gathered,
-                          preferred_element_type=jnp.float32))
+                          preferred_element_type=jnp.float32,
+                          precision=jax.lax.Precision.HIGHEST))
     return mixed.astype(out_dtype)
 
 

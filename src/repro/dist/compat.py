@@ -1,9 +1,9 @@
-"""jax version compatibility shims for the mesh/sharding layer.
+"""Mesh construction for the launch and sharding layers.
 
-The launch code targets the newest mesh API (``jax.set_mesh``, explicit
-``AxisType``) but must also run on the jax 0.4.x wheels baked into the CPU
-test containers, where neither exists.  All version probing lives here so
-``repro.launch`` and ``repro.dist.sharding`` can stay branch-free.
+Every mesh this repo builds has all axes ``Auto`` (GSPMD propagates
+shardings freely) and is entered with ``jax.set_mesh``; these helpers keep
+those two choices in one place for ``repro.launch`` and
+``repro.dist.sharding``.
 """
 from __future__ import annotations
 
@@ -11,28 +11,19 @@ from typing import Mapping, Sequence
 
 import jax
 import numpy as np
-from jax.sharding import Mesh
-
-try:  # jax >= 0.5: explicit axis types (Auto lets GSPMD propagate freely)
-    from jax.sharding import AxisType
-
-    _AUTO = AxisType.Auto
-except ImportError:  # jax 0.4.x: every axis is implicitly auto
-    AxisType = None
-    _AUTO = None
+from jax.sharding import AbstractMesh, AxisType, Mesh
 
 
 def make_mesh(axis_shapes: Sequence[int], axis_names: Sequence[str]) -> Mesh:
-    """``jax.make_mesh`` with all axes ``Auto``, on any supported jax.
+    """``jax.make_mesh`` with all axes ``Auto``.
 
     Used for the production mesh (``repro.launch.mesh``) and for the
     CPU-backed fake meshes in tests/smoke runs (``XLA_FLAGS=
     --xla_force_host_platform_device_count=N`` before first jax init).
     """
-    if _AUTO is not None:
-        return jax.make_mesh(tuple(axis_shapes), tuple(axis_names),
-                             axis_types=(_AUTO,) * len(tuple(axis_names)))
-    return jax.make_mesh(tuple(axis_shapes), tuple(axis_names))
+    names = tuple(axis_names)
+    return jax.make_mesh(tuple(axis_shapes), names,
+                         axis_types=(AxisType.Auto,) * len(names))
 
 
 def mesh_of(devices: np.ndarray, axis_names: Sequence[str]) -> Mesh:
@@ -44,36 +35,21 @@ def mesh_of(devices: np.ndarray, axis_names: Sequence[str]) -> Mesh:
     ``repro.launch.mesh.make_decentralized_mesh``).
     """
     names = tuple(axis_names)
-    if _AUTO is not None:
-        return Mesh(devices, names, axis_types=(_AUTO,) * len(names))
-    return Mesh(devices, names)
+    return Mesh(devices, names, axis_types=(AxisType.Auto,) * len(names))
 
 
 def use_mesh(mesh: Mesh):
-    """Context manager entering ``mesh`` (``jax.set_mesh`` when available).
-
-    Inside the context, jit tracing and sharding-constraint resolution treat
-    ``mesh`` as the ambient mesh.  On jax 0.4.x a ``Mesh`` is itself a
-    context manager with the same meaning, so we return it directly.
-    """
-    if hasattr(jax, "set_mesh"):
-        return jax.set_mesh(mesh)
-    return mesh
+    """Context manager entering ``mesh`` (``jax.set_mesh``): inside it, jit
+    tracing and sharding-constraint resolution treat ``mesh`` as the
+    ambient mesh."""
+    return jax.set_mesh(mesh)
 
 
-def abstract_mesh(axis_sizes: Mapping[str, int]):
+def abstract_mesh(axis_sizes: Mapping[str, int]) -> AbstractMesh:
     """Device-free :class:`jax.sharding.AbstractMesh` for spec-level work.
 
     Lets tests and planners build ``NamedSharding``\\s for meshes larger than
     the local device count (e.g. asserting the clients-axis placement of
     :func:`repro.dist.sharding.params_shardings` on a 1-CPU container).
-    Handles the two AbstractMesh constructor generations.
     """
-    from jax.sharding import AbstractMesh
-
-    items = tuple(axis_sizes.items())
-    try:  # jax 0.4.x: AbstractMesh(((name, size), ...))
-        return AbstractMesh(items)
-    except TypeError:  # jax >= 0.5: AbstractMesh(sizes, names)
-        return AbstractMesh(tuple(s for _, s in items),
-                            tuple(n for n, _ in items))
+    return AbstractMesh(tuple(axis_sizes.values()), tuple(axis_sizes))

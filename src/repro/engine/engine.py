@@ -151,11 +151,11 @@ def timed_chunk_builder(build_chunk: Callable[[int], Any], *,
     cache module docstring); callers that cannot enumerate those must not
     pass a cache.
 
-    When the built function has no ``lower`` (a plain Python callable) or
-    lowering fails (exotic jit wrappers), the whole first call — compile
-    *and* its one execution — is attributed to ``compile_s``; for the
-    multi-second XLA programs this wrapper exists to time, the execution
-    share of that first call is noise.
+    When the built function has no ``lower`` (a plain Python callable), the
+    whole first call — compile *and* its one execution — is attributed to
+    ``compile_s``; for the multi-second XLA programs this wrapper exists to
+    time, the execution share of that first call is noise.  A failed
+    compile raises.
     """
     wrapped: Dict[int, Any] = {}
     stats = {"compile_s": 0.0}
@@ -176,15 +176,11 @@ def timed_chunk_builder(build_chunk: Callable[[int], Any], *,
                     holder.append(compiled)
                     return holder[0](*args)
                 t0 = time.perf_counter()
-                compiled = None
                 lower = getattr(fn, "lower", None)
                 if lower is not None:
-                    try:
-                        compiled = lower(*args).compile()
-                    except Exception:
-                        compiled = None
-                if compiled is not None:
-                    holder.append(compiled)
+                    # a compile error propagates: retrying inside a plain
+                    # call would only compile (and fail) a second time
+                    holder.append(lower(*args).compile())
                     stats["compile_s"] += time.perf_counter() - t0
                 else:
                     holder.append(fn)
@@ -314,6 +310,8 @@ def run(
             with telemetry.span("readback", round=r):
                 records = records_from_buffer(buf)
         if wall_clock:
+            # the stamp covers the chunk's device work, not just its enqueue
+            jax.block_until_ready(state)
             wall = time.perf_counter() - t0
             # only compilation incurred by THIS run: the builder (and its
             # stats) may be shared across runs, while t0 is per-run

@@ -1,4 +1,4 @@
-"""Whole-round fused Pallas kernel (TPU target, validated in interpret).
+"""Whole-round fused Pallas kernel (TPU target).
 
 ``kernels/gossip.py`` fuses only the round *epilogue*; every one of the K
 local SGDA steps still round-trips the client state through HBM, which is
@@ -35,9 +35,12 @@ W (see ``core.compression``).
 
 Memory: this kernel is grid-less — n is tiny (≤ a few hundred after the
 sparse path takes over) and the G z contraction binds the full dz axis, so
-every operand is a single VMEM block.  G is the big one: n·dz²·4 bytes
-(8 MB at n=8, dz=512); ``ops.fused_round`` asserts dz_pad ≤ 1024 to stay
-inside a TPU core's ~16 MB VMEM.
+every operand is a single VMEM block, inside Mosaic's default 16 MiB of
+scoped VMEM on TPU v5e.  G (n·dz²·4 bytes) is the big one, and the batched
+matvec's lane-padded right-hand side adds ~1 KiB per (client, dz) row:
+``ops.fused_round_vmem_bytes`` counts it all, and ``ops.fused_round``
+raises past the limit.  At n=8 and K=8 that admits dz ≤ 512 (G = 8 MiB);
+at dz = 640 the v5e compiler reports 17.3 MiB and refuses.
 
 ``gossip_dtype`` narrows only the W-matmul operands (the wire values), as
 in ``kernels/gossip.py``; Δ/q stay f32 inside the correction.
@@ -59,12 +62,16 @@ def _kernel(w_ref, z0_ref, c_ref, ef_ref, g_ref, h_ref, step_ref, etas_ref,
     z0 = z0_ref[...].astype(jnp.float32)            # (N, DZ)
     c = c_ref[...].astype(jnp.float32)              # (N, DZ)
     step = step_ref[...]                            # (N, DZ)  ±η_c ⊙ mask
-    g = g_ref[...]                                  # (N, DZ, DZ)
-    # batched matvec: grad[i] = G[i] @ z[i]
+    # batched matvec: grad[i] = G[i] @ z[i].  G (N, DZ, DZ) is read from its
+    # ref inside the loop: a value hoisted out of it would be a second
+    # VMEM copy of the largest operand.
     gdims = (((2,), (1,)), ((0,), (0,)))
 
+    # f32 operands contract at f32 precision (not the MXU's one bf16 pass)
+    exact = jax.lax.Precision.HIGHEST
+
     def body(k, z):
-        grad = jax.lax.dot_general(g, z, gdims,
+        grad = jax.lax.dot_general(g_ref[...], z, gdims, precision=exact,
                                    preferred_element_type=jnp.float32)
         return z - step * (grad + h_ref[k] + c)
 
@@ -89,8 +96,11 @@ def _kernel(w_ref, z0_ref, c_ref, ef_ref, g_ref, h_ref, step_ref, etas_ref,
         qg = q.astype(gossip_dtype)
         zg = z0.astype(gossip_dtype)
     wdims = (((1,), (0,)), ((), ()))
-    wq = jax.lax.dot_general(wg, qg, wdims, preferred_element_type=jnp.float32)
-    wz = jax.lax.dot_general(wg, zg, wdims, preferred_element_type=jnp.float32)
+    wprec = exact if gossip_dtype is None else None
+    wq = jax.lax.dot_general(wg, qg, wdims, precision=wprec,
+                             preferred_element_type=jnp.float32)
+    wz = jax.lax.dot_general(wg, zg, wdims, precision=wprec,
+                             preferred_element_type=jnp.float32)
     z_out_ref[...] = wz + etas_ref[...] * wq
     c_out_ref[...] = c + corr_ref[...] * (q - wq)
     e_out_ref[...] = e_new
@@ -98,7 +108,7 @@ def _kernel(w_ref, z0_ref, c_ref, ef_ref, g_ref, h_ref, step_ref, etas_ref,
 
 def fused_round_nd(w, z0, c, ef, g, h_steps, step, etas, corr, mask, *,
                    k_steps: int, compress=None, gossip_dtype=None,
-                   interpret: bool = True):
+                   interpret: bool):
     """w: (N, N); z0/c/ef/step/etas/corr/mask: (N, DZ) f32; g: (N, DZ, DZ);
     h_steps: (K, N, DZ).  N a sublane multiple, DZ a lane multiple (padding
     handled by ``ops.fused_round``).  Returns (z_new, c_new, ef_new) f32."""

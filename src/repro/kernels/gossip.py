@@ -1,4 +1,4 @@
-"""Fused gossip-epilogue Pallas kernel (TPU target, validated in interpret).
+"""Fused gossip-epilogue Pallas kernel (TPU target).
 
 One kernel pass over the packed ``(n, D)`` client state computes the whole
 round epilogue of Algorithm 1 (lines 7–11) for one variable:
@@ -49,15 +49,19 @@ def _kernel(s_ref, w_ref, delta_ref, theta_ref, c_ref, theta_out_ref,
         dg = delta_ref[...].astype(gossip_dtype)
         tg = theta_ref[...].astype(gossip_dtype)
     dims = (((1,), (0,)), ((), ()))
-    wd = jax.lax.dot_general(wg, dg, dims, preferred_element_type=jnp.float32)
-    wt = jax.lax.dot_general(wg, tg, dims, preferred_element_type=jnp.float32)
+    # f32 operands contract at f32 precision (not the MXU's one bf16 pass)
+    prec = jax.lax.Precision.HIGHEST if gossip_dtype is None else None
+    wd = jax.lax.dot_general(wg, dg, dims, precision=prec,
+                             preferred_element_type=jnp.float32)
+    wt = jax.lax.dot_general(wg, tg, dims, precision=prec,
+                             preferred_element_type=jnp.float32)
     theta_out_ref[...] = (wt + eta_s * wd).astype(theta_out_ref.dtype)
     c_out_ref[...] = (c_ref[...].astype(jnp.float32)
                       + corr_scale * (d32 - wd)).astype(c_out_ref.dtype)
 
 
 def fused_gossip_nd(w, delta, theta, c, scalars, *, block_d: int = 512,
-                    gossip_dtype=None, interpret: bool = True):
+                    gossip_dtype=None, interpret: bool):
     """w: (N, N); delta/theta/c: (N, D) with N a sublane multiple and D a
     ``block_d`` multiple (padding handled by ``ops.fused_gossip_round``);
     scalars: (2,) f32 = [η_s, corr_scale].  Returns (θ_new, c_new) f32."""

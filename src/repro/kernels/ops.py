@@ -2,10 +2,10 @@
 
 Callers use model-layout tensors ((B, S, H, D) attention, (B, S, H, P) SSD);
 these wrappers handle layout, GQA folding, block padding and the
-pallas/interpret/xla backend choice.  On this CPU container the kernels run
-in interpret mode for validation; ``backend="xla"`` routes to the pure-jnp
-oracle (what the dry-run lowers); on real TPU ``interpret=False`` compiles
-the kernels proper.
+pallas/interpret/xla backend choice, which every caller names: ``"pallas"``
+compiles the kernel for the TPU, ``"interpret"`` runs the same kernel in
+the Pallas interpreter (validation on CPU), and ``"xla"`` routes to the
+pure-jnp oracle (what the dry-run lowers).
 """
 from __future__ import annotations
 
@@ -34,8 +34,8 @@ def _pad_to(x, axis: int, mult: int):
 
 
 @partial(jax.jit, static_argnames=("causal", "window", "backend", "block_q", "block_k"))
-def flash_attention(q, k, v, *, causal: bool = True, window: int = 0,
-                    backend: str = "interpret", block_q: int = 128,
+def flash_attention(q, k, v, *, backend: str, causal: bool = True,
+                    window: int = 0, block_q: int = 128,
                     block_k: int = 128):
     """q: (B, Sq, H, D); k, v: (B, Sk, KV, D).  Returns (B, Sq, H, D)."""
     b, sq, h, d = q.shape
@@ -57,7 +57,7 @@ def flash_attention(q, k, v, *, causal: bool = True, window: int = 0,
 
 
 @partial(jax.jit, static_argnames=("chunk", "backend"))
-def ssd_scan(xdt, loga, bm, cm, *, chunk: int = 64, backend: str = "interpret"):
+def ssd_scan(xdt, loga, bm, cm, *, backend: str, chunk: int = 64):
     """xdt: (B, S, H, P); loga: (B, S, H); bm, cm: (B, S, N)."""
     b, s, h, p = xdt.shape
     xf = xdt.transpose(0, 2, 1, 3).reshape(b * h, s, p)
@@ -75,8 +75,8 @@ def ssd_scan(xdt, loga, bm, cm, *, chunk: int = 64, backend: str = "interpret"):
 
 
 @partial(jax.jit, static_argnames=("block_t", "block_v", "backend"))
-def fused_cross_entropy(hidden, weight, labels, *, block_t: int = 128,
-                        block_v: int = 512, backend: str = "interpret"):
+def fused_cross_entropy(hidden, weight, labels, *, backend: str,
+                        block_t: int = 128, block_v: int = 512):
     """Per-token NLL without materializing (N, V) logits.
     hidden: (N, d); weight: (V, d); labels: (N,) int32."""
     if backend == "xla":
@@ -169,7 +169,7 @@ _fused_gossip_jit_donate = jax.jit(
 
 
 def fused_gossip_round(w, delta, theta, c, eta_s, corr_scale, *,
-                       backend: str = "interpret", block_d=None,
+                       backend: str, block_d=None,
                        gossip_dtype=None, donate: bool = False):
     """Fused round epilogue over packed client state.
 
@@ -198,9 +198,26 @@ def fused_gossip_round(w, delta, theta, c, eta_s, corr_scale, *,
               block_d=blk, gossip_dtype=gossip_dtype)
 
 
+#: Mosaic's default scoped-VMEM limit on TPU v5e.  ``fused_round`` is
+#: grid-less, so every operand and temporary must fit in it at once.
+SCOPED_VMEM_BYTES = 16 * 2**20
+
+
+def fused_round_vmem_bytes(n_pad: int, dz_pad: int, k_steps: int) -> int:
+    """Scoped VMEM the compiled ``fused_round`` kernel needs (f32 words):
+    G once (n·dz²); the lane-padded right-hand side of the batched G·z
+    matvec (256 lanes per client row); the K offset rows of h, 7 input, 3
+    output and 3 temporary ``(n, dz)`` blocks; and W's lane-padded tile.
+    Fitted to what the v5e compiler reports (tests/test_tpu_compile.py
+    holds the guard to the compiler at n=8 on both sides of the bound)."""
+    return 4 * (n_pad * dz_pad * dz_pad
+                + (256 + k_steps + 13) * n_pad * dz_pad
+                + n_pad * max(128, n_pad))
+
+
 @partial(jax.jit, static_argnames=("backend", "compress", "gossip_dtype"))
 def fused_round(w, z0, c, ef, g_mat, h_steps, step, etas, corr, mask, *,
-                backend: str = "interpret", compress=None, gossip_dtype=None):
+                backend: str, compress=None, gossip_dtype=None):
     """Whole Algorithm-1 round (K affine local SGDA steps + gossip epilogue)
     in one kernel pass over the packed z = (x; y) state.
 
@@ -213,7 +230,9 @@ def fused_round(w, z0, c, ef, g_mat, h_steps, step, etas, corr, mask, *,
     gossip; ``ef`` is the carried residual (pass zeros when None — it flows
     through untouched).  The pallas/interpret path pads n → 8 and dz → 128
     with zeros (padded G rows/cols and masked rows contribute nothing) and
-    slices back; ``backend="xla"`` routes to ``ref.fused_round_ref``.
+    slices back; ``backend="xla"`` routes to ``ref.fused_round_ref``.  It
+    raises where the padded problem would not fit the scoped VMEM
+    (:func:`fused_round_vmem_bytes`).
     """
     gd = (None if gossip_dtype in (None, "float32")
           else jnp.dtype(gossip_dtype))
@@ -223,12 +242,15 @@ def fused_round(w, z0, c, ef, g_mat, h_steps, step, etas, corr, mask, *,
             compress=compress, gossip_dtype=gd)
     n, dz = z0.shape
     k_steps = h_steps.shape[0]
+    n_pad = -(-n // 8) * 8
     dz_pad = max(128, -(-dz // 128) * 128)
-    if dz_pad > 1024:
+    need = fused_round_vmem_bytes(n_pad, dz_pad, k_steps)
+    if need > SCOPED_VMEM_BYTES:
         raise ValueError(
-            f"fused_round holds G (n·dz²·4 bytes) in one VMEM block; "
-            f"dz_pad={dz_pad} > 1024 will not fit — use mixing_impl="
-            f"'pallas_packed' for larger problems")
+            f"fused_round holds the whole round in VMEM: n={n_pad}, "
+            f"dz={dz_pad}, K={k_steps} needs {need / 2**20:.1f} MiB of the "
+            f"{SCOPED_VMEM_BYTES / 2**20:.0f} MiB scoped limit — use "
+            f"mixing_impl='pallas_packed' for larger problems")
     wp, _ = _pad_to(jnp.asarray(w, jnp.float32), 0, 8)
     wp, _ = _pad_to(wp, 1, 8)
 
@@ -249,9 +271,26 @@ def fused_round(w, z0, c, ef, g_mat, h_steps, step, etas, corr, mask, *,
     return z_new[:n, :dz], c_new[:n, :dz], e_new[:n, :dz]
 
 
+#: Client rows per program of the neighbor-gather kernel.
+SPARSE_BLOCK_N = 256
+
+
+def sparse_block_d(n_pad: int, d: int, block_d: int) -> int:
+    """The D tile of the neighbor-gather kernel: ``block_d`` clamped to the
+    padded D and to what fits the scoped VMEM, where the two gather sources
+    (Δ and θ over the whole client axis, double-buffered) dominate —
+    16·n·BD bytes.  Raises where even one 128-lane tile does not fit."""
+    fit = SCOPED_VMEM_BYTES * 3 // 4 // (16 * n_pad) // 128 * 128
+    if fit < 128:
+        raise ValueError(
+            f"sparse_gossip: n={n_pad} clients do not fit one 128-lane "
+            f"gather tile in {SCOPED_VMEM_BYTES / 2**20:.0f} MiB of VMEM")
+    return min(block_d, fit, max(128, -(-d // 128) * 128))
+
+
 @partial(jax.jit, static_argnames=("backend", "block_d", "gossip_dtype"))
 def sparse_gossip_round(neighbor_idx, neighbor_w, self_w, delta, theta, c,
-                        eta_s, corr_scale, *, backend: str = "interpret",
+                        eta_s, corr_scale, *, backend: str,
                         block_d: int = 512, gossip_dtype=None):
     """Fused round epilogue over packed client state, sparse W.
 
@@ -264,9 +303,10 @@ def sparse_gossip_round(neighbor_idx, neighbor_w, self_w, delta, theta, c,
     pytree so the kernels package stays free of core imports.
 
     The pallas/interpret path prepends the augmented self slot (slot 0 =
-    own row at weight w_ii), pads n to the f32 sublane multiple (padded
-    rows gather row 0 at weight 0.0 — contribute nothing) and D to the
-    block multiple, and slices back to (n, D).
+    own row at weight w_ii), narrows the weights to ``gossip_dtype``, pads
+    n to the client-block multiple (padded rows gather row 0 at weight 0.0
+    — contribute nothing) and D to the block multiple, and slices back to
+    (n, D).
     """
     gd = (None if gossip_dtype in (None, "float32")
           else jnp.dtype(gossip_dtype))
@@ -279,27 +319,28 @@ def sparse_gossip_round(neighbor_idx, neighbor_w, self_w, delta, theta, c,
     n, d = delta.shape
     own = jnp.arange(n, dtype=jnp.int32)[:, None]
     aidx = jnp.concatenate([own, neighbor_idx.astype(jnp.int32)], axis=1)
-    aw = jnp.concatenate(
-        [self_w.astype(jnp.float32)[:, None],
-         neighbor_w.astype(jnp.float32)], axis=1)
-    aidx, _ = _pad_to(aidx, 0, 8)
-    aw, _ = _pad_to(aw, 0, 8)
-    blk = min(block_d, max(128, -(-d // 128) * 128))
+    aw = jnp.concatenate([self_w.astype(jnp.float32)[:, None],
+                          neighbor_w.astype(jnp.float32)], axis=1)
+    aw = (aw if gd is None else aw.astype(gd)).astype(jnp.float32)
+    block_n = min(SPARSE_BLOCK_N, -(-n // 8) * 8)
+    aidx, _ = _pad_to(aidx, 0, block_n)
+    aw, _ = _pad_to(aw, 0, block_n)
+    blk = sparse_block_d(aidx.shape[0], d, block_d)
 
     def prep(x):
-        x, _ = _pad_to(x.astype(jnp.float32), 0, 8)
+        x, _ = _pad_to(x.astype(jnp.float32), 0, block_n)
         x, _ = _pad_to(x, 1, blk)
         return x
 
     scalars = jnp.stack([eta_s, corr_scale])
     theta_new, c_new = ngossip_lib.sparse_gossip_nd(
         aidx, aw, prep(delta), prep(theta), prep(c), scalars, block_d=blk,
-        gossip_dtype=gd, interpret=(backend == "interpret"))
+        block_n=block_n, gossip_dtype=gd, interpret=(backend == "interpret"))
     return theta_new[:n, :d], c_new[:n, :d]
 
 
 @partial(jax.jit, static_argnames=("chunk", "backend"))
-def rglru_scan(a, u, *, chunk: int = 256, backend: str = "interpret"):
+def rglru_scan(a, u, *, backend: str, chunk: int = 256):
     """a, u: (B, S, W) -> h: (B, S, W)."""
     if backend == "xla":
         return ref_lib.rglru_ref(a, u)

@@ -8,6 +8,10 @@ import jax.numpy as jnp
 
 NEG_INF = -1e30
 
+#: The gossip oracles' contractions at full f32 precision: at its default a
+#: TPU rounds f32 operands to bf16, which an oracle must not do.
+_EXACT = jax.lax.Precision.HIGHEST
+
 
 def attention_ref(q, k, v, *, causal: bool = True, window: int = 0, scale=None):
     """q: (BH, Sq, D); k, v: (BKV, Sk, D); GQA by head-group replication."""
@@ -78,8 +82,10 @@ def fused_gossip_ref(w, delta, theta, c, eta_s, corr_scale, *,
         wg = w.astype(gossip_dtype)
         dg = d32.astype(gossip_dtype)
         tg = t32.astype(gossip_dtype)
-    wd = jnp.einsum("ij,jd->id", wg, dg, preferred_element_type=jnp.float32)
-    wt = jnp.einsum("ij,jd->id", wg, tg, preferred_element_type=jnp.float32)
+    wd = jnp.einsum("ij,jd->id", wg, dg, preferred_element_type=jnp.float32,
+                    precision=_EXACT)
+    wt = jnp.einsum("ij,jd->id", wg, tg, preferred_element_type=jnp.float32,
+                    precision=_EXACT)
     theta_new = wt + eta_s * wd
     c_new = c.astype(jnp.float32) + corr_scale * (d32 - wd)
     return theta_new, c_new
@@ -102,7 +108,8 @@ def fused_round_ref(w, z0, c, ef, g, h_steps, step, etas, corr, mask, *,
 
     def body(z, h):
         grad = jnp.einsum("nij,nj->ni", g, z,
-                          preferred_element_type=jnp.float32)
+                          preferred_element_type=jnp.float32,
+                          precision=_EXACT)
         return z - step * (grad + h + c32), None
 
     zk, _ = jax.lax.scan(body, z0, h_steps)
@@ -121,8 +128,10 @@ def fused_round_ref(w, z0, c, ef, g, h_steps, step, etas, corr, mask, *,
         wg = w32.astype(gossip_dtype)
         qg = q.astype(gossip_dtype)
         zg = z0.astype(gossip_dtype)
-    wq = jnp.einsum("ij,jd->id", wg, qg, preferred_element_type=jnp.float32)
-    wz = jnp.einsum("ij,jd->id", wg, zg, preferred_element_type=jnp.float32)
+    wq = jnp.einsum("ij,jd->id", wg, qg, preferred_element_type=jnp.float32,
+                    precision=_EXACT)
+    wz = jnp.einsum("ij,jd->id", wg, zg, preferred_element_type=jnp.float32,
+                    precision=_EXACT)
     return wz + etas * wq, c32 + corr * (q - wq), e_new
 
 
@@ -154,7 +163,8 @@ def sparse_gossip_ref(neighbor_idx, neighbor_w, self_w, delta, theta, c,
         gathered = jnp.take(x, neighbor_idx, axis=0)        # (n, m, D)
         return (swg.astype(jnp.float32)[:, None] * x.astype(jnp.float32)
                 + jnp.einsum("nm,nmd->nd", nwg, gathered,
-                             preferred_element_type=jnp.float32))
+                             preferred_element_type=jnp.float32,
+                             precision=_EXACT))
 
     wd = spmv(dg)
     theta_new = spmv(tg) + eta_s * wd

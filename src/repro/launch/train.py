@@ -117,14 +117,21 @@ def _build_telemetry(args, algo, cfg, state):
 
 
 def _compile_cache(args):
-    """Resolve ``--compile-cache`` / ``$REPRO_COMPILE_CACHE`` into a
-    ``repro.sweep.cache.CompileCache`` (arming jax's persistent compilation
-    cache under the same root), or None when off.  Flag wins over env."""
+    """Resolve ``--compile-cache`` / ``$REPRO_COMPILE_CACHE`` (flag wins)
+    into a ``repro.sweep.cache.CompileCache``, or None.
+
+    Unset, jax's persistent compilation cache is still turned on (at
+    ``$JAX_COMPILATION_CACHE_DIR``, else ``results/.xla_cache/xla``) and the
+    AOT layer stays off; ``off`` turns both off; ``on`` or a directory turns
+    both on under the default root or that directory."""
     from repro.sweep import cache as cache_lib
 
     spec = getattr(args, "compile_cache", None)
     if spec is None:
-        return cache_lib.from_env()
+        spec = os.environ.get(cache_lib.ENV_CACHE)
+    if spec is None:
+        cache_lib.enable_xla_cache()
+        return None
     s = str(spec).strip().lower()
     if s in cache_lib._OFF_VALUES:
         return None
@@ -150,11 +157,16 @@ def _build_mesh_programs(args, cfg, algo, minimax, sched, sampler, metrics_fn,
                          engine_mode):
     """The repro.dist-sharded program over the local device mesh: the chunk
     builder (scan engine) or the per-round step (host engine) — only the
-    one the selected engine runs."""
+    one the selected engine runs — plus the state's sharding and that of a
+    per-client array."""
     import math
+
+    from jax.sharding import NamedSharding
+    from jax.sharding import PartitionSpec as P
 
     from repro.configs.base import InputShape, MeshConfig
     from repro.dist import compat
+    from repro.dist.sharding import CLIENTS
     from repro.launch import mesh as mesh_lib
     from repro.launch import steps as steps_lib
 
@@ -167,20 +179,24 @@ def _build_mesh_programs(args, cfg, algo, minimax, sched, sampler, metrics_fn,
     shape = InputShape(name="train_cli", seq_len=args.seq_len,
                        global_batch=args.batch * algo.num_clients,
                        kind="train")
+    # the leading (clients) dim of a per-client array on the clients axis
+    client_shard = NamedSharding(mesh, P(CLIENTS))
     with compat.use_mesh(mesh):
         if engine_mode == "scan":
             build_chunk, _, state_shard = steps_lib.build_train_chunk(
                 cfg, shape, mesh, mcfg, algo=algo, minimax=minimax,
                 lr_scale=sched, sampler=sampler, metrics_fn=metrics_fn,
                 log_every=args.log_every)
-            return None, build_chunk, state_shard
+            return None, build_chunk, state_shard, client_shard
         step, _, _, _, (state_shard, _, _) = steps_lib.build_train_round(
             cfg, shape, mesh, mcfg, algo=algo, minimax=minimax,
             lr_scale=sched)
-    return step, None, state_shard
+    return step, None, state_shard, client_shard
 
 
 def train(args) -> dict:
+    # before the first compile, so every program of the run can be cached
+    cache = _compile_cache(args)
     cfg = registry.get_model_config(args.arch)
     if args.reduced:
         cfg = registry.reduced(cfg)
@@ -235,8 +251,10 @@ def train(args) -> dict:
             dm, jax.random.fold_in(kd, 1), local_steps=1,
             num_clients=algo.num_clients, per_client_batch=args.batch,
             seq_len=args.seq_len, cfg=cfg))
-    state = kgt.init_state(problem, algo, ki, init_batch=init_b,
-                           init_keys=jax.random.split(ki, algo.num_clients))
+
+    def init_fn(k, b):
+        return kgt.init_state(problem, algo, k, init_batch=b,
+                              init_keys=jax.random.split(k, algo.num_clients))
 
     sched = schedules.get_schedule(args.schedule, args.rounds, args.warmup)
 
@@ -295,16 +313,21 @@ def train(args) -> dict:
     metrics_fn = engine_lib.dro_metrics_fn(
         problem, cfg, num_groups=args.groups, eval_batch=eval_b)
 
-    cache = _compile_cache(args)
     if mesh_mode == "decentralized":
         # Sharded path: the same jit programs the dry-run lowers for a pod,
         # here over whatever local devices exist (clients axis = n_devices).
         # repro.dist places the leading clients dim of the K-GT-Minimax
         # state on the "clients" mesh axis; only gossip crosses clients.
-        step, build_chunk, state_shard = _build_mesh_programs(
+        # The state is born sharded: each device computes and holds only
+        # its own clients' rows, never the whole client stack.
+        step, build_chunk, state_shard, client_shard = _build_mesh_programs(
             args, cfg, algo, minimax, sched, sampler, metrics_fn, engine_mode)
-        state = jax.device_put(state, state_shard)
+        state = jax.jit(init_fn, out_shardings=state_shard)(
+            ki, jax.device_put(init_b, client_shard))
     else:
+        # one compiled program: eager op-by-op dispatch of the init
+        # gradients is slow at full width on an accelerator
+        state = jax.jit(init_fn)(ki, init_b)
         round_step = kgt.make_round_step(problem, algo, lr_scale=sched,
                                          traced_w=random_w,
                                          participation=part,
@@ -385,6 +408,7 @@ def train(args) -> dict:
     return {
         "history": history,
         "final_consensus": history[-1]["consensus_x"] if history else None,
+        "state": state,
     }
 
 
@@ -429,7 +453,7 @@ def _host_loop(args, state, step, sampler, metrics_fn, cfg,
     return history
 
 
-def main() -> None:
+def build_parser() -> argparse.ArgumentParser:
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="paper-toy")
     ap.add_argument("--reduced", action="store_true",
@@ -529,16 +553,22 @@ def main() -> None:
                          "rounds (0 = profile the whole run)")
     ap.add_argument("--compile-cache", default=None, metavar="DIR|on|off",
                     help="persistent compile cache (repro.sweep.cache): a "
-                         "directory roots it, 'on' uses the default "
-                         "results/.xla_cache, 'off' disables; default: "
-                         "$REPRO_COMPILE_CACHE")
+                         "directory roots both layers, 'on' uses the default "
+                         "root, 'off' disables; default: $REPRO_COMPILE_CACHE, "
+                         "else jax's cache alone at $JAX_COMPILATION_CACHE_DIR "
+                         "or results/.xla_cache/xla")
     ap.add_argument("--out", default=None)
-    args = ap.parse_args()
+    return ap
+
+
+def main() -> None:
+    args = build_parser().parse_args()
     result = train(args)
     if args.out:
         os.makedirs(os.path.dirname(args.out) or ".", exist_ok=True)
         with open(args.out, "w") as f:
-            json.dump(result, f, indent=1)
+            json.dump({k: v for k, v in result.items() if k != "state"}, f,
+                      indent=1)
 
 
 if __name__ == "__main__":

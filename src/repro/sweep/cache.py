@@ -8,11 +8,12 @@ sub-seconds.  Every process recompiles every static cell from scratch, so
 This module removes that price in three layers:
 
 1. **jax's built-in persistent compilation cache** — :func:`enable_xla_cache`
-   roots ``jax_compilation_cache_dir`` under ``results/.xla_cache/xla`` (or
-   ``$REPRO_XLA_CACHE``) with the size/time thresholds dropped to zero, so
-   a repeated ``lower().compile()`` skips the XLA backend compile.  The
-   process still pays tracing + lowering per program, which is why layer 2
-   exists.
+   turns it on with the size/time thresholds dropped to zero, so a repeated
+   ``lower().compile()`` skips the XLA backend compile.  Where
+   ``$JAX_COMPILATION_CACHE_DIR`` is set, jax has already read it and that
+   directory is used as it is; otherwise the cache lives at the fixed
+   ``results/.xla_cache/xla``.  The process still pays tracing + lowering
+   per program, which is why layer 2 exists.
 
 2. **An AOT executable cache** — :class:`CompileCache` serializes
    ``jax.jit(...).lower(...).compile()`` executables
@@ -55,13 +56,15 @@ This module removes that price in three layers:
      Splitting a scan at a chunk boundary is bit-exact: the carried state
      is identical and the per-round bodies key off ``state.round``.
 
-Environment plumbing (both respected by the sweep CLI, ``launch/train`` and
+Environment plumbing (respected by the sweep CLI, ``launch/train`` and
 ``launch/dryrun``):
 
+* ``JAX_COMPILATION_CACHE_DIR`` — jax's own variable: where layer 1 lives.
+  The default root of the whole stack then sits beside it (its parent
+  directory), so the AOT layer lands in ``<parent>/aot``.
 * ``REPRO_COMPILE_CACHE`` — ``off``/``0`` disables; a path roots the whole
   stack (``<path>/aot`` + ``<path>/xla``); ``1``/``on``/``auto`` uses the
-  default root ``results/.xla_cache``.
-* ``REPRO_XLA_CACHE``     — overrides just the layer-1 directory.
+  default root.
 
 Cache traffic is observable: hit/miss/error/put counters and byte totals
 flow through ``repro.obs`` as ``compile_cache.*`` counter events (folded
@@ -92,7 +95,7 @@ import jax.numpy as jnp
 CACHE_VERSION = 1
 
 ENV_CACHE = "REPRO_COMPILE_CACHE"
-ENV_XLA_CACHE = "REPRO_XLA_CACHE"
+ENV_JAX_CACHE_DIR = "JAX_COMPILATION_CACHE_DIR"
 
 _OFF_VALUES = ("", "0", "off", "none", "false", "disabled")
 _ON_VALUES = ("1", "on", "auto", "true")
@@ -109,8 +112,13 @@ def repo_root() -> str:
 
 
 def default_root() -> str:
-    """``<repo>/results/.xla_cache`` — gitignored scratch, like the rest of
-    ``results/`` outside the curated artifacts."""
+    """The parent of ``$JAX_COMPILATION_CACHE_DIR`` when that is set (the
+    AOT layer roots beside jax's cache), else ``<repo>/results/.xla_cache``
+    — gitignored scratch, like the rest of ``results/`` outside the curated
+    artifacts."""
+    outside = os.environ.get(ENV_JAX_CACHE_DIR)
+    if outside:
+        return os.path.dirname(os.path.abspath(outside))
     return os.path.join(repo_root(), "results", ".xla_cache")
 
 
@@ -118,26 +126,23 @@ def default_root() -> str:
 # layer 1: jax's built-in persistent compilation cache
 
 
-def enable_xla_cache(root: Optional[str] = None) -> Optional[str]:
-    """Point ``jax_compilation_cache_dir`` at ``root`` (default
-    ``results/.xla_cache/xla``; ``$REPRO_XLA_CACHE`` overrides, with the
-    off-values disabling).  Thresholds are dropped so even the sweep's
-    sub-second programs persist.  Returns the active directory, or None
-    when disabled.  Idempotent — safe to call from every entry point."""
-    env = os.environ.get(ENV_XLA_CACHE)
-    if env is not None:
-        if env.strip().lower() in _OFF_VALUES:
-            return None
-        if env.strip().lower() not in _ON_VALUES:
-            root = env
-    root = root or os.path.join(default_root(), "xla")
+def enable_xla_cache(root: Optional[str] = None) -> str:
+    """Turn on jax's persistent compilation cache and return its directory.
+
+    Where ``$JAX_COMPILATION_CACHE_DIR`` is set, jax has read it at import
+    and its setting is left alone (``root`` is ignored); otherwise the cache
+    goes to ``root`` (default ``results/.xla_cache/xla``).  Thresholds are
+    dropped so even the sweep's sub-second programs persist.  Idempotent —
+    safe to call from every entry point."""
+    outside = os.environ.get(ENV_JAX_CACHE_DIR)
+    if outside:
+        root = outside
+    else:
+        root = root or os.path.join(default_root(), "xla")
+        jax.config.update("jax_compilation_cache_dir", root)
     os.makedirs(root, exist_ok=True)
-    jax.config.update("jax_compilation_cache_dir", root)
     jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
-    try:
-        jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
-    except AttributeError:  # pragma: no cover - older jax spelling
-        pass
+    jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
     return root
 
 

@@ -56,7 +56,7 @@ DEFAULT_POINT: Dict[str, Any] = dict(
     topology_family="static", edge_prob=0.5, client_drop_prob=0.3,
     participation=1.0,
     num_byzantine=0, attack="honest", attack_scale=1.0, robust_trim=1,
-    gossip_compress=None,
+    gossip_compress=None, gossip_backend="auto",
 )
 
 # Point parameters that change the traced program: same-valued across every
@@ -70,7 +70,7 @@ DEFAULT_POINT: Dict[str, Any] = dict(
 # ``cell_key=lambda f: f > 0``.)
 STATIC_KEYS = ("algorithm", "n", "K", "topology", "mixing_impl",
                "eps", "max_rounds", "eval_every", "topology_family",
-               "robust_trim", "gossip_compress")
+               "robust_trim", "gossip_compress", "gossip_backend")
 
 
 def _churn(p: Dict[str, Any]):
@@ -98,6 +98,7 @@ def _program_statics(p: Dict[str, Any], *, batched: bool) -> tuple:
         ("topology_family", p["topology_family"]),
         ("robust_trim", p["robust_trim"]),
         ("gossip_compress", p["gossip_compress"]),
+        ("gossip_backend", p["gossip_backend"]),
         ("noise", p["sigma"] > 0.0), ("churn", _churn(p)),
         ("byzantine", _byz(p)), ("batched", batched),
         ("geometry", (DX, DY)),
@@ -119,7 +120,8 @@ def _cfg(p: Dict[str, Any]) -> AlgorithmConfig:
         eta_cx=p["eta_cx"], eta_cy=p["eta_cy"], eta_sx=p["eta_s"],
         eta_sy=p["eta_s"], topology=p["topology"],
         mixing_impl=p["mixing_impl"], robust_trim=p["robust_trim"],
-        gossip_compress=p["gossip_compress"])
+        gossip_compress=p["gossip_compress"],
+        gossip_backend=p["gossip_backend"])
 
 
 # Jitted per-point setup, cached on the static parameters it bakes in.
@@ -328,6 +330,18 @@ def _chunk_lengths(length: int, cache) -> tuple:
     if cache is not None and cache.bucket_lengths:
         return cache_lib.length_schedule(length)
     return (length,)
+
+
+def point_program_text(p: Dict[str, Any], length: int) -> str:
+    """The optimized HLO of the ``length``-round chunk program that
+    :func:`run_point` compiles for ``p`` — where a caller checks which
+    lowering the backend really got (e.g. a ``tpu_custom_call`` for a
+    Pallas kernel)."""
+    p = _full_point(p)
+    traj, _ = prepare_trajectory(p)
+    build, _ = _cell_programs(p, batched=False)
+    final_round = jnp.int32(p["max_rounds"] - 1)
+    return build(length).lower(traj, final_round).compile().as_text()
 
 
 def run_point(p: Dict[str, Any], *, cache=cache_lib.UNSET, telemetry=None):

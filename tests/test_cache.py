@@ -189,7 +189,8 @@ def test_program_statics_cover_cell_parameters():
                   dict(topology="full"), dict(mixing_impl="gather"),
                   dict(sigma=0.0), dict(topology_family="erdos_renyi"),
                   dict(participation=0.5), dict(num_byzantine=1),
-                  dict(gossip_compress="int8"), dict(robust_trim=2)):
+                  dict(gossip_compress="int8"), dict(robust_trim=2),
+                  dict(gossip_backend="xla")):
         statics = sweep_run._program_statics(
             sweep_run._full_point(dict(POINT, **delta)), batched=False)
         assert statics not in seen, delta
@@ -300,3 +301,58 @@ def test_resolve_env_path(monkeypatch, tmp_path):
     assert cache.root == str(tmp_path / "c" / "aot")
     # memoized per env value: run_point calls share one executable memo
     assert cache_lib.resolve(cache_lib.UNSET) is cache
+
+
+def _run_env(code: str, **env):
+    import subprocess
+
+    full = {k: v for k, v in os.environ.items()
+            if k != cache_lib.ENV_JAX_CACHE_DIR}
+    full.update(env, JAX_PLATFORMS="cpu")
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    full["PYTHONPATH"] = os.path.join(root, "src")
+    return subprocess.run([sys.executable, "-c", code], env=full, cwd=root,
+                          capture_output=True, text=True, timeout=600)
+
+
+@pytest.mark.parametrize("outside", [False, True])
+def test_enable_xla_cache_placement(tmp_path, outside):
+    """Unset, jax's cache goes to the fixed results/.xla_cache/xla; set,
+    $JAX_COMPILATION_CACHE_DIR is left as jax read it and the AOT layer's
+    default root moves beside it.  A fresh process each: the placement is
+    global jax config."""
+    env = ({cache_lib.ENV_JAX_CACHE_DIR: str(tmp_path / "xla")}
+           if outside else {})
+    proc = _run_env(
+        "import jax\n"
+        "from repro.sweep import cache as c\n"
+        "print(c.enable_xla_cache('/nonexistent/never'))\n"
+        "print(jax.config.jax_compilation_cache_dir)\n"
+        "print(c.default_root())\n", **env)
+    assert proc.returncode == 0, proc.stderr
+    active, configured, root = proc.stdout.split()
+    if outside:
+        assert active == configured == str(tmp_path / "xla")
+        assert root == str(tmp_path)
+    else:
+        assert active == configured == "/nonexistent/never"
+        assert root == os.path.join(cache_lib.repo_root(), "results",
+                                    ".xla_cache")
+
+
+def test_trainer_writes_its_cache_only_where_jax_is_told(tmp_path):
+    """A CPU run of the trainer CLI with $JAX_COMPILATION_CACHE_DIR set
+    persists its programs there and writes nothing beside it (the AOT
+    layer stays off unless asked for)."""
+    xla = tmp_path / "xla"
+    proc = _run_env(
+        "from repro.launch.train import main\n"
+        "import sys\n"
+        "sys.argv = ['train', '--arch', 'paper-toy', '--reduced',\n"
+        "            '--rounds', '2', '--clients', '2', '--local-steps',\n"
+        "            '1', '--batch', '1', '--seq-len', '16', '--chunk', '2',\n"
+        "            '--log-every', '1']\n"
+        "main()\n", **{cache_lib.ENV_JAX_CACHE_DIR: str(xla)})
+    assert proc.returncode == 0, proc.stderr[-2000:]
+    assert os.listdir(tmp_path) == ["xla"]
+    assert len(os.listdir(xla)) > 0

@@ -82,8 +82,9 @@ def test_fused_round_kernel_matches_oracle(compress, gossip_dtype):
 
 def test_fused_round_rejects_oversized_state():
     """VMEM guard: the whole-round kernel holds G = (n, dz, dz) resident,
-    so dz beyond one block must fail loudly, not silently spill."""
-    n, dz, k = 4, 1100, 1  # pads past the 1024 single-block ceiling
+    so a dz past the scoped-VMEM bound must fail loudly, not silently
+    spill."""
+    n, dz, k = 4, 1100, 1  # G alone is 40 MiB once padded
     z = jnp.zeros((n, dz))
     with pytest.raises(ValueError, match="fused_round"):
         ops.fused_round(jnp.eye(n), z, z, z, jnp.zeros((n, dz, dz)),
@@ -334,3 +335,15 @@ def test_validate_method():
     assert compression.validate_method("int8") == "int8"
     with pytest.raises(ValueError, match="int4"):
         compression.validate_method("int4")
+
+
+def test_point_program_text_is_the_run_point_chunk():
+    """The HLO a caller inspects for the lowering is the chunk run_point
+    compiles: the XLA oracle backend holds no Pallas kernel call."""
+    from repro.sweep import run as sweep_run
+
+    p = dict(n=4, K=2, mixing_impl="fused_round", max_rounds=4,
+             eval_every=2, gossip_backend="xla")
+    txt = sweep_run.point_program_text(p, 2)
+    assert "HloModule" in txt
+    assert "tpu_custom_call" not in txt

@@ -88,3 +88,13 @@ def test_mix_packed_matches_per_leaf_dense():
     for a, b in zip(jax.tree.leaves(packed), jax.tree.leaves(dense)):
         assert a.shape == b.shape and a.dtype == b.dtype
         np.testing.assert_allclose(a, b, rtol=1e-6, atol=1e-6)
+
+
+def test_f32_gossip_contracts_at_full_precision():
+    """f32 gossip is f32 on every backend: the contraction asks for HIGHEST
+    precision, which a TPU needs (its default rounds f32 operands to bf16,
+    which would round every parameter to bf16 each round)."""
+    w = topology.mixing_matrix("ring", 4)
+    x = {"a": jnp.ones((4, 3), jnp.float32)}
+    txt = jax.jit(lambda t: mixing.mix_dense(t, w)).lower(x).as_text()
+    assert "HIGHEST" in txt

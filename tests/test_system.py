@@ -53,9 +53,13 @@ def test_train_driver_end_to_end():
 def test_train_driver_loss_improves():
     res = train_lib.train(_args(rounds=20, eta_cx=0.05, eta_cy=0.2, batch=4))
     hist = res["history"]
-    # the LM quality metric (mean group loss) must improve; the saddle value
-    # f(x̄,ȳ) itself is not monotone (y climbs first)
-    assert hist[-1]["mean_loss"] < hist[0]["mean_loss"]
+    # judged on the held-out loss: it is measured on one fixed batch the
+    # optimizer never sees, so it tracks the model.  The train mean_loss is
+    # each logged round's own fresh batch, whose draw-to-draw noise is as
+    # large as 20 rounds of progress (it rose 3.12 -> 3.67 in a run whose
+    # held-out loss fell 1.56 -> 1.39).  The saddle value f(x̄,ȳ) itself is
+    # not monotone either (y climbs first).
+    assert hist[-1]["eval_loss"] < hist[0]["eval_loss"]
 
 
 @pytest.mark.parametrize("algorithm", ["dsgda", "local_sgda", "gt_gda"])
@@ -87,3 +91,19 @@ def test_train_driver_wsd_schedule():
     res = train_lib.train(_args(rounds=6, schedule="wsd", warmup=2,
                                 arch="minicpm-2b"))
     assert all(jnp.isfinite(h["f_bar"]) for h in res["history"])
+
+
+def test_chip_smoke_refuses_the_cpu():
+    """chip_smoke.py never falls back to the CPU: with no TPU it exits
+    non-zero and prints no result."""
+    import os
+    import subprocess
+    import sys
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    proc = subprocess.run(
+        [sys.executable, "chip_smoke.py"], cwd=root, capture_output=True,
+        text=True, timeout=300, env=dict(os.environ, JAX_PLATFORMS="cpu"))
+    assert proc.returncode != 0
+    assert '"ok"' not in proc.stdout
+    assert "no TPU" in proc.stderr
