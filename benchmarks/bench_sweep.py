@@ -96,7 +96,9 @@ def _cache_pair(csv) -> dict:
     env["PYTHONPATH"] = os.pathsep.join(
         p for p in (os.path.join(root, "src"), root,
                     env.get("PYTHONPATH", "")) if p)
-    env.pop("REPRO_COMPILE_CACHE", None)  # the child gets an explicit dir
+    # the children get an explicit dir, and both layers start empty there
+    env.pop("REPRO_COMPILE_CACHE", None)
+    env.pop("JAX_COMPILATION_CACHE_DIR", None)
     shutil.rmtree(CACHE_DIR, ignore_errors=True)
     out: dict = {}
     for mode in ("cold_cache", "warm_cache"):

@@ -5,9 +5,9 @@ Default (one TPU chip), two phases:
 1. ``train``: ``repro.launch.train`` exactly as its CLI runs it — paper-toy
    at its own widths (12 layers, d=768, vocab 32000), n=4 clients, K=2,
    per-client batch 2 at seq 512, ``--mesh host --engine scan --chunk 4``,
-   ``ROUNDS`` rounds at x step ``ETA_CX``: one compile, then steady chunks.  Passes when every logged
-   metric is finite and the held-out loss at the last logged round is below
-   that at round 0.
+   ``ROUNDS`` rounds at x step ``ETA_CX``: one compile, then steady
+   chunks.  Passes when every logged metric is finite and the held-out
+   loss at the last logged round is below that at round 0.
 2. ``kernel``: the paper quadratic through ``repro.sweep.run.run_point`` at
    n=8, K=8 on a ring with ``mixing_impl="fused_round"`` and
    ``gossip_backend="auto"`` — the whole round as one Pallas kernel.  Passes
@@ -43,15 +43,16 @@ REPO = os.path.dirname(os.path.abspath(__file__))
 #: Rounds of the train phase, in chunks of CHUNK rounds (one compile: every
 #: chunk has the same length).  Each chunk logs its first round.  At full
 #: width the held-out loss moves slowly — 32000 embedding rows each see few
-#: tokens per step under plain SGD — so 8 rounds cannot show it fall:
-#: on a v5e it read 5.26255 -> 5.26387 over 8 rounds at the default
-#: steps, and 5.26256 -> 5.25987 over 64 rounds at ETA_CX.
+#: tokens per step under plain SGD — so 8 rounds cannot show it fall: on a
+#: v5e it read 5.26255 -> 5.26387 over 8 rounds at the default steps.
 ROUNDS = 64
 CHUNK = 4
 
-#: The x step size.  The default 0.05 diverges at full width (NaN by round
-#: 36 on a v5e; the group weights y grow to the group losses, ~7, and scale
-#: the x step with them); 0.01 stayed finite over 64 rounds.
+#: The x step size.  At 0.01 the job stayed finite and the held-out loss
+#: fell, 5.262521 -> 5.259042 over 64 rounds on a v5e with f32 gossip at
+#: full precision.  The default 0.05 was NaN by round 36 on a v5e, but only
+#: in runs whose gossip rounded the parameters to bf16 each round; it has
+#: not yet been run on a chip with that rounding removed.
 ETA_CX = 0.01
 
 #: The train phase's job, as CLI arguments (``--seed`` is appended).
@@ -68,13 +69,12 @@ KERNEL_POINT = dict(n=8, K=8, topology="ring", mixing_impl="fused_round",
                     eps=0.0, max_rounds=160, eval_every=10)
 
 #: Relative tolerance between the Pallas kernel's and the XLA oracle's
-#: ‖∇Φ‖ at each evaluation.  The two lowerings round f32 matmuls
-#: differently on the TPU (XLA's default f32 dot is one bf16 pass, relative
-#: error ~2^-9 per operand; the kernel's MXU contraction may differ), and
-#: each round feeds the next, so the trajectories drift apart by roughly
-#: that much per round before the contraction of the algorithm damps it.
-#: 2e-2 holds that drift with room; a wrong mixing weight, sign or
-#: correction changes ‖∇Φ‖ by tens of percent or makes it diverge.
+#: ‖∇Φ‖ at each evaluation.  Both contract f32 at full precision, but in
+#: different orders, and each round feeds the next: on a v5e the two
+#: trajectories differed by at most 1.3e-6 relative over 160 rounds.  2e-2
+#: leaves room for a compiler that splits the f32 contraction into fewer
+#: bf16 passes (relative error ~2^-9 per operand); a wrong mixing weight,
+#: sign or correction changes ‖∇Φ‖ by tens of percent or makes it diverge.
 KERNEL_RTOL = 2e-2
 
 #: Relative tolerance between the sharded four-chip run's and the one-chip
@@ -121,20 +121,35 @@ def finite(value) -> bool:
     return math.isfinite(value)
 
 
-def run_train(train_lib, seed: int, mesh: str) -> tuple:
-    """One train() call as the CLI makes it; returns (history, state)."""
+def client_rows(jax, state) -> dict:
+    """Where each ``state.x`` leaf's client rows live:
+    ``{leaf path: sorted [(first row, rows, device id)] per shard}``."""
+    return {
+        jax.tree_util.keystr(path): sorted(
+            (s.index[0].start or 0, s.data.shape[0], s.device.id)
+            for s in leaf.addressable_shards)
+        for path, leaf in jax.tree_util.tree_leaves_with_path(state.x)}
+
+
+def run_train(jax, train_lib, seed: int, mesh: str) -> tuple:
+    """One train() call as the CLI makes it; returns (history, placement):
+    the history of logged rounds and ``client_rows`` of the final state."""
     argv = TRAIN_ARGV + ["--seed", str(seed)]
     argv[argv.index("--mesh") + 1] = mesh
     args = train_lib.build_parser().parse_args(argv)
-    res = train_lib.train(args)
-    hist = res["history"]
+    placement = {}
+
+    def read_placement(state, records, prev_round):
+        placement.update(client_rows(jax, state))
+
+    hist = train_lib.train(args, hooks=[read_placement])["history"]
     if not hist:
         fail(f"train ({mesh}) logged no rounds")
     for rec in hist:
         bad = [k for k, v in rec.items() if not finite(v)]
         if bad:
             fail(f"train ({mesh}) round {rec['round']}: non-finite {bad}")
-    return hist, res["state"]
+    return hist, placement
 
 
 def train_summary(hist) -> dict:
@@ -149,21 +164,23 @@ def train_summary(hist) -> dict:
         "steady_s_per_round": (hist[-1]["wall_s"] - first_end) / steady_rounds,
         "eval_loss": [(r["round"], r["eval_loss"]) for r in hist],
         "mean_loss": [(r["round"], r["mean_loss"]) for r in hist],
+        "y_bar_norm": [(r["round"], r["y_bar_norm"]) for r in hist],
     }
 
 
 def phase_train(jax, seed: int) -> None:
     from repro.configs import registry
     from repro.launch import train as train_lib
+    from repro.models import model as model_lib
 
     cfg = registry.get_model_config("paper-toy")
+    n_params = model_lib.param_count(jax.eval_shape(
+        lambda key: model_lib.init_params(cfg, key), jax.random.PRNGKey(0)))
     say(f"train: {cfg.name} layers={cfg.num_layers} d_model={cfg.d_model} "
         f"heads={cfg.num_heads}/{cfg.num_kv_heads} d_ff={cfg.d_ff} "
         f"vocab={cfg.vocab_size}; argv: {' '.join(TRAIN_ARGV)} --seed {seed}")
     t0 = time.perf_counter()
-    hist, state = run_train(train_lib, seed, "host")
-    n_params = sum(x.size for x in jax.tree.leaves(state.x)) // 4
-    del state
+    hist, _ = run_train(jax, train_lib, seed, "host")
     s = train_summary(hist)
     peak = peak_bytes(jax.devices()[0])
     say(f"train: params per client {n_params}, compile_s={s['compile_s']}, "
@@ -172,6 +189,7 @@ def phase_train(jax, seed: int) -> None:
         f"wall {time.perf_counter() - t0:.1f}s")
     say(f"train: eval_loss {s['eval_loss']}")
     say(f"train: mean_loss {s['mean_loss']}")
+    say(f"train: y_bar_norm {s['y_bar_norm']}")
     first, last = hist[0]["eval_loss"], hist[-1]["eval_loss"]
     if not last < first:
         fail(f"held-out loss did not fall: {first} -> {last}")
@@ -214,25 +232,21 @@ def phase_four_chips(jax, seed: int) -> None:
     if len(devs) != 4:
         fail(f"--four-chips needs 4 devices, found {len(devs)}")
     say(f"mesh: paper-toy, 4 clients over {len(devs)} chips")
-    hist_d, state = run_train(train_lib, seed, "decentralized")
-    for path, leaf in jax.tree_util.tree_leaves_with_path(state.x):
-        shards = leaf.addressable_shards
-        rows = sorted((s.index[0].start or 0, s.device.id) for s in shards)
-        owners = {s.device.id for s in shards}
-        if (len(owners) != 4 or any(s.data.shape[0] != 1 for s in shards)
-                or [r for r, _ in rows] != [0, 1, 2, 3]):
-            fail(f"state.x{jax.tree_util.keystr(path)} is not one client "
-                 f"row per device: {rows}")
-    say(f"mesh: every state.x leaf holds client rows on devices "
-        f"{sorted(rows)} (row, device id)")
-    del state
+    hist_d, placement = run_train(jax, train_lib, seed, "decentralized")
+    if not placement:
+        fail("no chunk boundary reported the final state")
+    for path, rows in placement.items():
+        if ([(r, k) for r, k, _ in rows] != [(0, 1), (1, 1), (2, 1), (3, 1)]
+                or len({d for _, _, d in rows}) != 4):
+            fail(f"state.x{path} is not one client row per device: {rows}")
+    say(f"mesh: every state.x leaf holds one client row per device; "
+        f"(row, rows, device id) {rows}")
     peaks_d = [peak_bytes(d) for d in devs]
     s_d = train_summary(hist_d)
     say(f"mesh: decentralized compile_s={s_d['compile_s']}, "
         f"steady_s_per_round={s_d['steady_s_per_round']:.4f}, "
         f"peak_bytes_in_use per device {peaks_d}")
-    hist_h, state = run_train(train_lib, seed, "host")
-    del state
+    hist_h, _ = run_train(jax, train_lib, seed, "host")
     peak_h = peak_bytes(devs[0])
     s_h = train_summary(hist_h)
     say(f"mesh: host compile_s={s_h['compile_s']}, "

@@ -54,8 +54,7 @@ def main() -> None:
 
     os.makedirs(RESULTS, exist_ok=True)
     with open(ns.out, "w") as f:
-        json.dump({k: v for k, v in result.items() if k != "state"}, f,
-                  indent=1)
+        json.dump(result, f, indent=1)
     print(f"[robust_lm] wrote {ns.out}")
 
 

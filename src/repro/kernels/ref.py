@@ -160,11 +160,14 @@ def sparse_gossip_ref(neighbor_idx, neighbor_w, self_w, delta, theta, c,
         swg = self_w.astype(gossip_dtype)
 
     def spmv(x):
-        gathered = jnp.take(x, neighbor_idx, axis=0)        # (n, m, D)
-        return (swg.astype(jnp.float32)[:, None] * x.astype(jnp.float32)
-                + jnp.einsum("nm,nmd->nd", nwg, gathered,
-                             preferred_element_type=jnp.float32,
-                             precision=_EXACT))
+        # self term first, then one slot at a time: the neighbor-gather
+        # kernel's summation order, so the two agree to the ulp
+        x32 = x.astype(jnp.float32)
+        acc = swg.astype(jnp.float32)[:, None] * x32
+        for slot in range(neighbor_idx.shape[1]):
+            acc = acc + (nwg[:, slot].astype(jnp.float32)[:, None]
+                         * jnp.take(x32, neighbor_idx[:, slot], axis=0))
+        return acc
 
     wd = spmv(dg)
     theta_new = spmv(tg) + eta_s * wd

@@ -118,27 +118,24 @@ def _build_telemetry(args, algo, cfg, state):
 
 def _compile_cache(args):
     """Resolve ``--compile-cache`` / ``$REPRO_COMPILE_CACHE`` (flag wins)
-    into a ``repro.sweep.cache.CompileCache``, or None.
-
-    Unset, jax's persistent compilation cache is still turned on (at
-    ``$JAX_COMPILATION_CACHE_DIR``, else ``results/.xla_cache/xla``) and the
-    AOT layer stays off; ``off`` turns both off; ``on`` or a directory turns
-    both on under the default root or that directory."""
+    into a ``repro.sweep.cache.CompileCache``, or None when neither is
+    given or it is ``off``.  ``on`` turns on both layers at their default
+    places; a directory roots both there."""
     from repro.sweep import cache as cache_lib
 
     spec = getattr(args, "compile_cache", None)
     if spec is None:
         spec = os.environ.get(cache_lib.ENV_CACHE)
     if spec is None:
-        cache_lib.enable_xla_cache()
         return None
     s = str(spec).strip().lower()
     if s in cache_lib._OFF_VALUES:
         return None
-    root = (cache_lib.default_root() if s in cache_lib._ON_VALUES
-            else str(spec))
-    cache_lib.enable_xla_cache(os.path.join(root, "xla"))
-    return cache_lib.CompileCache(os.path.join(root, "aot"))
+    if s in cache_lib._ON_VALUES:
+        cache_lib.enable_xla_cache()
+        return cache_lib.CompileCache()
+    cache_lib.enable_xla_cache(os.path.join(str(spec), "xla"))
+    return cache_lib.CompileCache(os.path.join(str(spec), "aot"))
 
 
 def _train_statics(args) -> tuple:
@@ -194,7 +191,12 @@ def _build_mesh_programs(args, cfg, algo, minimax, sched, sampler, metrics_fn,
     return step, None, state_shard, client_shard
 
 
-def train(args) -> dict:
+def train(args, hooks=()) -> dict:
+    """Run the job ``args`` describes (the CLI's namespace) and return its
+    JSON-able result.  ``hooks`` are extra chunk-boundary hooks of the scan
+    engine, ``hook(state, records, prev_round)`` (``repro.engine.run``)."""
+    if hooks and getattr(args, "engine", "scan") != "scan":
+        raise ValueError("train(hooks=...) needs --engine scan")
     # before the first compile, so every program of the run can be cached
     cache = _compile_cache(args)
     cfg = registry.get_model_config(args.arch)
@@ -379,20 +381,21 @@ def train(args) -> dict:
             # the telemetry hook routes metric rows to the stderr sink
             # (the historical console log) and, with --telemetry-out, the
             # ledger + health gauges into the JSONL stream
-            hooks = [engine_lib.telemetry_hook(
+            engine_hooks = [engine_lib.telemetry_hook(
                 telemetry, ledger=ledger,
                 health_fn=obs.health_gauges if ledger is not None else None)]
             if args.checkpoint_every:
-                hooks.append(engine_lib.checkpoint_hook(
+                engine_hooks.append(engine_lib.checkpoint_hook(
                     args.checkpoint_dir, args.checkpoint_every,
                     metadata={"arch": cfg.name}, verbose=True))
             if profiler is not None:
                 profiler.start()
-                hooks.append(profiler.hook)
+                engine_hooks.append(profiler.hook)
+            engine_hooks.extend(hooks)
 
             state, history = engine_lib.run(
                 state, build_chunk, total_rounds=args.rounds,
-                chunk_rounds=chunk_rounds, hooks=hooks,
+                chunk_rounds=chunk_rounds, hooks=engine_hooks,
                 # chunk boundaries land on every checkpoint multiple, so the
                 # requested cadence is honored exactly (matches --engine host)
                 boundary_every=args.checkpoint_every or None,
@@ -408,7 +411,6 @@ def train(args) -> dict:
     return {
         "history": history,
         "final_consensus": history[-1]["consensus_x"] if history else None,
-        "state": state,
     }
 
 
@@ -554,21 +556,27 @@ def build_parser() -> argparse.ArgumentParser:
     ap.add_argument("--compile-cache", default=None, metavar="DIR|on|off",
                     help="persistent compile cache (repro.sweep.cache): a "
                          "directory roots both layers, 'on' uses the default "
-                         "root, 'off' disables; default: $REPRO_COMPILE_CACHE, "
-                         "else jax's cache alone at $JAX_COMPILATION_CACHE_DIR "
-                         "or results/.xla_cache/xla")
+                         "places, 'off' disables; default: "
+                         "$REPRO_COMPILE_CACHE, else jax's cache alone at "
+                         "$JAX_COMPILATION_CACHE_DIR or "
+                         "results/.xla_cache/xla")
     ap.add_argument("--out", default=None)
     return ap
 
 
 def main() -> None:
+    from repro.sweep import cache as cache_lib
+
     args = build_parser().parse_args()
+    if args.compile_cache is None and cache_lib.ENV_CACHE not in os.environ:
+        # the CLI keeps jax's persistent cache on by default; train() itself
+        # turns on only what it is asked for
+        cache_lib.enable_xla_cache()
     result = train(args)
     if args.out:
         os.makedirs(os.path.dirname(args.out) or ".", exist_ok=True)
         with open(args.out, "w") as f:
-            json.dump({k: v for k, v in result.items() if k != "state"}, f,
-                      indent=1)
+            json.dump(result, f, indent=1)
 
 
 if __name__ == "__main__":

@@ -9,11 +9,11 @@ This module removes that price in three layers:
 
 1. **jax's built-in persistent compilation cache** — :func:`enable_xla_cache`
    turns it on with the size/time thresholds dropped to zero, so a repeated
-   ``lower().compile()`` skips the XLA backend compile.  Where
-   ``$JAX_COMPILATION_CACHE_DIR`` is set, jax has already read it and that
-   directory is used as it is; otherwise the cache lives at the fixed
-   ``results/.xla_cache/xla``.  The process still pays tracing + lowering
-   per program, which is why layer 2 exists.
+   ``lower().compile()`` skips the XLA backend compile.  A directory the
+   caller names wins; else, where ``$JAX_COMPILATION_CACHE_DIR`` is set,
+   jax has already read it and that directory is used as it is; otherwise
+   the cache lives at the fixed ``results/.xla_cache/xla``.  The process
+   still pays tracing + lowering per program, which is why layer 2 exists.
 
 2. **An AOT executable cache** — :class:`CompileCache` serializes
    ``jax.jit(...).lower(...).compile()`` executables
@@ -59,12 +59,12 @@ This module removes that price in three layers:
 Environment plumbing (respected by the sweep CLI, ``launch/train`` and
 ``launch/dryrun``):
 
-* ``JAX_COMPILATION_CACHE_DIR`` — jax's own variable: where layer 1 lives.
-  The default root of the whole stack then sits beside it (its parent
-  directory), so the AOT layer lands in ``<parent>/aot``.
+* ``JAX_COMPILATION_CACHE_DIR`` — jax's own variable: where layer 1 lives
+  unless a directory is named explicitly.  The AOT layer stays inside the
+  checkout (``results/.xla_cache/aot``) whatever it says.
 * ``REPRO_COMPILE_CACHE`` — ``off``/``0`` disables; a path roots the whole
   stack (``<path>/aot`` + ``<path>/xla``); ``1``/``on``/``auto`` uses the
-  default root.
+  default AOT root and layer 1's default placement.
 
 Cache traffic is observable: hit/miss/error/put counters and byte totals
 flow through ``repro.obs`` as ``compile_cache.*`` counter events (folded
@@ -112,13 +112,8 @@ def repo_root() -> str:
 
 
 def default_root() -> str:
-    """The parent of ``$JAX_COMPILATION_CACHE_DIR`` when that is set (the
-    AOT layer roots beside jax's cache), else ``<repo>/results/.xla_cache``
-    — gitignored scratch, like the rest of ``results/`` outside the curated
-    artifacts."""
-    outside = os.environ.get(ENV_JAX_CACHE_DIR)
-    if outside:
-        return os.path.dirname(os.path.abspath(outside))
+    """``<repo>/results/.xla_cache`` — gitignored scratch, like the rest of
+    ``results/`` outside the curated artifacts."""
     return os.path.join(repo_root(), "results", ".xla_cache")
 
 
@@ -129,13 +124,14 @@ def default_root() -> str:
 def enable_xla_cache(root: Optional[str] = None) -> str:
     """Turn on jax's persistent compilation cache and return its directory.
 
-    Where ``$JAX_COMPILATION_CACHE_DIR`` is set, jax has read it at import
-    and its setting is left alone (``root`` is ignored); otherwise the cache
-    goes to ``root`` (default ``results/.xla_cache/xla``).  Thresholds are
-    dropped so even the sweep's sub-second programs persist.  Idempotent —
-    safe to call from every entry point."""
+    An explicit ``root`` wins.  Without one, where
+    ``$JAX_COMPILATION_CACHE_DIR`` is set, jax has read it at import and its
+    setting is left alone; otherwise the cache goes to
+    ``results/.xla_cache/xla``.  Thresholds are dropped so even the sweep's
+    sub-second programs persist.  Idempotent — safe to call from every
+    entry point."""
     outside = os.environ.get(ENV_JAX_CACHE_DIR)
-    if outside:
+    if root is None and outside:
         root = outside
     else:
         root = root or os.path.join(default_root(), "xla")
@@ -534,10 +530,12 @@ def from_env(telemetry=None) -> Optional[CompileCache]:
     if value in _DEFAULT:
         cache = _DEFAULT[value]
     else:
-        root = (default_root() if value.strip().lower() in _ON_VALUES
-                else value)
-        enable_xla_cache(os.path.join(root, "xla"))
-        cache = CompileCache(os.path.join(root, "aot"))
+        if value.strip().lower() in _ON_VALUES:
+            enable_xla_cache()
+            cache = CompileCache()
+        else:
+            enable_xla_cache(os.path.join(value, "xla"))
+            cache = CompileCache(os.path.join(value, "aot"))
         _DEFAULT[value] = cache
     if telemetry is not None:
         cache.telemetry = telemetry

@@ -307,7 +307,7 @@ def _run_env(code: str, **env):
     import subprocess
 
     full = {k: v for k, v in os.environ.items()
-            if k != cache_lib.ENV_JAX_CACHE_DIR}
+            if k not in (cache_lib.ENV_JAX_CACHE_DIR, cache_lib.ENV_CACHE)}
     full.update(env, JAX_PLATFORMS="cpu")
     root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
     full["PYTHONPATH"] = os.path.join(root, "src")
@@ -315,29 +315,53 @@ def _run_env(code: str, **env):
                           capture_output=True, text=True, timeout=600)
 
 
+@pytest.mark.parametrize("explicit", [False, True])
 @pytest.mark.parametrize("outside", [False, True])
-def test_enable_xla_cache_placement(tmp_path, outside):
-    """Unset, jax's cache goes to the fixed results/.xla_cache/xla; set,
-    $JAX_COMPILATION_CACHE_DIR is left as jax read it and the AOT layer's
-    default root moves beside it.  A fresh process each: the placement is
-    global jax config."""
-    env = ({cache_lib.ENV_JAX_CACHE_DIR: str(tmp_path / "xla")}
+def test_enable_xla_cache_placement(tmp_path, outside, explicit):
+    """A directory the caller names wins; else $JAX_COMPILATION_CACHE_DIR
+    is left as jax read it; else jax's cache goes to the fixed
+    results/.xla_cache/xla.  The AOT layer's default root stays inside the
+    checkout whatever the environment says.  A fresh process each: the
+    placement is global jax config."""
+    env = ({cache_lib.ENV_JAX_CACHE_DIR: str(tmp_path / "env")}
            if outside else {})
+    arg = repr(str(tmp_path / "named")) if explicit else ""
     proc = _run_env(
         "import jax\n"
         "from repro.sweep import cache as c\n"
-        "print(c.enable_xla_cache('/nonexistent/never'))\n"
+        f"print(c.enable_xla_cache({arg}))\n"
         "print(jax.config.jax_compilation_cache_dir)\n"
         "print(c.default_root())\n", **env)
     assert proc.returncode == 0, proc.stderr
     active, configured, root = proc.stdout.split()
-    if outside:
-        assert active == configured == str(tmp_path / "xla")
-        assert root == str(tmp_path)
+    in_repo = os.path.join(cache_lib.repo_root(), "results", ".xla_cache")
+    if explicit:
+        want = str(tmp_path / "named")
+    elif outside:
+        want = str(tmp_path / "env")
     else:
-        assert active == configured == "/nonexistent/never"
-        assert root == os.path.join(cache_lib.repo_root(), "results",
-                                    ".xla_cache")
+        want = os.path.join(in_repo, "xla")
+    assert active == configured == want
+    assert root == in_repo
+
+
+def test_train_library_leaves_the_cache_alone():
+    """train() turns on no persistent cache by itself: only the CLI's
+    main() (and chip_smoke.py) make that choice for the process."""
+    proc = _run_env(
+        "import jax\n"
+        "from repro.launch import train as t\n"
+        "args = t.build_parser().parse_args(\n"
+        "    ['--arch', 'paper-toy', '--reduced', '--rounds', '1',\n"
+        "     '--clients', '2', '--local-steps', '1', '--batch', '1',\n"
+        "     '--seq-len', '16', '--chunk', '1', '--log-every', '1'])\n"
+        "res = t.train(args)\n"
+        "print(sorted(res))\n"
+        "print(jax.config.jax_compilation_cache_dir)\n")
+    assert proc.returncode == 0, proc.stderr[-2000:]
+    keys, cache_dir = proc.stdout.strip().splitlines()[-2:]
+    assert keys == "['final_consensus', 'history']"
+    assert cache_dir == "None"
 
 
 def test_trainer_writes_its_cache_only_where_jax_is_told(tmp_path):

@@ -22,20 +22,12 @@ K_STEPS = 8
 @pytest.fixture(scope="module")
 def topo():
     from jax.experimental import topologies
-    from jax.experimental.compilation_cache import compilation_cache
 
-    # a TPU executable written to the persistent cache could not be read
-    # back on this host: keep these compiles out of it
-    was = jax.config.jax_enable_compilation_cache
-    jax.config.update("jax_enable_compilation_cache", False)
-    compilation_cache.reset_cache()
     try:
-        yield topologies.get_topology_desc(platform="tpu",
-                                           topology_name="v5e:2x2")
+        return topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
     except Exception as e:
         pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
-    finally:
-        jax.config.update("jax_enable_compilation_cache", was)
 
 
 @pytest.fixture(scope="module")
