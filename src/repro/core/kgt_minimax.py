@@ -239,6 +239,12 @@ def make_round_step(
     and the round epilogue runs the neighbor-gather kernel
     (``kernels.ops.sparse_gossip_round``) — O(n·max_deg·D) per round with no
     (n, n) materialization anywhere.
+
+    The round names its parts with ``jax.named_scope``, which only adds
+    ``op_name`` metadata: ``kgt.grads`` (each local step's gradient oracle,
+    forward and backward), ``kgt.local_update`` (``g + c`` and the SGDA
+    step) and ``kgt.epilogue`` (Δ to the new state, every lowering but
+    ``fused_round``, whose kernel runs under none).
     """
     if traced_etas and lr_scale is not None:
         raise ValueError(
@@ -402,41 +408,12 @@ def make_round_step(
         return (new_state if mask is None
                 else _freeze_inactive(mask, new_state, state))
 
-    def _round(state: KGTState, batches, keys,
-               eta_cx, eta_cy, eta_sx, eta_sy, corr_x, corr_y,
-               w_t=None, mask=None, adv=None) -> KGTState:
-        if packed or sparse_w or robust or dynamic_w or fused:
-            if w_t is None:
-                w_t = get_w(state.round)
-            if mask is not None:
-                w_t = (sparse_lib.sparse_masked_w(w_t, mask) if sparse_w
-                       else stoch_lib.masked_w(w_t, mask))
-            mix = (None if packed or sparse_w or robust or fused
-                   else (lambda tree: traced_mix(tree, w_t)))
-        else:
-            mix = make_mix(state.round)
-
-        if fused:
-            # the local steps live inside the kernel — skip the scan below
-            return _fused_round(state, batches, keys, w_t, mask,
-                                eta_cx, eta_cy, eta_sx, eta_sy,
-                                corr_x, corr_y)
-
-        def local_step(carry, inp):
-            xx, yy = carry
-            batch_k, key_k = inp
-            gx, gy = grads_v(xx, yy, batch_k, key_k)
-            gx = _tree_axpy(1.0, state.cx, gx) if track else gx   # g + c
-            gy = _tree_axpy(1.0, state.cy, gy) if track else gy
-            xx = _tree_axpy(-eta_cx, gx, xx)
-            yy = _tree_axpy(eta_cy, gy, yy)
-            return (xx, yy), None
-
-        # slice exactly k_steps from the provided K-stacked batch
-        bat = jax.tree.map(lambda b: b[:k_steps], batches)
-        kk = jax.tree.map(lambda b: b[:k_steps], keys)
-        (xk, yk), _ = jax.lax.scan(local_step, (state.x, state.y), (bat, kk))
-
+    def _epilogue(state: KGTState, xk, yk, w_t, mix, mask, adv,
+                  eta_sx, eta_sy, corr_x, corr_y) -> KGTState:
+        """Algorithm 1, lines 6-11, for every lowering but the whole-round
+        kernel: Δ = x^K − x from the local steps' end point, then the
+        correction update and the parameter mixing, then the freeze of
+        inactive clients."""
         dx = _tree_sub(xk, state.x)   # Δx = x^{(t)+K} − x^{(t)}
         dy = _tree_sub(yk, state.y)
         if adv is not None:
@@ -455,6 +432,15 @@ def make_round_step(
             dx = _tree_mask_clients(mask, dx)
             dy = _tree_mask_clients(mask, dy)
 
+        new_state = _mix_round(state, dx, dy, w_t, mix, mask,
+                               eta_sx, eta_sy, corr_x, corr_y)
+        return (new_state if mask is None
+                else _freeze_inactive(mask, new_state, state))
+
+    def _mix_round(state: KGTState, dx, dy, w_t, mix, mask,
+                   eta_sx, eta_sy, corr_x, corr_y) -> KGTState:
+        """Lines 7-11 from this round's (attacked, masked) Δ, per lowering;
+        inactive clients are frozen by the caller."""
         if robust:
             # Robust-aggregation epilogue: R replaces every W contraction.
             # R is nonlinear, so the parameter update is the one-pass
@@ -493,11 +479,9 @@ def make_round_step(
                 cy = packing.unpack(cyb, spec_cy)
             else:
                 cx, cy = state.cx, state.cy
-            new_state = KGTState(
+            return KGTState(
                 x=packing.unpack(xb, spec_x), y=packing.unpack(yb, spec_y),
                 cx=cx, cy=cy, round=state.round + 1)
-            return (new_state if mask is None
-                    else _freeze_inactive(mask, new_state, state))
 
         if sparse:
             # Sparse whole-state lowering: same fused epilogue as the packed
@@ -513,11 +497,9 @@ def make_round_step(
                 yb = sparse_lib.sparse_mix(
                     w_t, packing.pack(state.y, spec_y)
                     + eta_sy * packing.pack(dy, spec_y), gossip_dtype=pack_gd)
-                new_state = KGTState(
+                return KGTState(
                     x=packing.unpack(xb, spec_x), y=packing.unpack(yb, spec_y),
                     cx=state.cx, cy=state.cy, round=state.round + 1)
-                return (new_state if mask is None
-                        else _freeze_inactive(mask, new_state, state))
             spec_cx = packing.pack_spec(state.cx)
             spec_cy = packing.pack_spec(state.cy)
             xb, cxb = kernel_ops.sparse_gossip_round(
@@ -530,14 +512,12 @@ def make_round_step(
                 packing.pack(dy, spec_y), packing.pack(state.y, spec_y),
                 packing.pack(state.cy, spec_cy), eta_sy, corr_y,
                 backend=gossip_backend, gossip_dtype=cfg.gossip_dtype)
-            new_state = KGTState(
+            return KGTState(
                 x=packing.unpack(xb, spec_x),
                 y=packing.unpack(yb, spec_y),
                 cx=packing.unpack(cxb, spec_cx),
                 cy=packing.unpack(cyb, spec_cy),
                 round=state.round + 1)
-            return (new_state if mask is None
-                    else _freeze_inactive(mask, new_state, state))
 
         if packed:
             # Whole-state lowering: ravel each variable into one (n, D)
@@ -576,12 +556,10 @@ def make_round_step(
                 yb = mixing_lib.mix_dense(
                     packing.pack(state.y, spec_y) + eta_sy * dyb,
                     w_t, gossip_dtype=pack_gd)
-                new_state = KGTState(
+                return KGTState(
                     x=packing.unpack(xb, spec_x), y=packing.unpack(yb, spec_y),
                     cx=state.cx, cy=state.cy, round=state.round + 1,
                     ef_x=efx, ef_y=efy)
-                return (new_state if mask is None
-                        else _freeze_inactive(mask, new_state, state))
             spec_cx = packing.pack_spec(state.cx)
             spec_cy = packing.pack_spec(state.cy)
             # pack() builds fresh buffers each round, so their storage can
@@ -597,15 +575,13 @@ def make_round_step(
                 packing.pack(state.cy, spec_cy), eta_sy, corr_y,
                 backend=gossip_backend, gossip_dtype=cfg.gossip_dtype,
                 donate=True)
-            new_state = KGTState(
+            return KGTState(
                 x=packing.unpack(xb, spec_x),
                 y=packing.unpack(yb, spec_y),
                 cx=packing.unpack(cxb, spec_cx),
                 cy=packing.unpack(cyb, spec_cy),
                 round=state.round + 1,
                 ef_x=efx, ef_y=efy)
-            return (new_state if mask is None
-                    else _freeze_inactive(mask, new_state, state))
 
         # Algorithm 1 communicates two quantities per variable per round:
         # Δ (lines 7-8) and the parameters (lines 10-11).  The faithful
@@ -640,10 +616,49 @@ def make_round_step(
         x_new = _tree_axpy(eta_sx, mdx, mx)
         y_new = _tree_axpy(eta_sy, mdy, my)
 
-        new_state = KGTState(x=x_new, y=y_new, cx=cx, cy=cy,
-                             round=state.round + 1)
-        return (new_state if mask is None
-                else _freeze_inactive(mask, new_state, state))
+        return KGTState(x=x_new, y=y_new, cx=cx, cy=cy,
+                        round=state.round + 1)
+
+    def _round(state: KGTState, batches, keys,
+               eta_cx, eta_cy, eta_sx, eta_sy, corr_x, corr_y,
+               w_t=None, mask=None, adv=None) -> KGTState:
+        if packed or sparse_w or robust or dynamic_w or fused:
+            if w_t is None:
+                w_t = get_w(state.round)
+            if mask is not None:
+                w_t = (sparse_lib.sparse_masked_w(w_t, mask) if sparse_w
+                       else stoch_lib.masked_w(w_t, mask))
+            mix = (None if packed or sparse_w or robust or fused
+                   else (lambda tree: traced_mix(tree, w_t)))
+        else:
+            mix = make_mix(state.round)
+
+        if fused:
+            # the local steps live inside the kernel — skip the scan below
+            return _fused_round(state, batches, keys, w_t, mask,
+                                eta_cx, eta_cy, eta_sx, eta_sy,
+                                corr_x, corr_y)
+
+        def local_step(carry, inp):
+            xx, yy = carry
+            batch_k, key_k = inp
+            with jax.named_scope("kgt.grads"):
+                gx, gy = grads_v(xx, yy, batch_k, key_k)
+            with jax.named_scope("kgt.local_update"):
+                if track:   # g + c
+                    gx = _tree_axpy(1.0, state.cx, gx)
+                    gy = _tree_axpy(1.0, state.cy, gy)
+                xx = _tree_axpy(-eta_cx, gx, xx)
+                yy = _tree_axpy(eta_cy, gy, yy)
+            return (xx, yy), None
+
+        # slice exactly k_steps from the provided K-stacked batch
+        bat = jax.tree.map(lambda b: b[:k_steps], batches)
+        kk = jax.tree.map(lambda b: b[:k_steps], keys)
+        (xk, yk), _ = jax.lax.scan(local_step, (state.x, state.y), (bat, kk))
+        with jax.named_scope("kgt.epilogue"):
+            return _epilogue(state, xk, yk, w_t, mix, mask, adv,
+                             eta_sx, eta_sy, corr_x, corr_y)
 
     n_extras = int(traced_w) + int(participation) + int(byzantine)
     extras_doc = "".join(

@@ -21,6 +21,14 @@ This engine compiles **R-round chunks as a single XLA program**:
     inside ``round_step``), and the metrics gating are all functions of it,
     so a restored checkpoint resumes the identical trajectory.
 
+Observability: the chunk program names its parts with ``jax.named_scope``
+(``engine.sampler``, ``engine.metrics``; the round step adds its own
+``kgt.*`` scopes), which only adds ``op_name`` metadata to the compiled
+instructions, and :func:`run` wraps its host phases in telemetry spans
+(``engine.dispatch``, ``engine.readback``, ``engine.hooks``,
+``engine.compile``), which are ``jax.profiler`` annotations on the device
+trace's clock — see docs/architecture.md, "Observability".
+
 Layering: this module is algorithm- and problem-agnostic — it only needs a
 ``round_step(state, batches, keys) -> state`` with an integer
 ``state.round`` field, a sampler, and (optionally) a metrics function
@@ -39,6 +47,8 @@ from typing import Any, Callable, Dict, List, Optional, Sequence, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
+
+from repro import obs
 
 # (round_idx) -> (batches, keys) or (batches, keys, extras): a sampler may
 # return a third element — a tuple of per-round traced operands (a sampled
@@ -79,7 +89,8 @@ def chunk_program(
 
     def chunk_step(state, final_round):
         def body(st, _):
-            batches, keys, extras = split_sampled(sampler(st.round))
+            with jax.named_scope("engine.sampler"):
+                batches, keys, extras = split_sampled(sampler(st.round))
             new_st = round_step(st, batches, keys, *extras)
             if metrics_fn is None:
                 return new_st, None
@@ -87,8 +98,9 @@ def chunk_program(
                                     st.round == final_round)
             shapes = jax.eval_shape(metrics_fn, new_st, batches)
             zeros = jax.tree.map(lambda s: jnp.zeros(s.shape, s.dtype), shapes)
-            row = jax.lax.cond(
-                do_log, lambda: metrics_fn(new_st, batches), lambda: zeros)
+            with jax.named_scope("engine.metrics"):
+                row = jax.lax.cond(
+                    do_log, lambda: metrics_fn(new_st, batches), lambda: zeros)
             return new_st, (do_log, st.round, row)
 
         state, buf = jax.lax.scan(body, state, None, length=length)
@@ -155,7 +167,8 @@ def timed_chunk_builder(build_chunk: Callable[[int], Any], *,
     whole first call — compile *and* its one execution — is attributed to
     ``compile_s``; for the multi-second XLA programs this wrapper exists to
     time, the execution share of that first call is noise.  A failed
-    compile raises.
+    compile raises.  The first call's compile runs inside the profiler
+    annotation ``engine.compile``.
     """
     wrapped: Dict[int, Any] = {}
     stats = {"compile_s": 0.0}
@@ -167,20 +180,20 @@ def timed_chunk_builder(build_chunk: Callable[[int], Any], *,
         holder: List[Any] = []
 
         def call(*args):
-            if not holder:
+            if holder:
+                return holder[0](*args)
+            t0 = time.perf_counter()
+            with obs.NULL.span("engine.compile"):
                 if cache is not None:
                     compiled, info = cache.get_or_compile(
                         "chunk", (statics, ("length", length)), fn, args)
                     stats["compile_s"] += (info["compile_s"]
                                            + info["deserialize_s"])
                     holder.append(compiled)
-                    return holder[0](*args)
-                t0 = time.perf_counter()
-                lower = getattr(fn, "lower", None)
-                if lower is not None:
+                elif getattr(fn, "lower", None) is not None:
                     # a compile error propagates: retrying inside a plain
                     # call would only compile (and fail) a second time
-                    holder.append(lower(*args).compile())
+                    holder.append(fn.lower(*args).compile())
                     stats["compile_s"] += time.perf_counter() - t0
                 else:
                     holder.append(fn)
@@ -262,11 +275,14 @@ def run(
     ``compile_s`` ≈ 0.
 
     ``telemetry`` (a ``repro.obs.events.Telemetry``, or anything with the
-    same ``span``/``span_event`` surface) wraps each chunk's dispatch and
-    metrics read-back in monotonic-clock spans and emits a ``compile`` span
-    whenever a chunk incurred XLA compilation.  ``None`` (the default) is
-    the zero-overhead path: no telemetry object is ever touched and the
-    executed program is byte-identical to pre-telemetry behavior.
+    same ``span``/``span_event`` surface; ``None`` means ``obs.NULL``) names
+    the host phases of each chunk: ``engine.dispatch`` (the call that
+    enqueues it), ``engine.readback`` (the metrics read-back and the
+    ``wall_clock`` wait: where the host waits for the chunk) and
+    ``engine.hooks``, plus an ``engine.compile`` span event whenever a
+    chunk incurred XLA compilation.  Spans are profiler annotations; only
+    with sinks do they also read the clock and emit events.  Neither
+    changes the executed program.
     """
     chunk_rounds = max(int(chunk_rounds), 1)
     if hasattr(build_chunk, "stats"):
@@ -282,6 +298,8 @@ def run(
                 build_chunk._timed = build
             except AttributeError:
                 pass
+    if telemetry is None:
+        telemetry = obs.NULL
     history: List[dict] = []
     start = int(state.round)
     final_round = jnp.int32(total_rounds - 1)
@@ -293,25 +311,23 @@ def run(
         if boundary_every:
             next_boundary = (r // boundary_every + 1) * boundary_every
             length = min(length, next_boundary - r)
-        if telemetry is None:
+        comp_prev = build.stats["compile_s"]
+        with telemetry.span("engine.dispatch", round=r, length=length):
             state, buf = build(length)(state, final_round)
+        comp_delta = build.stats["compile_s"] - comp_prev
+        if comp_delta > 0:
+            # compilation happens inside the first call at each length
+            # (timed_chunk_builder's AOT path) — surface it as its own
+            # span so dispatch time reads as steady-state
+            telemetry.span_event("engine.compile", comp_delta,
+                                 round=r, length=length)
+        with telemetry.span("engine.readback", round=r):
             records = records_from_buffer(buf)
-        else:
-            comp_prev = build.stats["compile_s"]
-            with telemetry.span("dispatch", round=r, length=length):
-                state, buf = build(length)(state, final_round)
-            comp_delta = build.stats["compile_s"] - comp_prev
-            if comp_delta > 0:
-                # compilation happens inside the first call at each length
-                # (timed_chunk_builder's AOT path) — surface it as its own
-                # span so dispatch time reads as steady-state
-                telemetry.span_event("compile", comp_delta,
-                                     round=r, length=length)
-            with telemetry.span("readback", round=r):
-                records = records_from_buffer(buf)
+            if wall_clock:
+                # the stamp covers the chunk's device work, not just its
+                # enqueue
+                jax.block_until_ready(state)
         if wall_clock:
-            # the stamp covers the chunk's device work, not just its enqueue
-            jax.block_until_ready(state)
             wall = time.perf_counter() - t0
             # only compilation incurred by THIS run: the builder (and its
             # stats) may be shared across runs, while t0 is per-run
@@ -325,8 +341,9 @@ def run(
                 rec["compile_s"] = round(comp, 3)
                 rec["run_s"] = round(max(wall - comp, 0.0), 3)
         history.extend(records)
-        for hook in hooks:
-            hook(state, records, r)
+        with telemetry.span("engine.hooks", round=r):
+            for hook in hooks:
+                hook(state, records, r)
         r += length
         if stop_fn is not None and stop_fn(records):
             break

@@ -11,9 +11,10 @@ and opt-in profiler capture (see ``docs/architecture.md``, "Observability").
                into a time/communication/convergence summary.
 
 Everything here is host-side and strictly opt-in: a run that does not
-construct a sink dispatches nothing extra and its trajectory is
-bit-identical to a run that never imported this package
-(tests/test_obs.py pins that).
+construct a sink dispatches nothing extra, its spans are only
+``jax.profiler`` annotations (no-ops unless a trace is capturing), and its
+trajectory is bit-identical to a run with sinks (tests/test_obs.py pins
+that).
 """
 from repro.obs.events import (  # noqa: F401
     EVENT_TYPES,

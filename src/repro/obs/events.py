@@ -10,9 +10,9 @@ Type-specific fields:
 
 * ``span``    — ``name`` + ``dur_s`` (monotonic-clock duration; extra
   attributes ride alongside, e.g. ``round``/``length`` for a chunk
-  dispatch).  Spans come from the ``with telemetry.span("dispatch"): …``
-  context manager or, for durations measured elsewhere (XLA compile time
-  accumulated by ``engine.timed_chunk_builder``), from
+  dispatch).  Spans come from the ``with telemetry.span("engine.dispatch"):
+  …`` context manager or, for durations measured elsewhere (XLA compile
+  time accumulated by ``engine.timed_chunk_builder``), from
   :meth:`Telemetry.span_event`.
 * ``counter`` — ``name`` + ``value`` (a monotonically accumulated quantity:
   bytes communicated, rounds executed).
@@ -24,10 +24,12 @@ Type-specific fields:
 * ``meta``    — one-shot run description (config summary, versions).
 
 Sinks are deliberately dumb: ``emit(event)`` and optional ``close()``.
-``Telemetry`` fans one event out to every sink.  A ``Telemetry`` with no
-sinks is *disabled*: every method is a cheap no-op (``span`` returns a
-shared null context manager without touching the clock), which is what the
-zero-overhead guarantee rides on.
+``Telemetry`` fans one event out to every sink.  Every span is also a
+``jax.profiler.TraceAnnotation`` of the span's name, so it lands on the
+device trace's clock whenever a profiler capture is running.  A
+``Telemetry`` with no sinks is *disabled*: a span only opens that
+annotation (a no-op unless a trace is capturing) and reads no Python clock,
+and every other method returns before building an event.
 """
 from __future__ import annotations
 
@@ -123,17 +125,13 @@ class StderrSink:
         pass
 
 
-class _NullSpan:
-    """Shared no-op context manager: the disabled-telemetry span."""
+def _annotation(name: str):
+    """The profiler annotation of a span: the fixed name alone, since
+    ``TraceAnnotation`` keyword arguments become part of the recorded name
+    and would split one label into one per call."""
+    from jax.profiler import TraceAnnotation
 
-    def __enter__(self):
-        return self
-
-    def __exit__(self, *exc):
-        return False
-
-
-_NULL_SPAN = _NullSpan()
+    return TraceAnnotation(name)
 
 
 class _Span:
@@ -141,14 +139,17 @@ class _Span:
         self._telemetry = telemetry
         self._name = name
         self._attrs = attrs
+        self._annotation = _annotation(name)
         self._t0 = 0.0
 
     def __enter__(self):
+        self._annotation.__enter__()
         self._t0 = time.monotonic()
         return self
 
     def __exit__(self, *exc):
         dur = time.monotonic() - self._t0
+        self._annotation.__exit__(*exc)
         self._telemetry.span_event(self._name, dur, **self._attrs)
         return False
 
@@ -173,10 +174,11 @@ class Telemetry:
             sink.emit(event)
 
     def span(self, name: str, **attrs):
-        """``with telemetry.span("dispatch", round=r): …`` — emits a span
-        event with the monotonic-clock duration on exit."""
+        """``with telemetry.span("engine.dispatch", round=r): …`` — a
+        profiler annotation named ``name``; with sinks, also a span event
+        with the monotonic-clock duration and ``attrs`` on exit."""
         if not self.sinks:
-            return _NULL_SPAN
+            return _annotation(name)
         return _Span(self, name, attrs)
 
     def span_event(self, name: str, dur_s: float, **attrs) -> None:

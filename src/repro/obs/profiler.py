@@ -5,6 +5,9 @@ N-round window: ``start()`` before ``engine.run`` opens the trace, and the
 profiler's chunk-boundary hook closes it once the requested number of
 rounds has executed (0 = the whole run, closed by ``stop()``/context exit).
 The trace lands under ``directory`` and opens in Perfetto / TensorBoard.
+It records the device ops, whose ``op_name`` carries the program's named
+scopes, and the engine's spans on the same clock; Python calls are not
+traced.
 
 :func:`health_gauges` samples the algorithm-health quantities the theory
 says to watch — host-side, from the state at a chunk boundary, so they cost
@@ -75,7 +78,11 @@ class Profiler:
         try:
             import jax.profiler
 
-            jax.profiler.start_trace(self.directory)
+            opts = jax.profiler.ProfileOptions()
+            # device ops and the engine's spans only: tracing every Python
+            # call would widen every host gap the trace is read for
+            opts.python_tracer_level = 0
+            jax.profiler.start_trace(self.directory, profiler_options=opts)
             self.active = True
         except Exception as e:  # noqa: BLE001 — never take the run down
             print(f"[obs] profiler start failed: {e!r}", flush=True)

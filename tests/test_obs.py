@@ -1,11 +1,12 @@
 """Tests of the observability subsystem (repro.obs).
 
 The load-bearing claims: telemetry off is *zero-overhead* (bit-identical
-trajectories, no extra dispatches — the engine's ``telemetry=None`` path is
-the original code path); the communication ledger's analytic bytes/round
-match hand-computed wire arithmetic for every lowering family and separate
-the lowerings in the expected ratios; and a JSONL artifact round-trips
-through ``repro.obs.report`` for every event type.
+trajectories, no extra dispatches; a sink-less span is only a profiler
+annotation and reads no clock); the program's named scopes and the engine's
+spans reach the profiler's trace; the communication ledger's analytic
+bytes/round match hand-computed wire arithmetic for every lowering family
+and separate the lowerings in the expected ratios; and a JSONL artifact
+round-trips through ``repro.obs.report`` for every event type.
 """
 import json
 import math
@@ -59,27 +60,36 @@ def _assert_states_equal(a, b, context=""):
 # ---------------------------------------------------------------- events
 
 
-def test_disabled_telemetry_is_noop():
-    """A sink-less Telemetry must never touch the clock or build objects:
-    span() returns the shared null context manager, emit/metrics return
-    before stamping."""
+def test_disabled_telemetry_is_noop(monkeypatch):
+    """A sink-less Telemetry touches no sink and reads no Python clock: a
+    span only opens the profiler annotation of its fixed name (a no-op
+    unless a trace is capturing), and emit/metrics return before stamping."""
+    from repro.obs import events
+
+    class NoClock:
+        def __getattr__(self, name):
+            raise AssertionError(f"a disabled telemetry read time.{name}")
+
+    monkeypatch.setattr(events, "time", NoClock())
     tel = obs.Telemetry(())
     assert not tel.enabled
-    s1, s2 = tel.span("dispatch"), tel.span("readback", round=3)
-    assert s1 is s2  # the shared _NULL_SPAN, not a fresh object
-    with s1:
-        pass
+    for t in (tel, obs.NULL):
+        span = t.span("engine.readback", round=3)
+        assert isinstance(span, jax.profiler.TraceAnnotation)
+        with span:
+            pass
+    tel.span_event("engine.compile", 1.0)
     tel.metrics({"round": 0})
     tel.counter("bytes", 10)
     tel.gauge("g", 1.0)
     tel.close()
-    assert obs.NULL.span("x") is s1
+    assert tel.sinks == [] and obs.NULL.sinks == []
 
 
 def test_telemetry_stamps_and_fans_out():
     a, b = obs.MemorySink(), obs.MemorySink()
     tel = obs.Telemetry([a, b])
-    with tel.span("dispatch", round=2, length=4):
+    with tel.span("engine.dispatch", round=2, length=4):
         pass
     tel.counter("rounds", 4)
     tel.gauge("consensus_x", 0.5, round=4)
@@ -91,7 +101,7 @@ def test_telemetry_stamps_and_fans_out():
         assert ev["type"] in ("span", "counter", "gauge", "metrics", "meta")
         assert "t" in ev
     span = a.events[0]
-    assert span["name"] == "dispatch" and span["dur_s"] >= 0
+    assert span["name"] == "engine.dispatch" and span["dur_s"] >= 0
     assert span["round"] == 2 and span["length"] == 4
     assert a.events[3]["f_bar"] == 1.25
 
@@ -136,6 +146,101 @@ def test_engine_bit_identical_with_telemetry_on():
     types = {ev["type"] for ev in sink.events}
     assert {"span", "metrics", "ledger", "gauge"} <= types
     assert ledger.rounds == 10
+
+
+ENGINE_SPANS = ("engine.dispatch", "engine.readback", "engine.hooks",
+                "engine.compile")
+#: The named scopes of the chunk program (docs/architecture.md).
+SCOPES = ("engine.sampler", "kgt.grads", "kgt.local_update", "kgt.epilogue",
+          "engine.metrics")
+
+
+def _trace_span_counts(directory):
+    """{span name: count} of the engine's annotations on the host planes of
+    the one trace under ``directory``."""
+    import glob
+    import os
+
+    (path,) = glob.glob(os.path.join(directory, "plugins", "profile", "*",
+                                     "*.xplane.pb"))
+    counts = {}
+    for plane in jax.profiler.ProfileData.from_file(path).planes:
+        if not plane.name.startswith("/host:"):
+            continue
+        for line in plane.lines:
+            for ev in line.events:
+                if ev.name.startswith("engine."):
+                    counts[ev.name] = counts.get(ev.name, 0) + 1
+    return counts
+
+
+@pytest.mark.parametrize("with_sinks", [False, True])
+def test_engine_spans_reach_the_profiler_trace(tmp_path, with_sinks):
+    """Each chunk records engine.dispatch, engine.readback and engine.hooks
+    once on the profiler's clock, with sinks or without; engine.compile is
+    recorded on the first call only, in the trace and, with sinks, as one
+    span event under the same name."""
+    prob, cfg, st, step, sampler = _setup()
+    build = engine_lib.make_chunk_builder(
+        step, sampler, engine_lib.quadratic_metrics_fn(prob), log_every=2,
+        donate=False)
+    sink = obs.MemorySink()
+    tel = obs.Telemetry([sink]) if with_sinks else None
+    seen = []
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        st, _ = engine_lib.run(st, build, total_rounds=8, chunk_rounds=4,
+                               hooks=[lambda *a: seen.append(a[2])],
+                               telemetry=tel)
+        # a second run on the same builder reuses the compiled chunk
+        engine_lib.run(st, build, total_rounds=16, chunk_rounds=4,
+                       hooks=[lambda *a: seen.append(a[2])], telemetry=tel)
+    finally:
+        jax.profiler.stop_trace()
+    assert seen == [0, 4, 8, 12]
+    counts = _trace_span_counts(str(tmp_path))
+    assert counts == {"engine.dispatch": 4, "engine.readback": 4,
+                      "engine.hooks": 4, "engine.compile": 1}
+    events = [(e["name"], e.get("round")) for e in sink.events
+              if e["type"] == "span"]
+    if with_sinks:
+        assert sorted(set(n for n, _ in events)) == sorted(ENGINE_SPANS)
+        assert [r for n, r in events if n == "engine.compile"] == [0]
+        assert [r for n, r in events if n == "engine.hooks"] == [0, 4, 8, 12]
+    else:
+        assert events == []
+
+
+def test_chunk_program_carries_the_named_scopes(monkeypatch):
+    """The compiled chunk program of --reduced paper-toy has instructions
+    under each of the five scopes, and backward ones (``transpose(``)
+    under kgt.grads: what a device trace's ops are attributed by."""
+    import re
+
+    from repro.launch import train as train_lib
+
+    texts = []
+    original = jax.stages.Lowered.compile
+
+    def compile_and_keep(lowered, *a, **kw):
+        compiled = original(lowered, *a, **kw)
+        texts.append(compiled.as_text())
+        return compiled
+
+    monkeypatch.setattr(jax.stages.Lowered, "compile", compile_and_keep)
+    args = train_lib.build_parser().parse_args([
+        "--arch", "paper-toy", "--reduced", "--clients", "2",
+        "--local-steps", "2", "--batch", "2", "--seq-len", "32",
+        "--rounds", "2", "--chunk", "2", "--log-every", "2",
+        "--engine", "scan", "--mesh", "host", "--mixing-impl", "dense"])
+    train_lib.train(args)
+    (text,) = [t for t in texts if t.startswith("HloModule jit_chunk_step")]
+    op_names = re.findall(r'op_name="([^"]*)"', text)
+    for scope in SCOPES:
+        assert any(f"/{scope}/" in n for n in op_names), scope
+    grads = [n for n in op_names if "/kgt.grads/" in n]
+    assert any("transpose(" in n for n in grads)
+    assert any("transpose(" not in n for n in grads)
 
 
 def test_telemetry_hook_emits_per_boundary():
@@ -286,8 +391,8 @@ def test_jsonl_roundtrip_every_event_type(tmp_path):
     path = str(tmp_path / "run.jsonl")
     tel = obs.Telemetry([obs.JsonlSink(path)])
     tel.meta("train", arch="toy", n=4)
-    tel.span_event("compile", 1.5, round=0)
-    with tel.span("dispatch", round=0, length=4):
+    tel.span_event("engine.compile", 1.5, round=0)
+    with tel.span("engine.dispatch", round=0, length=4):
         pass
     tel.counter("chunks", 1)
     tel.gauge("consensus_x", 0.25, round=4)
@@ -306,8 +411,8 @@ def test_jsonl_roundtrip_every_event_type(tmp_path):
     assert all(isinstance(e["t"], float) for e in events)
     s = report.summarize(events)
     assert s["num_events"] == 8
-    assert s["spans"]["compile"] == {"count": 1, "total_s": 1.5}
-    assert s["spans"]["dispatch"]["count"] == 1
+    assert s["spans"]["engine.compile"] == {"count": 1, "total_s": 1.5}
+    assert s["spans"]["engine.dispatch"]["count"] == 1
     assert s["counters"]["chunks"] == {"count": 1, "sum": 1.0}
     assert s["gauges"]["consensus_x"] == 0.25
     assert s["meta"]["arch"] == "toy"
@@ -455,7 +560,8 @@ def test_train_telemetry_artifact_and_zero_overhead(tmp_path):
 
     s = report.summarize(report.load(str(path)))
     assert s["meta"]["arch"].startswith("qwen2-0.5b")  # the reduced variant
-    assert "dispatch" in s["spans"] and "compile" in s["spans"]
+    assert {"engine.dispatch", "engine.readback", "engine.hooks",
+            "engine.compile"} <= set(s["spans"])
     assert s["num_metric_rows"] == len(res_tel["history"])
     assert {"corr_x_drift", "consensus_x"} <= set(s["gauges"])
     led = s["ledger"]
