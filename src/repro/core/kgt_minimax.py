@@ -185,6 +185,7 @@ def make_round_step(
     traced_w: bool = False,
     participation: bool = False,
     byzantine: bool = False,
+    clients_sharded: bool = False,
 ):
     """Builds round_step(state, batches, keys) -> state.
 
@@ -245,6 +246,10 @@ def make_round_step(
     forward and backward), ``kgt.local_update`` (``g + c`` and the SGDA
     step) and ``kgt.epilogue`` (Δ to the new state, every lowering but
     ``fused_round``, whose kernel runs under none).
+
+    ``clients_sharded=True`` says the state's client axis is split across a
+    mesh's devices: the dense gossip of a static or cycled W then stays one
+    contraction (one all-gather) at every n, ``mixing.mix_dense_sharded``.
     """
     if traced_etas and lr_scale is not None:
         raise ValueError(
@@ -299,6 +304,8 @@ def make_round_step(
     packed = cfg.mixing_impl == "pallas_packed"
     pack_gd = (None if cfg.gossip_dtype in (None, "float32")
                else jnp.dtype(cfg.gossip_dtype))
+    dense_mix = (mixing_lib.mix_dense_sharded if clients_sharded
+                 else mixing_lib.mix_dense)
     if dynamic_w and not packed and not sparse and not robust and not fused:
         # validates the impl (ring-style neighbor exchanges cannot realize a
         # per-round arbitrary W) and gives us mix(tree, w) with w traced
@@ -314,7 +321,7 @@ def make_round_step(
 
         def make_mix(round_idx):
             w_t = get_w(round_idx)
-            return lambda tree: mixing_lib.mix_dense(tree, w_t, gossip_dtype=gd)
+            return lambda tree: dense_mix(tree, w_t, gossip_dtype=gd)
     else:
         if w is None and not traced_w:
             w = (sparse_lib.sparse_mixing_matrix(cfg.topology, cfg.num_clients)
@@ -331,7 +338,8 @@ def make_round_step(
             make_mix = None  # W is consumed directly, per round
         else:
             static_mix = mixing_lib.make_mixer(
-                cfg.topology, cfg.mixing_impl, w, cfg.gossip_dtype)
+                cfg.topology, cfg.mixing_impl, w, cfg.gossip_dtype,
+                clients_sharded=clients_sharded)
             make_mix = lambda round_idx: static_mix
     gossip_backend = kernel_ops.resolve_gossip_backend(cfg.gossip_backend)
     algo = cfg.algorithm
@@ -550,12 +558,10 @@ def make_round_step(
                 # gossip of the already-stepped parameters, W(θ + η_s·Δ) —
                 # don't move (n, D) correction buffers through the kernel
                 # just to multiply them by zero
-                xb = mixing_lib.mix_dense(
-                    packing.pack(state.x, spec_x) + eta_sx * dxb,
-                    w_t, gossip_dtype=pack_gd)
-                yb = mixing_lib.mix_dense(
-                    packing.pack(state.y, spec_y) + eta_sy * dyb,
-                    w_t, gossip_dtype=pack_gd)
+                xb = dense_mix(packing.pack(state.x, spec_x) + eta_sx * dxb,
+                               w_t, gossip_dtype=pack_gd)
+                yb = dense_mix(packing.pack(state.y, spec_y) + eta_sy * dyb,
+                               w_t, gossip_dtype=pack_gd)
                 return KGTState(
                     x=packing.unpack(xb, spec_x), y=packing.unpack(yb, spec_y),
                     cx=state.cx, cy=state.cy, round=state.round + 1,

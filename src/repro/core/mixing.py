@@ -2,10 +2,12 @@
 
 Two lowering strategies for ``Σ_j w_ij T_j``:
 
-* ``dense`` — einsum with the full (n, n) mixing matrix W.  Faithful to the
-  paper (arbitrary topology); under GSPMD the contraction over the sharded
-  clients dim lowers to an all-gather of the full tensor, (n-1)·|T| bytes in
-  per client.
+* ``dense`` — the full (n, n) mixing matrix W.  Faithful to the paper
+  (arbitrary topology).  Up to ``UNROLL_MAX_CLIENTS`` clients on one device
+  it is a weighted sum of client slices, above that an einsum; across a
+  mesh's clients axis (``mix_dense_sharded``) always the einsum, whose
+  contraction over the sharded clients dim lowers to an all-gather of the
+  full tensor, (n-1)·|T| bytes in per client.
 * ``ring`` — neighbor-only exchange expressed as ``jnp.roll`` along the
   clients dim, which GSPMD lowers to collective-permutes over the clients
   mesh axis (2·|T| bytes in per client).  Valid for the ring topology (and
@@ -42,25 +44,58 @@ def _cast(tree, dtype):
     return jax.tree.map(lambda x: x.astype(dtype), tree)
 
 
-def mix_dense(tree: Any, w, gossip_dtype=None) -> Any:
-    """tree leaves: (n, ...) -> W @ leaves."""
-    w = jnp.asarray(w, jnp.float32)
+# Largest client count whose dense gossip is an unrolled sum of n² terms.
+UNROLL_MAX_CLIENTS = 8
 
-    def one(x):
-        orig = x.dtype
-        xc = x.astype(gossip_dtype) if gossip_dtype is not None else x
-        # einsum in the gossip dtype (keeps the all-gathered operand narrow),
-        # accumulate in f32.  HIGHEST: at its default precision a TPU rounds
-        # f32 operands to bf16, which would round every parameter to bf16
-        # each round and erase the local steps' smaller updates.
-        mixed = jnp.einsum(
-            "ij,j...->i...", w.astype(xc.dtype), xc,
-            preferred_element_type=jnp.float32,
-            precision=jax.lax.Precision.HIGHEST,
-        )
+
+def _dense_one(x, w, gossip_dtype, unroll: bool):
+    orig = x.dtype
+    xc = x.astype(gossip_dtype) if gossip_dtype is not None else x
+    wc = w.astype(xc.dtype)
+    n = x.shape[0]
+    if unroll and n <= UNROLL_MAX_CLIENTS:
+        # one f32 rounding per product and per sum, as in an f32 dot (and
+        # a bf16 gossip's products are exact): HIGHEST's precision below
+        col = (n,) + (1,) * (x.ndim - 1)
+        mixed = wc[:, 0].astype(jnp.float32).reshape(col) * xc[0].astype(
+            jnp.float32)
+        for j in range(1, n):
+            mixed = mixed + (wc[:, j].astype(jnp.float32).reshape(col)
+                             * xc[j].astype(jnp.float32))
         return mixed.astype(orig)
+    # einsum in the gossip dtype (keeps the all-gathered operand narrow),
+    # accumulate in f32.  HIGHEST: at its default precision a TPU rounds f32
+    # operands to bf16, which would round every parameter to bf16 each round
+    # and erase the local steps' smaller updates.
+    mixed = jnp.einsum(
+        "ij,j...->i...", wc, xc,
+        preferred_element_type=jnp.float32,
+        precision=jax.lax.Precision.HIGHEST,
+    )
+    return mixed.astype(orig)
 
-    return jax.tree.map(one, tree)
+
+def mix_dense(tree: Any, w, gossip_dtype=None) -> Any:
+    """tree leaves: (n, ...) -> W @ leaves.
+
+    Up to ``UNROLL_MAX_CLIENTS`` clients, ``W @ x`` is the weighted sum of
+    client slices ``Σ_j W[:, j] ⊗ x[j]``, elementwise along the leaves' own
+    axes: a contraction over the short client axis makes the TPU's layout
+    assignment move that axis into the tiled minor dims, so the state the
+    round loop carries would change layout twice a round.  Above it the n²
+    unrolled terms would not pay, and the gossip is one einsum.
+    """
+    w = jnp.asarray(w, jnp.float32)
+    return jax.tree.map(lambda x: _dense_one(x, w, gossip_dtype, True), tree)
+
+
+def mix_dense_sharded(tree: Any, w, gossip_dtype=None) -> Any:
+    """``mix_dense`` as one einsum at every n, for leaves whose client axis
+    is split across devices: there the contraction lowers to one all-gather
+    per leaf, where slicing the sharded axis would take n − 1
+    collective-permutes."""
+    w = jnp.asarray(w, jnp.float32)
+    return jax.tree.map(lambda x: _dense_one(x, w, gossip_dtype, False), tree)
 
 
 def mix_ring(tree: Any, w_self: float, w_nbr: float, gossip_dtype=None) -> Any:
@@ -228,8 +263,12 @@ MIXING_IMPLS = ("dense", "ring", "fused_dense", "fused_ring", "pallas_packed",
 
 
 def make_mixer(topology: str, impl: str, w: np.ndarray,
-               gossip_dtype: str = "float32", *, trim: int = 1):
-    """Returns mix(tree) -> tree for the configured implementation."""
+               gossip_dtype: str = "float32", *, trim: int = 1,
+               clients_sharded: bool = False):
+    """Returns mix(tree) -> tree for the configured implementation.
+    ``clients_sharded``: the leaves' client axis is split across devices
+    (the dense gossip then stays one contraction, see
+    :func:`mix_dense_sharded`)."""
     if impl not in MIXING_IMPLS:
         raise ValueError(f"unknown mixing_impl {impl!r}: {MIXING_IMPLS}")
     gd = None if gossip_dtype in (None, "float32") else jnp.dtype(gossip_dtype)
@@ -264,7 +303,9 @@ def make_mixer(topology: str, impl: str, w: np.ndarray,
         raise ValueError(
             "mixing_impl='fused_round' has no standalone mixer; it is "
             "routed whole-round by kgt_minimax.make_round_step")
-    return lambda tree: mix_dense(tree, w, gossip_dtype=gd)
+    # looked up when called, so that a replacement of either takes effect
+    return lambda tree: (mix_dense_sharded if clients_sharded
+                         else mix_dense)(tree, w, gossip_dtype=gd)
 
 
 def make_traced_mixer(impl: str, gossip_dtype: str = "float32", *,
@@ -276,8 +317,8 @@ def make_traced_mixer(impl: str, gossip_dtype: str = "float32", *,
 
     The neighbor-only ring impls hard-code the exchange pattern and cannot
     realize an arbitrary per-round W, so they raise; ``dense``/``fused_dense``
-    lower to the dense einsum and ``pallas_packed`` to the packed tree
-    contraction, both of which already take W as a runtime value.
+    lower to the dense gossip and ``pallas_packed`` to the packed tree
+    gossip, both of which already take W as a runtime value.
     """
     if impl not in MIXING_IMPLS:
         raise ValueError(f"unknown mixing_impl {impl!r}: {MIXING_IMPLS}")

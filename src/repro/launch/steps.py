@@ -66,7 +66,11 @@ def _train_parts(
         model_cfg, num_groups=minimax.num_groups, mu=minimax.mu,
         compute_dtype=jnp.bfloat16, remat=mcfg.remat)
     w = topology.mixing_matrix(algo.topology, n)
-    round_fn = kgt.make_round_step(problem, algo, w, lr_scale=lr_scale)
+    # a clients axis over devices splits the state's client dim: keep the
+    # dense gossip a contraction, one all-gather a leaf
+    round_fn = kgt.make_round_step(
+        problem, algo, w, lr_scale=lr_scale,
+        clients_sharded=dict(mesh.shape).get(sh.CLIENTS, 1) > 1)
 
     # ---- abstract state -------------------------------------------------
     x_one = jax.eval_shape(lambda k: model_lib.init_params(model_cfg, k),

@@ -91,10 +91,171 @@ def test_mix_packed_matches_per_leaf_dense():
 
 
 def test_f32_gossip_contracts_at_full_precision():
-    """f32 gossip is f32 on every backend: the contraction asks for HIGHEST
-    precision, which a TPU needs (its default rounds f32 operands to bf16,
-    which would round every parameter to bf16 each round)."""
-    w = topology.mixing_matrix("ring", 4)
-    x = {"a": jnp.ones((4, 3), jnp.float32)}
-    txt = jax.jit(lambda t: mixing.mix_dense(t, w)).lower(x).as_text()
-    assert "HIGHEST" in txt
+    """f32 gossip is f32 on every backend.  Unrolled (n <= 8), every
+    product and sum is an f32 elementwise op, which no backend rounds to
+    bf16; as a contraction (n = 9) it asks for HIGHEST precision, which a
+    TPU needs (its default rounds f32 operands to bf16, which would round
+    every parameter to bf16 each round)."""
+    import re
+
+    for n in (4, 9):
+        w = topology.mixing_matrix("ring", n)
+        x = {"a": jnp.ones((n, 3), jnp.float32)}
+        txt = jax.jit(lambda t: mixing.mix_dense(t, w)).lower(x).as_text()
+        assert "bf16" not in txt
+        if n <= mixing.UNROLL_MAX_CLIENTS:
+            ops = re.findall(r"stablehlo\.(multiply|add) .*: (tensor<\S+>)",
+                             txt)
+            assert len(ops) == 2 * n - 1
+            assert all(t.endswith("xf32>") for _, t in ops)
+        else:
+            assert "HIGHEST" in txt
+
+
+# ---------------------------------------------------------------------------
+# the dense gossip's two forms: unrolled client slices up to
+# UNROLL_MAX_CLIENTS, one contraction above
+# ---------------------------------------------------------------------------
+
+SIZES = [1, 2, 4, 8, 9]
+GOSSIP_DTYPES = [jnp.float32, jnp.bfloat16]
+W_KINDS = ["static_ring", "traced_random"]
+# (rtol, atol) against a float64 W @ x of the un-rounded inputs
+DENSE_TOL = {jnp.float32: (1e-6, 1e-6), jnp.bfloat16: (2e-2, 2e-2)}
+
+
+def _uniform_tree(n, seed):
+    rng = np.random.default_rng(seed)
+    return {"a": rng.uniform(-1, 1, (n,)).astype(np.float32),
+            "b": rng.uniform(-1, 1, (n, 5)).astype(np.float32),
+            "c": {"d": rng.uniform(-1, 1, (n, 3, 130)).astype(np.float32)}}
+
+
+def _doubly_stochastic(kind, n):
+    if kind == "static_ring":
+        return np.asarray(topology.mixing_matrix("ring", n), np.float32)
+    # a convex combination of permutation matrices
+    rng = np.random.default_rng(100 + n)
+    coef = rng.dirichlet(np.ones(3))
+    return sum(c * np.eye(n)[rng.permutation(n)]
+               for c in coef).astype(np.float32)
+
+
+def _dense_fn(kind, w, gossip_dtype):
+    """(call, lower) of jit(tree -> W @ tree), W a constant or traced."""
+    gd = None if gossip_dtype == jnp.float32 else gossip_dtype
+    if kind == "static_ring":
+        fn = jax.jit(lambda t: mixing.mix_dense(t, w, gossip_dtype=gd))
+        return fn, fn.lower
+    fn = jax.jit(lambda t, wt: mixing.mix_dense(t, wt, gossip_dtype=gd))
+    return (lambda t: fn(t, w)), (lambda t: fn.lower(t, w))
+
+
+@pytest.mark.parametrize("w_kind", W_KINDS)
+@pytest.mark.parametrize("gossip_dtype", GOSSIP_DTYPES)
+@pytest.mark.parametrize("n", SIZES)
+def test_mix_dense_matches_float64(n, gossip_dtype, w_kind):
+    w = _doubly_stochastic(w_kind, n)
+    tree = _uniform_tree(n, n)
+    call, _ = _dense_fn(w_kind, w, gossip_dtype)
+    out = call(tree)
+    rtol, atol = DENSE_TOL[gossip_dtype]
+    for x, y in zip(jax.tree.leaves(tree), jax.tree.leaves(out)):
+        ref = np.einsum("ij,j...->i...", w.astype(np.float64),
+                        x.astype(np.float64))
+        assert y.dtype == jnp.float32 and y.shape == x.shape
+        np.testing.assert_allclose(np.asarray(y), ref, rtol=rtol, atol=atol)
+
+
+@pytest.mark.parametrize("w_kind", W_KINDS)
+@pytest.mark.parametrize("gossip_dtype", GOSSIP_DTYPES)
+@pytest.mark.parametrize("n", SIZES)
+def test_mix_dense_preserves_client_mean(n, gossip_dtype, w_kind):
+    w = _doubly_stochastic(w_kind, n)
+    tree = _uniform_tree(n, 10 + n)
+    call, _ = _dense_fn(w_kind, w, gossip_dtype)
+    out = call(tree)
+    rtol, atol = ((1e-5, 1e-6) if gossip_dtype == jnp.float32
+                  else DENSE_TOL[gossip_dtype])
+    for x, y in zip(jax.tree.leaves(tree), jax.tree.leaves(out)):
+        np.testing.assert_allclose(np.asarray(y).mean(0), x.mean(0),
+                                   rtol=rtol, atol=atol)
+
+
+@pytest.mark.parametrize("w_kind", W_KINDS)
+@pytest.mark.parametrize("gossip_dtype", GOSSIP_DTYPES)
+@pytest.mark.parametrize("n", SIZES)
+def test_mix_dense_contracts_clients_only_above_unroll_limit(
+        n, gossip_dtype, w_kind):
+    """Up to UNROLL_MAX_CLIENTS the lowered gossip has no dot_general (so
+    nothing asks for a client-minor layout); above it, one per leaf."""
+    w = _doubly_stochastic(w_kind, n)
+    tree = _uniform_tree(n, 0)
+    _, lower = _dense_fn(w_kind, w, gossip_dtype)
+    txt = lower(tree).as_text()
+    n_dots = txt.count("stablehlo.dot_general")
+    if n <= mixing.UNROLL_MAX_CLIENTS:
+        assert n_dots == 0
+    else:
+        assert n_dots == len(jax.tree.leaves(tree))
+        assert "HIGHEST" in txt
+
+
+@pytest.mark.parametrize("n", [2, 4, 8])
+def test_mix_dense_sharded_keeps_the_contraction(n):
+    """The form for a client axis split across devices is one einsum at
+    every n, equal to the unrolled form to f32 rounding."""
+    w = _doubly_stochastic("static_ring", n)
+    tree = _uniform_tree(n, 3)
+    txt = jax.jit(lambda t: mixing.mix_dense_sharded(t, w)).lower(
+        tree).as_text()
+    assert txt.count("stablehlo.dot_general") == len(jax.tree.leaves(tree))
+    for a, b in zip(jax.tree.leaves(mixing.mix_dense_sharded(tree, w)),
+                    jax.tree.leaves(mixing.mix_dense(tree, w))):
+        np.testing.assert_allclose(a, b, rtol=1e-6, atol=1e-6)
+
+
+_SHARDED_GOSSIP = """
+import os, re, json
+os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
+import jax, jax.numpy as jnp, numpy as np
+from jax.sharding import NamedSharding, PartitionSpec as P
+from repro.core import mixing, topology
+mesh = jax.make_mesh((4,), ("clients",))
+w = topology.mixing_matrix("ring", 4)
+tree = {"a": jnp.ones((4, 256), jnp.float32),
+        "b": jnp.ones((4, 8, 128), jnp.float32)}
+shard = NamedSharding(mesh, P("clients"))
+out = {}
+for sharded in (False, True):
+    mix = mixing.make_mixer("ring", "dense", w, clients_sharded=sharded)
+    txt = jax.jit(mix, in_shardings=shard, out_shardings=shard).lower(
+        tree).compile().as_text()
+    out[str(sharded)] = {
+        op: len(re.findall(r"= \\S+ " + op + r"(?:-start)?\\(", txt))
+        for op in ("all-gather", "collective-permute", "all-reduce")}
+print(json.dumps(out))
+"""
+
+
+def test_sharded_clients_gossip_is_one_all_gather_per_leaf():
+    """On a 4-device clients axis the contraction lowers to one all-gather
+    per leaf; slicing the sharded axis would take n - 1 permutes."""
+    import json
+    import os
+    import subprocess
+    import sys
+
+    env = dict(os.environ)
+    env.pop("XLA_FLAGS", None)
+    env["JAX_PLATFORMS"] = "cpu"
+    env["PYTHONPATH"] = os.pathsep.join(
+        [os.path.join(os.path.dirname(os.path.dirname(__file__)), "src"),
+         env.get("PYTHONPATH", "")])
+    proc = subprocess.run([sys.executable, "-c", _SHARDED_GOSSIP], env=env,
+                          capture_output=True, text=True, timeout=300)
+    assert proc.returncode == 0, proc.stderr[-2000:]
+    counts = json.loads(proc.stdout.strip().splitlines()[-1])
+    assert counts["True"] == {"all-gather": 2, "collective-permute": 0,
+                              "all-reduce": 0}
+    assert counts["False"]["collective-permute"] > 2
