@@ -33,6 +33,14 @@ class MoEConfig:
     router_jitter: float = 0.0
     capacity_factor: float = 1.25
     dispatch: str = "dense"  # "dense" (one-hot capacity) | "sorted" (ragged_dot)
+    # Experts this chip holds of ``num_experts`` (the first ones; 0 = all):
+    # the layer routes over every expert and computes only its own experts'
+    # part of the result (expert parallelism's share, "sorted" dispatch).
+    experts_held: int = 0
+
+    @property
+    def held(self) -> int:
+        return self.experts_held or self.num_experts
 
 
 @dataclasses.dataclass(frozen=True)
@@ -85,6 +93,11 @@ class ModelConfig:
     num_prefix_tokens: int = 0
     # Audio: number of parallel codebook streams (musicgen).
     num_codebooks: int = 0
+    # Granite's scalar multipliers; the defaults emit no operation.
+    embedding_multiplier: float = 1.0   # embeddings scaled by it
+    attention_multiplier: float = 0.0   # score scale; 0 -> head_dim ** -0.5
+    residual_multiplier: float = 1.0    # each block's attention / MLP branch
+    logits_scaling: float = 1.0         # logits divided by it
     # Source citation for the assigned config.
     source: str = ""
 
@@ -110,10 +123,11 @@ class ModelConfig:
         return (pat * reps)[: self.num_layers]
 
     def param_count(self) -> int:
-        """Approximate parameter count (embedding + blocks + head)."""
+        """Approximate parameter count (embedding + blocks + final norm +
+        head); an MoE layer counts the experts held here."""
         d, hd = self.d_model, self.resolved_head_dim
         n_q, n_kv = self.num_heads, self.num_kv_heads
-        total = self.vocab_size * d  # embed
+        total = self.vocab_size * d + d  # embed, final norm
         if not self.tie_embeddings:
             total += self.vocab_size * d
         for kind in self.blocks():
@@ -124,8 +138,8 @@ class ModelConfig:
                 total += attn
                 if kind == BLOCK_MOE:
                     m = self.moe
-                    total += d * m.num_experts  # router
-                    total += m.num_experts * 3 * d * m.expert_d_ff
+                    total += d * m.num_experts  # router over every expert
+                    total += m.held * 3 * d * m.expert_d_ff
                 else:
                     total += 3 * d * self.d_ff  # gate/up/down
                 total += 2 * d  # norms
@@ -147,14 +161,15 @@ class ModelConfig:
         return total
 
     def active_param_count(self) -> int:
-        """Params touched per token (MoE: only top_k experts)."""
+        """Params touched per token (MoE: the top_k experts, of which a
+        share of ``held / num_experts`` is expected among the held ones)."""
         if self.arch_type != "moe":
             return self.param_count()
         m = self.moe
-        dense_like = self.param_count()
         n_moe = sum(1 for k in self.blocks() if k == BLOCK_MOE)
-        unused = n_moe * (m.num_experts - m.top_k) * 3 * self.d_model * m.expert_d_ff
-        return dense_like - unused
+        idle = m.held - m.top_k * m.held / m.num_experts
+        unused = n_moe * idle * 3 * self.d_model * m.expert_d_ff
+        return self.param_count() - round(unused)
 
 
 # ---------------------------------------------------------------------------

@@ -10,6 +10,7 @@ import dataclasses
 
 from repro.configs import (
     granite_moe_1b_a400m,
+    granite_moe_1b_a400m_ep4,
     internvl2_76b,
     mamba2_1_3b,
     minicpm_2b,
@@ -35,9 +36,13 @@ ARCHS = {
     "qwen1.5-4b": qwen1_5_4b.CONFIG,
     "musicgen-medium": musicgen_medium.CONFIG,
     "paper-toy": paper_toy.CONFIG,
+    "granite-moe-1b-a400m-ep4": granite_moe_1b_a400m_ep4.CONFIG,
 }
 
-ASSIGNED = tuple(k for k in ARCHS if k != "paper-toy")
+#: Shares of an assigned architecture, sized for one chip of a stated
+#: deployment (their modules say which).
+SHARES = ("granite-moe-1b-a400m-ep4",)
+ASSIGNED = tuple(k for k in ARCHS if k != "paper-toy" and k not in SHARES)
 
 
 def get_model_config(arch_id: str) -> ModelConfig:
@@ -72,9 +77,13 @@ def reduced(cfg: ModelConfig) -> ModelConfig:
         num_prefix_tokens=min(cfg.num_prefix_tokens, 4),
     )
     if cfg.arch_type == "moe":
+        m = cfg.moe
         changes["moe"] = MoEConfig(
             num_experts=4, top_k=2, expert_d_ff=64,
-            router_aux_coef=cfg.moe.router_aux_coef,
+            router_aux_coef=m.router_aux_coef, dispatch=m.dispatch,
+            # a share keeps its share: 4 · held / num_experts of 4 experts
+            experts_held=(max(1, 4 * m.experts_held // m.num_experts)
+                          if m.experts_held else 0),
         )
     if cfg.arch_type == "ssm":
         changes["ssm"] = SSMConfig(d_state=16, d_head=32, expand=2, chunk=16, d_conv=4)

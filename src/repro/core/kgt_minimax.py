@@ -56,6 +56,9 @@ class KGTState:
     # unchanged and the engine's template validation sees identical trees.
     ef_x: Any = None
     ef_y: Any = None
+    # The problem's per-round counters (``MinimaxProblem.counters``) of the
+    # round that made this state: {name: f32 scalar}; None without them.
+    counters: Any = None
 
 
 def _tree_axpy(a: float, x_tree, y_tree):
@@ -151,8 +154,12 @@ def init_state(
         # Q(Δ) with nothing carried
         ef_x = compression_lib.init_ef(n, packing.pack_spec(x).dim)
         ef_y = compression_lib.init_ef(n, packing.pack_spec(y).dim)
+    counters = None
+    if problem.counters is not None:
+        counters = {name: jnp.zeros((), jnp.float32)
+                    for name in problem.counter_names}
     return KGTState(x=x, y=y, cx=cx, cy=cy, round=jnp.int32(0),
-                    ef_x=ef_x, ef_y=ef_y)
+                    ef_x=ef_x, ef_y=ef_y, counters=counters)
 
 
 def point_etas(cfg: AlgorithmConfig) -> dict:
@@ -345,7 +352,7 @@ def make_round_step(
     algo = cfg.algorithm
     track = algo in ("kgt_minimax", "gt_gda")
     k_steps = 1 if algo in ("dsgda", "gt_gda") else cfg.local_steps
-    grads_v = jax.vmap(problem.grads)
+    grads_v = jax.vmap(problem.grads_with_stats)
     # (K, n)-batched affine-coefficient oracle for the whole-round kernel
     coeffs_v = (jax.vmap(jax.vmap(problem.affine_coeffs)) if fused else None)
 
@@ -649,22 +656,27 @@ def make_round_step(
             xx, yy = carry
             batch_k, key_k = inp
             with jax.named_scope("kgt.grads"):
-                gx, gy = grads_v(xx, yy, batch_k, key_k)
+                (gx, gy), stats = grads_v(xx, yy, batch_k, key_k)
             with jax.named_scope("kgt.local_update"):
                 if track:   # g + c
                     gx = _tree_axpy(1.0, state.cx, gx)
                     gy = _tree_axpy(1.0, state.cy, gy)
                 xx = _tree_axpy(-eta_cx, gx, xx)
                 yy = _tree_axpy(eta_cy, gy, yy)
-            return (xx, yy), None
+            return (xx, yy), stats
 
         # slice exactly k_steps from the provided K-stacked batch
         bat = jax.tree.map(lambda b: b[:k_steps], batches)
         kk = jax.tree.map(lambda b: b[:k_steps], keys)
-        (xk, yk), _ = jax.lax.scan(local_step, (state.x, state.y), (bat, kk))
+        (xk, yk), stats = jax.lax.scan(local_step, (state.x, state.y),
+                                       (bat, kk))
         with jax.named_scope("kgt.epilogue"):
-            return _epilogue(state, xk, yk, w_t, mix, mask, adv,
-                             eta_sx, eta_sy, corr_x, corr_y)
+            new_state = _epilogue(state, xk, yk, w_t, mix, mask, adv,
+                                  eta_sx, eta_sy, corr_x, corr_y)
+        if state.counters is not None:   # this round's counters
+            new_state = dataclasses.replace(
+                new_state, counters=problem.counters(stats))
+        return new_state
 
     n_extras = int(traced_w) + int(participation) + int(byzantine)
     extras_doc = "".join(

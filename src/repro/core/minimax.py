@@ -37,11 +37,24 @@ class MinimaxProblem:
     # no affine form and mixing_impl="fused_round" must be rejected.
     affine_coeffs: Optional[Callable[[Any, Any], Any]] = None
     mu: float = 1.0
+    # The oracle with its statistics: value_with_stats(x, y, batch, key) ->
+    # (value, stats); None means (value(...), None).  counters(stats) ->
+    # {name: f32 scalar} for the stats of one round, stacked over (local
+    # step, client); the round keeps them in ``KGTState.counters`` under
+    # ``counter_names``.  A problem without counters leaves both unset.
+    value_with_stats: Optional[Callable[[Any, Any, Any, Any], Any]] = None
+    counters: Optional[Callable[[Any], Any]] = None
+    counter_names: tuple = ()
+
+    def grads_with_stats(self, x, y, batch, key):
+        """``((∇x f_i, ∇y f_i), stats)`` at (x, y) on ``batch`` with noise
+        key ``key``; ``stats`` is None without ``value_with_stats``."""
+        f = self.value_with_stats or (lambda *a: (self.value(*a), None))
+        return jax.grad(f, argnums=(0, 1), has_aux=True)(x, y, batch, key)
 
     def grads(self, x, y, batch, key):
         """(∇x f_i, ∇y f_i) at (x, y) on ``batch`` with noise key ``key``."""
-        gx, gy = jax.grad(self.value, argnums=(0, 1))(x, y, batch, key)
-        return gx, gy
+        return self.grads_with_stats(x, y, batch, key)[0]
 
     def phi_grad_norm(self, x) -> Any:
         assert self.phi_grad is not None, "problem lacks exact Phi oracle"

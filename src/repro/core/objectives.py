@@ -208,14 +208,36 @@ def dro_problem(cfg: ModelConfig, *, num_groups: int = 8, mu: float = 1.0,
     def init_y(key):
         return jnp.zeros((num_groups,))
 
-    def value(x, y, batch, key):
+    def value_with_stats(x, y, batch, key):
         del key  # stochasticity comes from the data batch itself
-        losses, aux = model_lib.per_group_loss(
+        losses, aux, stats = model_lib.per_group_loss(
             x, batch, cfg, num_groups=num_groups,
             compute_dtype=compute_dtype, remat=remat)
-        return jnp.dot(y, losses) + aux - 0.5 * mu * jnp.sum(y * y)
+        f = jnp.dot(y, losses) + aux - 0.5 * mu * jnp.sum(y * y)
+        return f, stats.get("rows")
 
-    return MinimaxProblem(init_x=init_x, init_y=init_y, value=value, mu=mu)
+    def value(x, y, batch, key):
+        return value_with_stats(x, y, batch, key)[0]
+
+    sorted_moe = cfg.moe.dispatch == "sorted" and "moe" in cfg.blocks()
+    return MinimaxProblem(init_x=init_x, init_y=init_y, value=value, mu=mu,
+                          value_with_stats=value_with_stats,
+                          counters=moe_counters if sorted_moe else None,
+                          counter_names=MOE_COUNTERS if sorted_moe else ())
+
+
+#: The per-round counters of a sorted-dispatch MoE model.
+MOE_COUNTERS = ("moe_rows_held", "moe_load_max")
+
+
+def moe_counters(rows) -> Dict[str, Any]:
+    """One round's routing counters from the rows routed to each held
+    expert, ``(local step, client, layer, held expert)``: the rows routed
+    to held experts, and the largest (layer, expert) load summed over the
+    round's steps and clients over the mean load."""
+    load = jnp.sum(rows, axis=(0, 1)).astype(jnp.float32)
+    return {"moe_rows_held": jnp.sum(load),
+            "moe_load_max": jnp.max(load) / jnp.maximum(jnp.mean(load), 1.0)}
 
 
 # ---------------------------------------------------------------------------

@@ -28,6 +28,10 @@ ConstraintFn = Callable[[Any], Any]
 
 # Slot name used for the residual-stream constraint (``apply_residual``).
 RESIDUAL = "residual"
+# Slot whose presence says that the clients axis is split over devices:
+# model code under the round's vmap then leaves its ops batched
+# (``models.moe``'s grouped products; see :func:`has`).
+CLIENTS_SHARDED = "clients_sharded"
 
 _tls = threading.local()
 
@@ -63,6 +67,11 @@ def apply(tag: str, x):
         if fn is not None:
             return fn(x)
     return x
+
+
+def has(tag: str) -> bool:
+    """Whether any installed frame registers ``tag``."""
+    return any(tag in frame for frame in _stack())
 
 
 def apply_residual(x):

@@ -47,7 +47,10 @@ def dro_metrics_fn(
     """Metrics for DRO-LM training (what ``repro.launch.train`` logs).
 
     Train-side: f(x̄, ȳ) and the mean per-group loss on the round's own
-    first (k=0, client 0) batch.  Eval-side (when ``eval_batch`` is given —
+    first (k=0, client 0) batch, and the round's own counters where the
+    state carries them (``KGTState.counters``: a sorted-dispatch MoE's
+    ``moe_rows_held`` and ``moe_load_max``, over all clients and local
+    steps).  Eval-side (when ``eval_batch`` is given —
     a fixed held-out batch from ``repro.engine.sampler.held_out_eval_batch``):
     mean and per-group losses of the consensus model on data the optimizer
     never trains on.
@@ -58,16 +61,17 @@ def dro_metrics_fn(
         xbar = kgt.mean_over_clients(state.x)
         ybar = state.y.mean(0)
         train_b = jax.tree.map(lambda b: b[0, 0], batches)  # (k=0, client 0)
-        train_losses, _ = per_group_loss(
+        train_losses, _, _ = per_group_loss(
             xbar, train_b, model_cfg, num_groups=num_groups,
             compute_dtype=compute_dtype)
         out = {
             "f_bar": problem.value(xbar, ybar, train_b, None),
             "mean_loss": train_losses.mean(),
             **_consensus_block(state),
+            **(state.counters or {}),
         }
         if eval_batch is not None:
-            eval_losses, _ = per_group_loss(
+            eval_losses, _, _ = per_group_loss(
                 xbar, eval_batch, model_cfg, num_groups=num_groups,
                 compute_dtype=compute_dtype)
             out["eval_loss"] = eval_losses.mean()

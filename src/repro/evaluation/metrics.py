@@ -18,7 +18,7 @@ from repro.models import model as model_lib
 def group_metrics(params, batch, cfg: ModelConfig, *, num_groups: int,
                   compute_dtype=jnp.bfloat16) -> Dict[str, jnp.ndarray]:
     """Per-group CE/ppl + worst-group stats on one batch."""
-    losses, _ = model_lib.per_group_loss(
+    losses, _, _ = model_lib.per_group_loss(
         params, batch, cfg, num_groups=num_groups, compute_dtype=compute_dtype)
     present = jax.nn.one_hot(batch["groups"], num_groups).sum((0, 1)) > 0
     masked = jnp.where(present, losses, -jnp.inf)

@@ -68,9 +68,19 @@ def _train_parts(
     w = topology.mixing_matrix(algo.topology, n)
     # a clients axis over devices splits the state's client dim: keep the
     # dense gossip a contraction, one all-gather a leaf
-    round_fn = kgt.make_round_step(
-        problem, algo, w, lr_scale=lr_scale,
-        clients_sharded=dict(mesh.shape).get(sh.CLIENTS, 1) > 1)
+    clients_sharded = dict(mesh.shape).get(sh.CLIENTS, 1) > 1
+    sorted_moe = (model_cfg.moe.dispatch == "sorted"
+                  and "moe" in model_cfg.blocks())
+    if (clients_sharded and sorted_moe
+            and mesh.devices.flat[0].platform == "tpu"):
+        # models.moe keeps the grouped products batched over a sharded
+        # clients axis, which XLA's TPU ragged-dot refuses
+        raise ValueError(
+            "sorted MoE dispatch is not supported on a TPU mesh that splits "
+            "the clients axis (the TPU's ragged-dot takes no batch "
+            "dimension); run it with --mesh host")
+    round_fn = kgt.make_round_step(problem, algo, w, lr_scale=lr_scale,
+                                   clients_sharded=clients_sharded)
 
     # ---- abstract state -------------------------------------------------
     x_one = jax.eval_shape(lambda k: model_lib.init_params(model_cfg, k),
@@ -78,8 +88,11 @@ def _train_parts(
     rep = lambda t: jax.tree.map(lambda s: _sds((n, *s.shape), s.dtype), t)
     x_sds = rep(x_one)
     y_sds = _sds((n, minimax.num_groups), jnp.float32)
+    counters = None
+    if problem.counters is not None:
+        counters = {c: _sds((), jnp.float32) for c in problem.counter_names}
     state_sds = kgt.KGTState(x=x_sds, y=y_sds, cx=x_sds, cy=y_sds,
-                             round=_sds((), jnp.int32))
+                             round=_sds((), jnp.int32), counters=counters)
 
     # ---- abstract inputs -------------------------------------------------
     tok_shape = (k_steps, n, b_client, shape.seq_len)
@@ -104,7 +117,8 @@ def _train_parts(
         lambda s: NamedSharding(mesh, P(sh.CLIENTS)), y_sds)
     state_shard = kgt.KGTState(
         x=x_shard, y=y_shard, cx=x_shard, cy=y_shard,
-        round=NamedSharding(mesh, P()))
+        round=NamedSharding(mesh, P()),
+        counters=jax.tree.map(lambda _: NamedSharding(mesh, P()), counters))
     def batch_spec(s):
         parts = [None, sh.CLIENTS, sh.FSDP, sh.MODEL] + [None] * (len(s.shape) - 4)
         return NamedSharding(mesh, P(*parts[: len(s.shape)]))
@@ -130,6 +144,8 @@ def _train_parts(
             return jax.lax.with_sharding_constraint(x, NamedSharding(mesh, spec))
 
         slots = {"attn_qkv": qkv_fn, "attn_out": out_fn}
+    if clients_sharded:
+        slots[dist_ctx.CLIENTS_SHARDED] = lambda x: x
 
     def round_step(state, batches, keys):
         with dist_ctx.residual_constraint(constraint, **slots):

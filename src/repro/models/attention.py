@@ -67,8 +67,10 @@ def causal_mask(sq: int, sk: int, q_offset: int = 0, window: int = 0):
     return m
 
 
-def naive_attention(q, k, v, *, window: int = 0, q_offset: int = 0):
-    """Reference attention.  q: (B,Sq,H,D); k,v: (B,Sk,KV,D).
+def naive_attention(q, k, v, *, window: int = 0, q_offset: int = 0,
+                    scale=None):
+    """Reference attention.  q: (B,Sq,H,D); k,v: (B,Sk,KV,D); the scores
+    are scaled by ``scale`` (default ``D ** -0.5``).
 
     GQA via grouped einsums — the kv tensors are never materialized at H
     heads (an explicit repeat forces GSPMD to all-gather the expanded kv over
@@ -76,7 +78,7 @@ def naive_attention(q, k, v, *, window: int = 0, q_offset: int = 0):
     b, sq, h, d = q.shape
     kv = k.shape[-2]
     g = h // kv
-    scale = d ** -0.5
+    scale = d ** -0.5 if scale is None else scale
     mask = causal_mask(sq, k.shape[-3], q_offset, window)
     if g == 1:
         # MHA: direct einsum (the grouped form's singleton dim measurably
@@ -93,7 +95,8 @@ def naive_attention(q, k, v, *, window: int = 0, q_offset: int = 0):
     return ctx.reshape(b, sq, h, d)
 
 
-def qchunk_attention(q, k, v, *, window: int = 0, q_chunk: int = 512):
+def qchunk_attention(q, k, v, *, window: int = 0, q_chunk: int = 512,
+                     scale=None):
     """Memory-bounded attention for long no-grad prefill: lax.map over query
     blocks (scores materialized per block only)."""
     b, s, h, d = q.shape
@@ -106,14 +109,15 @@ def qchunk_attention(q, k, v, *, window: int = 0, q_chunk: int = 512):
 
     def one(args):
         i, qi = args
-        return naive_attention(qi, k, v, window=window, q_offset=i * qc)
+        return naive_attention(qi, k, v, window=window, q_offset=i * qc,
+                               scale=scale)
 
     out = jax.lax.map(one, (jnp.arange(nq), qs))
     out = out.transpose(1, 0, 2, 3, 4).reshape(b, nq * qc, h, d)
     return out[:, :s]
 
 
-def decode_attention(q, k_cache, v_cache, valid):
+def decode_attention(q, k_cache, v_cache, valid, scale=None):
     """Single-token decode: q (B,1,H,D) against a cache (B,S,KV,D) with a
     boolean validity mask ``valid`` (S,) — False for slots not yet written
     (cold start).  GQA is handled by grouping q heads — the kv cache is never
@@ -122,7 +126,7 @@ def decode_attention(q, k_cache, v_cache, valid):
     b, one, h, d = q.shape
     kv = k_cache.shape[-2]
     g = h // kv
-    scale = d ** -0.5
+    scale = d ** -0.5 if scale is None else scale
     if g == 1:  # MHA: direct form (see naive_attention)
         logits = jnp.einsum("bqhd,bkhd->bhqk", q, k_cache).astype(jnp.float32) * scale
         logits = jnp.where(valid[None, None, None, :], logits, NEG_INF)

@@ -57,10 +57,20 @@ def embed_tokens(params, tokens, cfg: ModelConfig, compute_dtype):
         # tokens: (B,S,ncb) -> sum of per-codebook embeddings
         parts = [emb[c][tokens[..., c]] for c in range(cfg.num_codebooks)]
         return sum(parts)
-    return emb[tokens]
+    x = emb[tokens]
+    if cfg.embedding_multiplier != 1.0:
+        x = x * cfg.embedding_multiplier
+    return x
 
 
 def lm_head(params, x, cfg: ModelConfig, compute_dtype):
+    logits = _head(params, x, cfg, compute_dtype)
+    if cfg.logits_scaling != 1.0:
+        logits = logits.astype(jnp.float32) / cfg.logits_scaling
+    return logits
+
+
+def _head(params, x, cfg: ModelConfig, compute_dtype):
     if cfg.tie_embeddings:
         w = params["embed"].astype(compute_dtype)
         if cfg.num_codebooks:
@@ -88,7 +98,8 @@ def backbone(
     remat: bool = False,
 ):
     """Everything up to (and incl.) the final norm.  Returns (hidden (B,S,d),
-    new_caches, aux)."""
+    new_caches, aux, stats), ``stats`` the MoE layers' routing statistics
+    (``transformer.stack_forward``)."""
     tokens = batch["tokens"]
     x = embed_tokens(params, tokens, cfg, compute_dtype)
     if "embed_bias" in batch:  # adversarial objective: universal perturbation
@@ -104,7 +115,7 @@ def backbone(
     else:
         positions = jnp.arange(x.shape[1], dtype=jnp.int32)[None, :].repeat(b, 0)
 
-    x, new_caches, aux = tf.stack_forward(
+    x, new_caches, aux, stats = tf.stack_forward(
         params["stack"], x, cfg, mode=mode, positions=positions, caches=caches,
         pos=pos, compute_dtype=compute_dtype, remat=remat,
         attn_impl="qchunk" if mode == "prefill" else "auto",
@@ -112,7 +123,7 @@ def backbone(
     x = rms_norm(x, params["final_norm"], cfg.norm_eps)
     if offset:
         x = x[:, offset:]
-    return x, new_caches, aux
+    return x, new_caches, aux, stats
 
 
 def forward(
@@ -129,7 +140,7 @@ def forward(
 ):
     """Returns (logits, new_caches, aux).  ``last_only`` computes the head on
     the final position only (prefill servers)."""
-    x, new_caches, aux = backbone(
+    x, new_caches, aux, _ = backbone(
         params, batch, cfg, mode=mode, compute_dtype=compute_dtype,
         caches=caches, pos=pos, remat=remat)
     if last_only:
@@ -181,21 +192,23 @@ def chunked_nll(params, hidden, labels, cfg: ModelConfig, *,
 def per_group_loss(params, batch, cfg: ModelConfig, *, num_groups: int,
                    compute_dtype=jnp.bfloat16, remat: bool = False):
     """Group-resolved LM loss for DRO.  batch needs "labels" (B,S[,ncb]) and
-    "groups" (B,S) int32 in [0, num_groups).  Returns ((G,) losses, aux)."""
-    hidden, _, aux = backbone(
-        params, batch, cfg, mode="train", compute_dtype=compute_dtype, remat=remat)
+    "groups" (B,S) int32 in [0, num_groups).  Returns ((G,) losses, aux,
+    stats), ``stats`` the MoE routing statistics ({} without them)."""
+    hidden, _, aux, stats = backbone(
+        params, batch, cfg, mode="train", compute_dtype=compute_dtype,
+        remat=remat)
     nll = chunked_nll(params, hidden, batch["labels"], cfg,
                       compute_dtype=compute_dtype)  # (B,S)
     g = batch["groups"]
     onehot = jax.nn.one_hot(g, num_groups, dtype=jnp.float32)  # (B,S,G)
     sums = jnp.einsum("bs,bsg->g", nll, onehot)
     counts = jnp.maximum(onehot.sum((0, 1)), 1.0)
-    return sums / counts, aux
+    return sums / counts, aux, stats
 
 
 def lm_loss(params, batch, cfg: ModelConfig, *, compute_dtype=jnp.bfloat16,
             remat: bool = False):
-    hidden, _, aux = backbone(
+    hidden, _, aux, _ = backbone(
         params, batch, cfg, mode="train", compute_dtype=compute_dtype, remat=remat)
     nll = chunked_nll(params, hidden, batch["labels"], cfg,
                       compute_dtype=compute_dtype)

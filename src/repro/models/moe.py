@@ -1,26 +1,33 @@
-"""Mixture-of-Experts MLP with top-k routing and capacity-bounded dense dispatch.
+"""Mixture-of-Experts MLP with top-k routing, in two dispatches.
 
-Dispatch uses the classic one-hot capacity formulation (Switch/GShard style):
-deterministic shapes, compiles cleanly under GSPMD.  Expert weights carry a
-leading experts dim; with ``moe_expert_parallel`` sharding (hillclimb option)
-that dim maps onto the ``model`` mesh axis and dispatch lowers to all-to-alls.
-Tokens overflowing an expert's capacity are dropped (residual passes through),
-which matches the reference systems.
+* ``moe_mlp_sorted`` (``MoEConfig.dispatch = "sorted"``): dropless.  The
+  layer routes every token over all ``num_experts`` and computes the part
+  of the result that the experts it holds give (``MoEConfig.held``, the
+  first ones): the (token, choice) rows routed to a held expert are sorted
+  by expert and run through grouped products (``jax.lax.ragged_dot``);
+  rows routed to an absent expert add nothing.  This is the training path
+  of one chip's share of an expert-parallel deployment
+  (``granite-moe-1b-a400m-ep4``).
+* ``moe_mlp`` (``"dense"``): the one-hot capacity formulation
+  (Switch/GShard style) with static shapes, which GSPMD shards over the
+  ``model`` axis with ``moe_expert_parallel`` (the dry runs).  Rows past
+  an expert's capacity are dropped (the residual passes through).
 """
 from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
 
+from repro.dist import context as dist_ctx
 from repro.models.layers import dense_init
 
 
 def init_moe(key, cfg, d_model: int):
     m = cfg.moe
     kr, kg, ku, kd = jax.random.split(key, 4)
-    e, f = m.num_experts, m.expert_d_ff
+    e, f = m.held, m.expert_d_ff
     return {
-        "router": dense_init(kr, (d_model, e), in_axis=0),
+        "router": dense_init(kr, (d_model, m.num_experts), in_axis=0),
         "gate": dense_init(kg, (e, d_model, f), in_axis=1),
         "up": dense_init(ku, (e, d_model, f), in_axis=1),
         "down": dense_init(kd, (e, f, d_model), in_axis=1),
@@ -80,41 +87,113 @@ def moe_mlp(params, x, cfg, compute_dtype=jnp.bfloat16):
     return out.astype(x.dtype), aux
 
 
-def moe_mlp_sorted(params, x, cfg, compute_dtype=jnp.bfloat16):
-    """Dropless sort-based dispatch (beyond-paper §Perf optimization).
+def route(router, xt, m):
+    """Router over all experts, in float32: ``(top-k expert ids (T, k),
+    gates (T, k), Switch load-balance loss)``.  The gates are the softmax
+    over the top-k logits, which is the renormalized top-k of the full
+    softmax."""
+    e, k = m.num_experts, m.top_k
+    logits = jnp.einsum("nd,de->ne", xt, router.astype(jnp.float32))
+    top_logits, idx = jax.lax.top_k(logits, k)
+    gates = jax.nn.softmax(top_logits, axis=-1)
+    probs = jax.nn.softmax(logits, axis=-1)
+    ce = jax.nn.one_hot(idx[:, 0], e, dtype=jnp.float32).mean(axis=0)
+    aux = m.router_aux_coef * e * jnp.sum(probs.mean(axis=0) * ce)
+    return idx, gates, aux
 
-    Flatten (token, choice) assignments, sort by expert, run grouped matmuls
-    with ``jax.lax.ragged_dot`` (group_sizes = per-expert counts), unsort and
-    combine.  Exactly N·k·d·f expert FLOPs — no capacity padding, no one-hot
-    dispatch einsums (the dense path's dominant waste per §Roofline), and no
-    token drops."""
+
+def _per_client(fn):
+    """``fn`` as is, and under ``vmap`` once per client on static slices:
+    XLA's TPU ragged-dot takes no batch dimension.  Where the clients axis
+    is split over devices (``dist_ctx.CLIENTS_SHARDED``), a static slice of
+    it moves each client's operands between devices (collective permutes),
+    so there the products stay batched, which only the CPU compiles
+    (``launch.steps`` refuses such a TPU mesh)."""
+    f = jax.custom_batching.custom_vmap(fn)
+
+    @f.def_vmap
+    def rule(axis_size, in_batched, *args):
+        if dist_ctx.has(dist_ctx.CLIENTS_SHARDED):
+            axes = [0 if b else None for b in in_batched]
+            out = jax.vmap(fn, in_axes=axes)(*args)
+        else:
+            outs = [f(*[a[c] if b else a for a, b in zip(args, in_batched)])
+                    for c in range(axis_size)]
+            out = jax.tree.map(lambda *o: jnp.stack(o), *outs)
+        return out, jax.tree.map(lambda _: True, out)
+
+    return f
+
+
+@_per_client
+def _gmm_fwd_product(xs, w, sizes):
+    return jax.lax.ragged_dot(xs, w, sizes)
+
+
+@_per_client
+def _gmm_bwd_product(xs, w, sizes, g):
+    _, vjp = jax.vjp(lambda a, b: jax.lax.ragged_dot(a, b, sizes), xs, w)
+    return vjp(g)
+
+
+@jax.custom_vjp
+def grouped_matmul(xs, w, sizes):
+    """``(R, d) × (G, d, f) -> (R, f)``: row block g (``sizes[g]`` rows, in
+    order) times ``w[g]``; rows past ``sum(sizes)`` belong to no group."""
+    return _gmm_fwd_product(xs, w, sizes)
+
+
+def _gmm_fwd(xs, w, sizes):
+    return _gmm_fwd_product(xs, w, sizes), (xs, w, sizes)
+
+
+def _gmm_bwd(res, g):
+    xs, w, sizes = res
+    with jax.named_scope("moe.experts"):
+        dxs, dw = _gmm_bwd_product(xs, w, sizes, g)
+    return dxs, dw, None
+
+
+grouped_matmul.defvjp(_gmm_fwd, _gmm_bwd)
+
+
+def moe_mlp_sorted(params, x, cfg, compute_dtype=jnp.bfloat16):
+    """Dropless sorted dispatch over the held experts.  x: (B, S, d).
+    Returns ``(out, aux, stats)``: ``stats["rows"]`` (held,) rows routed to
+    each held expert, ``stats["held"]`` (B·S, held) which held experts each
+    token picked.
+
+    ``params["gate"|"up"|"down"]`` hold the ``held`` experts' weights; the
+    router keeps all ``num_experts`` outputs.  Every (token, choice) row is
+    kept: rows routed to a held expert sort first, by expert, and only they
+    enter the grouped products; the rest sort last and add nothing."""
     m = cfg.moe
     b, s, d = x.shape
     n = b * s
-    e, k = m.num_experts, m.top_k
+    k, held = m.top_k, m.held
     xt = x.reshape(n, d)
-
-    logits = jnp.einsum("nd,de->ne", xt, params["router"].astype(jnp.float32))
-    probs = jax.nn.softmax(logits, axis=-1)
-    gate_vals, gate_idx = jax.lax.top_k(probs, k)  # (n,k)
-    gate_vals = gate_vals / jnp.maximum(gate_vals.sum(-1, keepdims=True), 1e-9)
-
-    me = probs.mean(axis=0)
-    ce = jax.nn.one_hot(gate_idx[:, 0], e, dtype=jnp.float32).mean(axis=0)
-    aux = m.router_aux_coef * e * jnp.sum(me * ce)
-
-    flat_e = gate_idx.reshape(-1)                       # (n*k,) expert ids
-    order = jnp.argsort(flat_e)                         # stable
-    tok_of = order // k                                 # source token per slot
-    xs = xt[tok_of].astype(compute_dtype)               # (n*k, d) sorted
-    counts = jnp.bincount(flat_e, length=e)             # group sizes
-
     w = lambda p: p.astype(compute_dtype)
-    h = jax.nn.silu(jax.lax.ragged_dot(xs, w(params["gate"]), counts))
-    h = h * jax.lax.ragged_dot(xs, w(params["up"]), counts)
-    ys = jax.lax.ragged_dot(h, w(params["down"]), counts)  # (n*k, d)
 
-    gates_sorted = gate_vals.reshape(-1)[order].astype(jnp.float32)
-    contrib = ys.astype(jnp.float32) * gates_sorted[:, None]
-    out = jnp.zeros((n, d), jnp.float32).at[tok_of].add(contrib)
-    return out.reshape(b, s, d).astype(x.dtype), aux
+    with jax.named_scope("moe.route"):
+        idx, gates, aux = route(params["router"], xt, m)
+        flat = idx.reshape(-1)                         # (n·k,)
+        group = jnp.where(flat < held, flat, held)     # absent experts last
+        order = jnp.argsort(group, stable=True)
+        tok_of = order // k
+        valid = (group < held)[order]
+        sizes = jnp.bincount(group, length=held + 1)[:held]
+        xs = jnp.where(valid[:, None], xt[tok_of].astype(compute_dtype), 0)
+
+    with jax.named_scope("moe.experts"):
+        h = jax.nn.silu(grouped_matmul(xs, w(params["gate"]), sizes))
+        h = h * grouped_matmul(xs, w(params["up"]), sizes)
+        ys = grouped_matmul(h, w(params["down"]), sizes)     # (n·k, d)
+
+    with jax.named_scope("moe.combine"):
+        g = jnp.where(valid, gates.reshape(-1)[order], 0.0)
+        contrib = jnp.where(valid[:, None], ys.astype(jnp.float32), 0.0)
+        out = jnp.zeros((n, d), jnp.float32).at[tok_of].add(
+            contrib * g[:, None])
+    out = out.reshape(b, s, d).astype(x.dtype)
+    picked = (idx[:, :, None] == jnp.arange(held)).any(axis=1)
+    return out, aux, {"rows": sizes, "held": picked}

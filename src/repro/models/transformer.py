@@ -97,6 +97,13 @@ def _attn_window(kind: str, cfg: ModelConfig) -> int:
     return 0
 
 
+def _residual(cfg: ModelConfig, y):
+    """A block's branch as Granite scales it (no operation by default)."""
+    if cfg.residual_multiplier == 1.0:
+        return y
+    return y * cfg.residual_multiplier
+
+
 def block_forward(
     kind: str,
     params,
@@ -110,7 +117,8 @@ def block_forward(
     compute_dtype=jnp.bfloat16,
     attn_impl: str = "auto",
 ):
-    """Returns (x_out, new_cache, aux_loss)."""
+    """Returns (x_out, new_cache, aux_loss, stats): ``stats`` are the
+    routing statistics of a sorted-dispatch MoE block, else None."""
     aux = jnp.zeros((), jnp.float32)
     h = rms_norm(x, params["norm1"], cfg.norm_eps)
 
@@ -121,7 +129,7 @@ def block_forward(
             params["ssm"], h, cfg, compute_dtype, conv_s, ssd_s,
             decode=(mode == "decode"),
         )
-        return x + y, new_cache, aux
+        return x + y, new_cache, aux, None
 
     if kind == "rglru":
         conv_s = cache["conv"] if cache else None
@@ -133,10 +141,11 @@ def block_forward(
         x = x + y
         h2 = rms_norm(x, params["norm2"], cfg.norm_eps)
         x = x + mlp(params["mlp"], h2, compute_dtype)
-        return x, new_cache, aux
+        return x, new_cache, aux, None
 
     # attention-family blocks -------------------------------------------------
     window = _attn_window(kind, cfg)
+    scale = cfg.attention_multiplier or None
     q, k, v = attn_lib.qkv_project(params["attn"], h, cfg, positions, compute_dtype)
     q = dist_ctx.apply("attn_qkv", q)  # optional head-sharding switch
 
@@ -156,14 +165,17 @@ def block_forward(
         # once pos >= window, which is exactly when wrapping starts)
         valid = jnp.arange(c_len, dtype=jnp.int32) <= pos
         ctx = attn_lib.decode_attention(q, kc.astype(compute_dtype),
-                                        vc.astype(compute_dtype), valid)
+                                        vc.astype(compute_dtype), valid,
+                                        scale=scale)
         new_cache = {"k": kc, "v": vc}
     else:
         new_cache = None
         if mode == "prefill" or attn_impl == "qchunk":
-            ctx = attn_lib.qchunk_attention(q, k, v, window=window)
+            ctx = attn_lib.qchunk_attention(q, k, v, window=window,
+                                            scale=scale)
         else:
-            ctx = attn_lib.naive_attention(q, k, v, window=window)
+            ctx = attn_lib.naive_attention(q, k, v, window=window,
+                                           scale=scale)
         if cache is not None:  # prefill populating a cache
             c_len = cache["k"].shape[1]
             kw = k[:, -c_len:].astype(cache["k"].dtype)
@@ -172,16 +184,18 @@ def block_forward(
 
     ctx = dist_ctx.apply("attn_out", ctx)  # back to seq-sharding
     y = attn_lib.out_project(params["attn"], ctx, compute_dtype)
-    x = x + y
+    x = x + _residual(cfg, y)
 
     h2 = rms_norm(x, params["norm2"], cfg.norm_eps)
-    if kind == "moe":
-        moe_fn = (moe_lib.moe_mlp_sorted if cfg.moe.dispatch == "sorted"
-                  else moe_lib.moe_mlp)
-        y2, aux = moe_fn(params["moe"], h2, cfg, compute_dtype)
+    stats = None
+    if kind == "moe" and cfg.moe.dispatch == "sorted":
+        y2, aux, stats = moe_lib.moe_mlp_sorted(params["moe"], h2, cfg,
+                                                compute_dtype)
+    elif kind == "moe":
+        y2, aux = moe_lib.moe_mlp(params["moe"], h2, cfg, compute_dtype)
     else:
         y2 = mlp(params["mlp"], h2, compute_dtype)
-    return x + y2, new_cache, aux
+    return x + _residual(cfg, y2), new_cache, aux, stats
 
 
 # ---------------------------------------------------------------------------
@@ -217,9 +231,12 @@ def stack_forward(
     remat: bool = False,
 ):
     """Run all segments.  caches mirrors stack_params structure (or None).
-    Returns (x, new_caches, total_aux)."""
+    Returns (x, new_caches, total_aux, stats): ``stats`` are the routing
+    statistics of the sorted-dispatch MoE layers, concatenated over layers
+    ({} for a model without them)."""
     segs = segments(cfg)
     new_caches = []
+    layer_stats = []
     total_aux = jnp.zeros((), jnp.float32)
 
     for si, (unit, reps) in enumerate(segs):
@@ -229,21 +246,28 @@ def stack_forward(
         def unit_fn(carry, xs, unit=unit):
             xx, aux = carry
             p_slices, c_slices = xs
-            new_cs = []
+            new_cs, unit_stats = [], []
             for bi, kind in enumerate(unit):
                 c = c_slices[bi] if c_slices is not None else None
-                xx, nc, a = block_forward(
+                xx, nc, a, st = block_forward(
                     kind, p_slices[bi], xx, cfg, mode=mode, positions=positions,
                     cache=c, pos=pos, compute_dtype=compute_dtype,
                     attn_impl=attn_impl,
                 )
                 new_cs.append(nc)
+                if st is not None:
+                    unit_stats.append(st)
             xx = dist_ctx.apply_residual(xx)
-            return (xx, aux + a), tuple(new_cs)
+            return (xx, aux + a), (tuple(new_cs), tuple(unit_stats))
 
         f = jax.checkpoint(unit_fn) if (remat and mode == "train") else unit_fn
         xs = (seg_params, seg_cache)
-        (x, total_aux), seg_new_cache = jax.lax.scan(f, (x, total_aux), xs)
+        (x, total_aux), (seg_new_cache, seg_stats) = jax.lax.scan(
+            f, (x, total_aux), xs)
         new_caches.append(seg_new_cache)
+        layer_stats.extend(seg_stats)
 
-    return x, (tuple(new_caches) if caches is not None else None), total_aux
+    stats = (jax.tree.map(lambda *t: jnp.concatenate(t), *layer_stats)
+             if layer_stats else {})
+    return (x, (tuple(new_caches) if caches is not None else None),
+            total_aux, stats)

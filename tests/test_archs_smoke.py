@@ -6,7 +6,7 @@ import jax.numpy as jnp
 import pytest
 
 from repro.configs.base import AlgorithmConfig, MinimaxConfig
-from repro.configs.registry import ASSIGNED, get_model_config, reduced
+from repro.configs.registry import ASSIGNED, SHARES, get_model_config, reduced
 from repro.core import init_state, make_round_step, objectives
 from repro.data import make_data_model, round_batches
 from repro.models import forward, init_params, per_group_loss
@@ -27,7 +27,7 @@ def _batch(cfg, key):
     return batch
 
 
-@pytest.mark.parametrize("arch", ASSIGNED)
+@pytest.mark.parametrize("arch", ASSIGNED + SHARES)
 def test_reduced_forward_shapes_and_finite(arch):
     cfg = reduced(get_model_config(arch))
     key = jax.random.PRNGKey(1)
@@ -40,12 +40,12 @@ def test_reduced_forward_shapes_and_finite(arch):
         assert logits.shape == (B, S, cfg.vocab_size)
     assert bool(jnp.isfinite(logits.astype(jnp.float32)).all())
     assert bool(jnp.isfinite(aux))
-    losses, _ = per_group_loss(params, batch, cfg, num_groups=G)
+    losses, _, _ = per_group_loss(params, batch, cfg, num_groups=G)
     assert losses.shape == (G,)
     assert bool(jnp.isfinite(losses).all())
 
 
-@pytest.mark.parametrize("arch", ASSIGNED)
+@pytest.mark.parametrize("arch", ASSIGNED + SHARES)
 def test_reduced_kgt_train_step(arch):
     """One full communication round (K=2 local DRO-minimax steps + tracking +
     gossip) on the reduced variant — no NaNs, consensus finite."""

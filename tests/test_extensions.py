@@ -66,7 +66,7 @@ def test_sorted_dispatch_matches_dense_without_drops():
     params = moe_lib.init_moe(jax.random.PRNGKey(0), cfg, cfg.d_model)
     x = jax.random.normal(jax.random.PRNGKey(1), (2, 16, cfg.d_model))
     y_dense, aux_d = moe_lib.moe_mlp(params, x, cfg, compute_dtype=jnp.float32)
-    y_sorted, aux_s = moe_lib.moe_mlp_sorted(params, x, cfg,
+    y_sorted, aux_s, _ = moe_lib.moe_mlp_sorted(params, x, cfg,
                                              compute_dtype=jnp.float32)
     np.testing.assert_allclose(y_dense, y_sorted, atol=1e-5)
     np.testing.assert_allclose(aux_d, aux_s, atol=1e-6)

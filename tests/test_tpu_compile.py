@@ -8,6 +8,9 @@ module-scoped fixture — never while a module is imported — because only
 one process may load the TPU library, and the worker that is given this
 file keeps it until it exits.
 """
+import hashlib
+import re
+
 import jax
 import jax.numpy as jnp
 import pytest
@@ -118,3 +121,93 @@ def test_fused_round_past_the_guard_overflows_vmem(sds):
             lambda *a: fround_lib.fused_round_nd(*a, k_steps=K_STEPS,
                                                  interpret=False),
             *_fused_round_args(sds, n, dz))
+
+
+#: sha256 of paper-toy's compiled round (smoke-test width, n=4, K=2, B=2,
+#: S=32) for one v5e chip, without its source locations (the stack-frame
+#: tables and each instruction's metadata), as the tree before Granite's
+#: multipliers compiled it.
+PAPER_TOY_ROUND_SHA256 = (
+    "3191dc63ea3564af43e115c726310871af3edfb3044750b6b60500e2698b2882")
+_FRAMES = re.compile(r"\nFileNames\n.*?\nStackFrames\n(?:\d+ [^\n]*\n)*",
+                     re.S)
+
+
+def _paper_toy_round_text(sds) -> str:
+    from repro.configs import registry
+    from repro.configs.base import AlgorithmConfig
+    from repro.core import kgt_minimax as kgt
+    from repro.core import objectives
+
+    cfg = registry.reduced(registry.get_model_config("paper-toy"))
+    algo = AlgorithmConfig(num_clients=4, local_steps=2, eta_cx=0.01,
+                           eta_cy=0.5, eta_sx=0.7, eta_sy=0.7)
+    problem = objectives.dro_problem(cfg, num_groups=8)
+    step = kgt.make_round_step(problem, algo)
+    x1 = jax.eval_shape(problem.init_x, jax.random.PRNGKey(0))
+    rep = lambda t: jax.tree.map(lambda s: sds(4, *s.shape, dtype=s.dtype), t)
+    state = kgt.KGTState(x=rep(x1), y=sds(4, 8), cx=rep(x1), cy=sds(4, 8),
+                         round=sds(dtype=jnp.int32))
+    tok = sds(2, 4, 2, 32, dtype=jnp.int32)
+    batch = {"tokens": tok, "labels": tok, "groups": tok}
+    text = jax.jit(step).lower(state, batch,
+                               sds(2, 4, 2, dtype=jnp.uint32)).compile(
+                                   ).as_text()
+    return re.sub(r",? metadata=\{[^{}]*\}", "", _FRAMES.sub("\n", text))
+
+
+def test_paper_toy_round_is_unchanged_by_the_multipliers(sds):
+    """Granite's multipliers, the attention scale and the MoE counters
+    default to emitting no operation: paper-toy's optimized round is the
+    one it was, instruction for instruction."""
+    text = _paper_toy_round_text(sds)
+    assert hashlib.sha256(text.encode()).hexdigest() == PAPER_TOY_ROUND_SHA256
+
+
+def test_moe_share_grouped_products_compile_under_vmap(sds):
+    """The share's expert layer at its published widths, forward and
+    backward, vmapped over 4 clients: XLA's TPU ragged-dot takes no batch
+    dimension, so each client's grouped products are their own kernels."""
+    from repro.configs import registry
+    from repro.models import moe as moe_lib
+
+    cfg = registry.get_model_config("granite-moe-1b-a400m-ep4")
+    m, d, n, t = cfg.moe, cfg.d_model, 4, 1024
+    params = {"router": sds(n, d, m.num_experts),
+              "gate": sds(n, m.held, d, m.expert_d_ff),
+              "up": sds(n, m.held, d, m.expert_d_ff),
+              "down": sds(n, m.held, m.expert_d_ff, d)}
+
+    def loss(p, x):
+        out, aux, _ = moe_lib.moe_mlp_sorted(p, x, cfg)
+        return jnp.sum(out.astype(jnp.float32) ** 2) + aux
+
+    txt = _compiled_text(jax.vmap(jax.grad(loss, argnums=(0, 1))), params,
+                         sds(n, 1, t, d, dtype=jnp.bfloat16))
+    # forward, input and weight gradients of 3 products, for 4 clients
+    assert len(re.findall(r"^\s*%ragged-dot-(?!metadata)[\w.-]+ = ", txt,
+                          re.M)) == 3 * 3 * n
+
+
+def test_sorted_moe_is_refused_on_a_clients_sharded_tpu_mesh(topo):
+    """Over 4 chips that split the clients axis, one slice per client would
+    move operands between chips and the batched ragged-dot does not compile
+    for the TPU: the round builder says so before any tracing."""
+    import numpy as np
+
+    from repro.configs import registry
+    from repro.configs.base import InputShape, MeshConfig
+    from repro.dist import compat
+    from repro.dist import sharding as sh
+    from repro.launch import steps
+
+    cfg = registry.reduced(registry.get_model_config(
+        "granite-moe-1b-a400m-ep4"))
+    mesh = compat.mesh_of(np.array(topo.devices).reshape(4, 1, 1),
+                          (sh.CLIENTS, sh.FSDP, sh.MODEL))
+    with pytest.raises(ValueError, match="--mesh host"):
+        steps.build_train_round(
+            cfg, InputShape(name="t", seq_len=32, global_batch=8,
+                            kind="train"),
+            mesh, MeshConfig(num_clients=4, fsdp=1, model=1,
+                             param_mode="replicated"))
