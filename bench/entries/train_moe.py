@@ -163,8 +163,10 @@ def routing_flip_share(run) -> float:
     x0, batches, ref_held = job.first_step_held(run.seed)
     cfg = program_config(run.config, run.traffic["argv"])
     groups = int(base.job_params(run.traffic["argv"])["groups"])
-    prog = jax.jit(jax.vmap(lambda b: model_lib.per_group_loss(
-        program_params(x0), b, cfg, num_groups=groups)[2]["held"]))(batches)
+    # the weights are an argument: closed over, they would be compiled in
+    prog = jax.jit(jax.vmap(lambda p, b: model_lib.per_group_loss(
+        p, b, cfg, num_groups=groups)[2]["held"], in_axes=(None, 0)))(
+            program_params(x0), batches)
     return float(np.mean(np.any(np.asarray(prog) != ref_held, axis=-1)))
 
 

@@ -204,7 +204,8 @@ class Job(dro_lm.Job):
         x0, dm, kt, _, _ = self.draws(seed)
         batches = jax.tree.map(lambda t: t[0], self._batches(
             dm, jax.random.fold_in(kt, 0)))
+        # the weights are an argument: closed over, they would be compiled in
         held = jax.jit(jax.vmap(
-            lambda tok: forward(self.cfg, self.num, x0, tok)[2]))(
-                batches["tokens"])
+            lambda p, tok: forward(self.cfg, self.num, p, tok)[2],
+            in_axes=(None, 0)))(x0, batches["tokens"])
         return x0, batches, np.asarray(held)
