@@ -111,26 +111,19 @@ def state_copies(hlo: str, shapes) -> list:
     return found
 
 
-def main(argv=None) -> int:
-    ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
-    ap.add_argument("--traffic",
-                    default=os.path.join(REPO, "bench", "traffic",
-                                         "dro-n4-k2.json"))
-    ap.add_argument("--seed", type=int, default=0)
-    ap.add_argument("--out", default=None)
-    ap.add_argument("extra", nargs="*")
-    args = ap.parse_args(argv)
-
+def chunk_hlo(traffic: str, seed: int = 0, extra=()):
+    """Run one chunk of the job in ``traffic`` (a ``bench/traffic`` file)
+    through ``train()``; return the optimized HLO of its chunk program, the
+    shapes of the f32 state leaves (x and cx) and the argv run."""
     sys.path.insert(0, os.path.join(REPO, "src"))
     import jax
 
     from repro.launch import train as train_lib
 
-    with open(args.traffic) as f:
+    with open(traffic) as f:
         job = json.load(f)["argv"]
     chunk = int(job[job.index("--chunk") + 1])
-    job = job + ["--rounds", str(chunk), "--seed", str(args.seed),
-                 *args.extra]
+    job = job + ["--rounds", str(chunk), "--seed", str(seed), *extra]
 
     texts = []
     original = jax.stages.Lowered.compile
@@ -155,10 +148,24 @@ def main(argv=None) -> int:
         jax.stages.Lowered.compile = original
     chunks = [t for t in texts if t.startswith("HloModule jit_chunk_step")]
     if len(chunks) != 1:
-        print(f"expected one compiled chunk, got {len(chunks)}",
-              file=sys.stderr)
-        return 1
-    found = state_copies(chunks[0], shapes)
+        raise RuntimeError(f"expected one compiled chunk, got {len(chunks)}")
+    return chunks[0], shapes, job
+
+
+def main(argv=None) -> int:
+    ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
+    ap.add_argument("--traffic",
+                    default=os.path.join(REPO, "bench", "traffic",
+                                         "dro-n4-k2.json"))
+    ap.add_argument("--seed", type=int, default=0)
+    ap.add_argument("--out", default=None)
+    ap.add_argument("extra", nargs="*")
+    args = ap.parse_args(argv)
+
+    hlo, shapes, job = chunk_hlo(args.traffic, args.seed, args.extra)
+    import jax
+
+    found = state_copies(hlo, shapes)
     summary = {"device": jax.devices()[0].device_kind, "argv": job,
                **{p: sum(c["place"] == p for c in found) for p in PLACES}}
     if args.out:

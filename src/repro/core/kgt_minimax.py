@@ -255,8 +255,16 @@ def make_round_step(
     ``fused_round``, whose kernel runs under none).
 
     ``clients_sharded=True`` says the state's client axis is split across a
-    mesh's devices: the dense gossip of a static or cycled W then stays one
-    contraction (one all-gather) at every n, ``mixing.mix_dense_sharded``.
+    mesh's devices: the dense gossip of a static or cycled W then lowers as
+    ``mixing.mix_dense_sharded`` (collective-permutes over the client shifts
+    a static ring-like W weighs, else one all-gather a leaf).  On the
+    ``dense`` lowering the parameter gossip ``Wx, Wy`` is then formed from
+    the round's starting state, and the local steps, which run unrolled,
+    wait for it: its collectives run at the round's start, beside the
+    sampler's draw, instead of after the local steps' loops.  On one
+    device there is nothing to hide, and the early gossip would only keep a
+    second copy of x live through the local steps, so that program is left
+    as it was.
     """
     if traced_etas and lr_scale is not None:
         raise ValueError(
@@ -313,6 +321,7 @@ def make_round_step(
                else jnp.dtype(cfg.gossip_dtype))
     dense_mix = (mixing_lib.mix_dense_sharded if clients_sharded
                  else mixing_lib.mix_dense)
+    early_gossip = clients_sharded and cfg.mixing_impl == "dense"
     if dynamic_w and not packed and not sparse and not robust and not fused:
         # validates the impl (ring-style neighbor exchanges cannot realize a
         # per-round arbitrary W) and gives us mix(tree, w) with w traced
@@ -424,7 +433,7 @@ def make_round_step(
                 else _freeze_inactive(mask, new_state, state))
 
     def _epilogue(state: KGTState, xk, yk, w_t, mix, mask, adv,
-                  eta_sx, eta_sy, corr_x, corr_y) -> KGTState:
+                  eta_sx, eta_sy, corr_x, corr_y, mixed=None) -> KGTState:
         """Algorithm 1, lines 6-11, for every lowering but the whole-round
         kernel: Δ = x^K − x from the local steps' end point, then the
         correction update and the parameter mixing, then the freeze of
@@ -448,14 +457,15 @@ def make_round_step(
             dy = _tree_mask_clients(mask, dy)
 
         new_state = _mix_round(state, dx, dy, w_t, mix, mask,
-                               eta_sx, eta_sy, corr_x, corr_y)
+                               eta_sx, eta_sy, corr_x, corr_y, mixed)
         return (new_state if mask is None
                 else _freeze_inactive(mask, new_state, state))
 
     def _mix_round(state: KGTState, dx, dy, w_t, mix, mask,
-                   eta_sx, eta_sy, corr_x, corr_y) -> KGTState:
+                   eta_sx, eta_sy, corr_x, corr_y, mixed=None) -> KGTState:
         """Lines 7-11 from this round's (attacked, masked) Δ, per lowering;
-        inactive clients are frozen by the caller."""
+        inactive clients are frozen by the caller.  ``mixed``: the
+        parameter gossip ``(Wx, Wy)`` when the round formed it early."""
         if robust:
             # Robust-aggregation epilogue: R replaces every W contraction.
             # R is nonlinear, so the parameter update is the one-pass
@@ -616,7 +626,8 @@ def make_round_step(
             mdy, my = pack_mix(dy, state.y)
         else:
             mdx, mdy = mix(dx), mix(dy)
-            mx, my = mix(state.x), mix(state.y)
+            mx, my = mixed if mixed is not None else (mix(state.x),
+                                                      mix(state.y))
 
         if track:
             # c^x += (Δx − WΔx)/(K η_cx) ;  c^y −= (Δy − WΔy)/(K η_cy)
@@ -665,14 +676,24 @@ def make_round_step(
                 yy = _tree_axpy(eta_cy, gy, yy)
             return (xx, yy), stats
 
+        x0, y0, mixed = state.x, state.y, None
+        if early_gossip:
+            with jax.named_scope("kgt.epilogue"):
+                mixed = (mix(state.x), mix(state.y))
+            # The scheduler keeps no collective in flight across the layer
+            # loops of the local steps, so Wx left free would run after
+            # them.  Making the local steps wait for it starts its collectives
+            # at the round's start, under the sampler's draw.
+            mixed, x0, y0 = jax.lax.optimization_barrier((mixed, x0, y0))
         # slice exactly k_steps from the provided K-stacked batch
         bat = jax.tree.map(lambda b: b[:k_steps], batches)
         kk = jax.tree.map(lambda b: b[:k_steps], keys)
-        (xk, yk), stats = jax.lax.scan(local_step, (state.x, state.y),
-                                       (bat, kk))
+        (xk, yk), stats = jax.lax.scan(
+            local_step, (x0, y0), (bat, kk),
+            unroll=k_steps if early_gossip else 1)
         with jax.named_scope("kgt.epilogue"):
             new_state = _epilogue(state, xk, yk, w_t, mix, mask, adv,
-                                  eta_sx, eta_sy, corr_x, corr_y)
+                                  eta_sx, eta_sy, corr_x, corr_y, mixed)
         if state.counters is not None:   # this round's counters
             new_state = dataclasses.replace(
                 new_state, counters=problem.counters(stats))

@@ -4,8 +4,10 @@ Two lowering strategies for ``Σ_j w_ij T_j``:
 
 * ``dense`` — the full (n, n) mixing matrix W.  Faithful to the paper
   (arbitrary topology).  Up to ``UNROLL_MAX_CLIENTS`` clients on one device
-  it is a weighted sum of client slices, above that an einsum; across a
-  mesh's clients axis (``mix_dense_sharded``) always the einsum, whose
+  it is a weighted sum of client slices, above that an einsum.  Across a
+  mesh's clients axis (``mix_dense_sharded``) a static W that weighs only
+  some client shifts is a weighted sum of the rolled tensor, one
+  collective-permute per weighed shift, and any other W the einsum, whose
   contraction over the sharded clients dim lowers to an all-gather of the
   full tensor, (n-1)·|T| bytes in per client.
 * ``ring`` — neighbor-only exchange expressed as ``jnp.roll`` along the
@@ -89,11 +91,44 @@ def mix_dense(tree: Any, w, gossip_dtype=None) -> Any:
     return jax.tree.map(lambda x: _dense_one(x, w, gossip_dtype, True), tree)
 
 
+def _client_shifts(w) -> dict:
+    """{s: W[i, (i − s) mod n] over rows i} for each client shift s that
+    some row weighs, or {} for a W known only when traced."""
+    if isinstance(w, jax.core.Tracer):
+        return {}
+    w = np.asarray(w)
+    rows = np.arange(w.shape[0])
+    diags = {s: w[rows, (rows - s) % w.shape[0]] for s in range(w.shape[0])}
+    return {s: d for s, d in diags.items() if np.any(d != 0)}
+
+
+def _shifted_one(x, shifts: dict, gossip_dtype):
+    orig = x.dtype
+    xc = x.astype(gossip_dtype) if gossip_dtype is not None else x
+    col = (x.shape[0],) + (1,) * (x.ndim - 1)
+    mixed = None
+    for s, d in sorted(shifts.items()):
+        # roll(x, s)[i] = x[(i - s) mod n]: one collective-permute
+        term = jnp.asarray(d, jnp.float32).reshape(col) * (
+            xc if s == 0 else jnp.roll(xc, s, axis=0)).astype(jnp.float32)
+        mixed = term if mixed is None else mixed + term
+    return mixed.astype(orig)
+
+
 def mix_dense_sharded(tree: Any, w, gossip_dtype=None) -> Any:
-    """``mix_dense`` as one einsum at every n, for leaves whose client axis
-    is split across devices: there the contraction lowers to one all-gather
-    per leaf, where slicing the sharded axis would take n − 1
-    collective-permutes."""
+    """``mix_dense`` for leaves whose client axis is split across devices.
+
+    A W whose rows weigh only some client shifts (a ring, a torus: a
+    static W with a zero circulant diagonal) is the sum over those shifts
+    of the rolled leaf, one collective-permute per shift but the self one,
+    which moves (shifts − 1)·|T| bytes into each client.  Any other W is
+    one einsum, whose contraction lowers to one all-gather per leaf,
+    (n − 1)·|T| bytes in, where slicing the sharded axis would take n − 1
+    permutes."""
+    shifts = _client_shifts(w)
+    if 0 < len(shifts) < np.shape(w)[0]:
+        return jax.tree.map(lambda x: _shifted_one(x, shifts, gossip_dtype),
+                            tree)
     w = jnp.asarray(w, jnp.float32)
     return jax.tree.map(lambda x: _dense_one(x, w, gossip_dtype, False), tree)
 
@@ -267,8 +302,7 @@ def make_mixer(topology: str, impl: str, w: np.ndarray,
                clients_sharded: bool = False):
     """Returns mix(tree) -> tree for the configured implementation.
     ``clients_sharded``: the leaves' client axis is split across devices
-    (the dense gossip then stays one contraction, see
-    :func:`mix_dense_sharded`)."""
+    (the dense gossip then lowers as :func:`mix_dense_sharded`)."""
     if impl not in MIXING_IMPLS:
         raise ValueError(f"unknown mixing_impl {impl!r}: {MIXING_IMPLS}")
     gd = None if gossip_dtype in (None, "float32") else jnp.dtype(gossip_dtype)

@@ -13,7 +13,7 @@ import dataclasses
 from typing import Optional
 
 import jax
-import numpy as np
+from jax.experimental import mesh_utils
 from jax.sharding import Mesh
 
 from repro.configs.base import MeshConfig
@@ -51,9 +51,14 @@ def decentralized_mesh_config(arch_id: str, *, multi_pod: bool = False) -> MeshC
 
 
 def local_mesh(n_devices: Optional[int] = None) -> Mesh:
-    """Small mesh over whatever devices exist (tests / CPU examples)."""
-    devs = np.array(jax.devices()[: n_devices or len(jax.devices())])
-    return compat.mesh_of(devs.reshape(len(devs), 1, 1), (CLIENTS, FSDP, MODEL))
+    """Small mesh over whatever devices exist (tests / CPU examples).
+
+    The clients axis walks the devices in the order of their interconnect
+    (``mesh_utils.create_device_mesh``: on a v5e 2x2, chips 0, 1, 3, 2), so
+    that clients adjacent on the axis, ring neighbors, are one link apart."""
+    devs = jax.devices()[: n_devices or len(jax.devices())]
+    devs = mesh_utils.create_device_mesh((len(devs), 1, 1), devices=devs)
+    return compat.mesh_of(devs, (CLIENTS, FSDP, MODEL))
 
 
 def fake_mesh(num_clients: int = 2, fsdp: int = 2, model: int = 2) -> Mesh:

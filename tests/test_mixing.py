@@ -203,9 +203,11 @@ def test_mix_dense_contracts_clients_only_above_unroll_limit(
 
 @pytest.mark.parametrize("n", [2, 4, 8])
 def test_mix_dense_sharded_keeps_the_contraction(n):
-    """The form for a client axis split across devices is one einsum at
-    every n, equal to the unrolled form to f32 rounding."""
-    w = _doubly_stochastic("static_ring", n)
+    """For a client axis split across devices, a W that weighs every client
+    shift (the complete graph; the ring at n = 2) is one einsum at every n,
+    equal to the unrolled form to f32 rounding."""
+    w = np.asarray(topology.mixing_matrix("full" if n > 2 else "ring", n),
+                   np.float32)
     tree = _uniform_tree(n, 3)
     txt = jax.jit(lambda t: mixing.mix_dense_sharded(t, w)).lower(
         tree).as_text()
@@ -215,6 +217,26 @@ def test_mix_dense_sharded_keeps_the_contraction(n):
         np.testing.assert_allclose(a, b, rtol=1e-6, atol=1e-6)
 
 
+@pytest.mark.parametrize("topo,n,shifts", [
+    ("ring", 4, 3), ("ring", 8, 3), ("torus", 9, 7)])
+def test_mix_dense_sharded_rolls_the_weighed_shifts(topo, n, shifts):
+    """A static W with a zero circulant diagonal is the sum of the leaf
+    rolled by each client shift that W weighs: no contraction, one roll per
+    shift but the self one, equal to the unrolled form to f32 rounding; a
+    traced W keeps the einsum."""
+    w = np.asarray(topology.mixing_matrix(topo, n), np.float32)
+    assert len(mixing._client_shifts(w)) == shifts
+    tree = _uniform_tree(n, 3)
+    txt = jax.jit(lambda t: mixing.mix_dense_sharded(t, w)).lower(
+        tree).as_text()
+    assert "stablehlo.dot_general" not in txt
+    for a, b in zip(jax.tree.leaves(mixing.mix_dense_sharded(tree, w)),
+                    jax.tree.leaves(mixing.mix_dense(tree, w))):
+        np.testing.assert_allclose(a, b, rtol=1e-6, atol=1e-6)
+    traced = jax.jit(mixing.mix_dense_sharded).lower(tree, w).as_text()
+    assert traced.count("stablehlo.dot_general") == len(jax.tree.leaves(tree))
+
+
 _SHARDED_GOSSIP = """
 import os, re, json
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
@@ -222,25 +244,24 @@ import jax, jax.numpy as jnp, numpy as np
 from jax.sharding import NamedSharding, PartitionSpec as P
 from repro.core import mixing, topology
 mesh = jax.make_mesh((4,), ("clients",))
-w = topology.mixing_matrix("ring", 4)
 tree = {"a": jnp.ones((4, 256), jnp.float32),
         "b": jnp.ones((4, 8, 128), jnp.float32)}
 shard = NamedSharding(mesh, P("clients"))
 out = {}
-for sharded in (False, True):
-    mix = mixing.make_mixer("ring", "dense", w, clients_sharded=sharded)
-    txt = jax.jit(mix, in_shardings=shard, out_shardings=shard).lower(
-        tree).compile().as_text()
-    out[str(sharded)] = {
-        op: len(re.findall(r"= \\S+ " + op + r"(?:-start)?\\(", txt))
-        for op in ("all-gather", "collective-permute", "all-reduce")}
+for topo in ("full", "ring"):
+    w = topology.mixing_matrix(topo, 4)
+    for sharded in (False, True):
+        mix = mixing.make_mixer(topo, "dense", w, clients_sharded=sharded)
+        txt = jax.jit(mix, in_shardings=shard, out_shardings=shard).lower(
+            tree).compile().as_text()
+        out[topo + str(sharded)] = {
+            op: len(re.findall(r"= \\S+ " + op + r"(?:-start)?\\(", txt))
+            for op in ("all-gather", "collective-permute", "all-reduce")}
 print(json.dumps(out))
 """
 
 
-def test_sharded_clients_gossip_is_one_all_gather_per_leaf():
-    """On a 4-device clients axis the contraction lowers to one all-gather
-    per leaf; slicing the sharded axis would take n - 1 permutes."""
+def _sharded_gossip_counts() -> dict:
     import json
     import os
     import subprocess
@@ -255,7 +276,17 @@ def test_sharded_clients_gossip_is_one_all_gather_per_leaf():
     proc = subprocess.run([sys.executable, "-c", _SHARDED_GOSSIP], env=env,
                           capture_output=True, text=True, timeout=300)
     assert proc.returncode == 0, proc.stderr[-2000:]
-    counts = json.loads(proc.stdout.strip().splitlines()[-1])
-    assert counts["True"] == {"all-gather": 2, "collective-permute": 0,
-                              "all-reduce": 0}
-    assert counts["False"]["collective-permute"] > 2
+    return json.loads(proc.stdout.strip().splitlines()[-1])
+
+
+def test_sharded_clients_gossip_is_one_all_gather_per_leaf():
+    """On a 4-device clients axis, a W that weighs every client shift
+    lowers to one all-gather per leaf; slicing the sharded axis would take
+    n - 1 permutes.  The ring, which weighs two neighbors, takes one
+    permute per neighbor and leaf: 2 of the all-gather's 3 slices in."""
+    counts = _sharded_gossip_counts()
+    assert counts["fullTrue"] == {"all-gather": 2, "collective-permute": 0,
+                                  "all-reduce": 0}
+    assert counts["ringTrue"] == {"all-gather": 0, "collective-permute": 4,
+                                  "all-reduce": 0}
+    assert counts["fullFalse"]["collective-permute"] > 2

@@ -235,17 +235,20 @@ import os, re, json
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
 import jax, numpy as np
 from repro.configs import registry
-from repro.configs.base import InputShape, MeshConfig
+from repro.configs.base import AlgorithmConfig, InputShape, MeshConfig
 from repro.dist import compat, sharding as sh
 from repro.launch import steps
 cfg = registry.reduced(registry.get_model_config("granite-moe-1b-a400m-ep4"))
+# a W that weighs every client: its gossip is all-gathers, so that any
+# collective permute would come from the expert layer
+algo = AlgorithmConfig(num_clients=4, topology="full")
 mesh = compat.mesh_of(np.array(jax.devices()).reshape(4, 1, 1),
                       (sh.CLIENTS, sh.FSDP, sh.MODEL))
 mcfg = MeshConfig(num_clients=4, fsdp=1, model=1, param_mode="replicated")
 shape = InputShape(name="t", seq_len=32, global_batch=8, kind="train")
 with compat.use_mesh(mesh):
     jitted, state, batch, keys, _ = steps.build_train_round(cfg, shape, mesh,
-                                                            mcfg)
+                                                            mcfg, algo=algo)
     txt = jitted.lower(state, batch, keys).compile().as_text()
 print(json.dumps({
     "counters": sorted(state.counters),
@@ -257,7 +260,8 @@ print(json.dumps({
 def test_sharded_clients_round_moves_no_client_and_keeps_the_counters():
     """On a 4-device clients axis the grouped products stay batched: one
     per-client slice each would add collective permutes (42 in this round).
-    The gossip's all-gathers remain, and one all-reduce of the counters."""
+    The gossip's all-gathers remain (the complete graph's W, which the
+    sharded gossip contracts), and one all-reduce of the counters."""
     import json
     import subprocess
 
