@@ -212,15 +212,17 @@ class AlgorithmConfig:
     eta_sx: float = 1.0             # communication stepsize for x
     eta_sy: float = 1.0             # communication stepsize for y
     topology: str = "ring"          # ring | torus | full | exp | star
-    # Gossip implementation: "dense" (faithful W-einsum), "ring" (ppermute),
-    # "fused_dense"/"fused_ring" (pack Delta+params into one collective per
-    # leaf), "pallas_packed" (ravel the whole state into one (n, D) buffer and
-    # run the fused gossip/correction/mixing epilogue in a single pass —
-    # see repro.core.packing + repro.kernels.gossip), or "sparse_packed"
-    # (same fused packed epilogue, but W is padded-CSR neighbor lists and
-    # gossip is a neighbor-row gather — O(n·max_deg·D), never an (n, n)
-    # array; the scaling path for num_clients ≳ 512, see
-    # repro.core.sparse_topology + repro.kernels.neighbor_gossip).
+    # Gossip implementation (mixing.MIXING_IMPLS): "dense" (faithful W
+    # gossip per leaf, lowered by W and the mesh: see mixing.mix_dense and
+    # mix_dense_sharded), "pallas_packed" (ravel the whole state into one
+    # (n, D) buffer and run the fused gossip/correction/mixing epilogue in a
+    # single pass — see repro.core.packing + repro.kernels.gossip),
+    # "sparse_packed" (same fused packed epilogue, but W is padded-CSR
+    # neighbor lists and gossip is a neighbor-row gather — O(n·max_deg·D),
+    # never an (n, n) array; the scaling path for num_clients ≳ 512, see
+    # repro.core.sparse_topology + repro.kernels.neighbor_gossip),
+    # "fused_round" (the whole round in one kernel, affine problems only), or
+    # a robust aggregation (mixing.ROBUST_IMPLS).
     mixing_impl: str = "dense"
     # Backend for the pallas_packed/sparse_packed epilogue: "auto" (Pallas
     # kernel on TPU, packed-xla oracle elsewhere), "pallas", "interpret",

@@ -24,7 +24,6 @@ from repro.core.mixing import (  # noqa: F401
     make_mixer,
     mix_dense,
     mix_packed,
-    mix_ring,
     robust_mix_dense,
     robust_mix_packed,
     robust_mix_sparse,

@@ -255,7 +255,7 @@ def make_round_step(
     ``fused_round``, whose kernel runs under none).
 
     ``clients_sharded=True`` says the state's client axis is split across a
-    mesh's devices: the dense gossip of a static or cycled W then lowers as
+    mesh's devices: the dense gossip of this round's W then lowers as
     ``mixing.mix_dense_sharded`` (collective-permutes over the client shifts
     a static ring-like W weighs, else one all-gather a leaf).  On the
     ``dense`` lowering the parameter gossip ``Wx, Wy`` is then formed from
@@ -273,12 +273,6 @@ def make_round_step(
     if cfg.mixing_impl not in mixing_lib.MIXING_IMPLS:
         raise ValueError(
             f"unknown mixing_impl {cfg.mixing_impl!r}: {mixing_lib.MIXING_IMPLS}")
-    if cfg.topology_cycle and cfg.mixing_impl.endswith("ring"):
-        # the time-varying path lowers gossip densely per round; a
-        # neighbor-only ring exchange cannot realize arbitrary cycle members
-        raise ValueError(
-            f"mixing_impl={cfg.mixing_impl!r} is not supported with "
-            "topology_cycle; use 'dense', 'fused_dense', or 'pallas_packed'")
     if traced_w and cfg.topology_cycle:
         raise ValueError(
             "traced_w supplies W per round; topology_cycle would fight it — "
@@ -315,29 +309,19 @@ def make_round_step(
         raise ValueError(
             f"mixing_impl={cfg.mixing_impl!r} is not supported with "
             "topology_cycle; use traced_w with a per-round sampler instead")
-    dynamic_w = traced_w or participation
     packed = cfg.mixing_impl == "pallas_packed"
     pack_gd = (None if cfg.gossip_dtype in (None, "float32")
                else jnp.dtype(cfg.gossip_dtype))
-    dense_mix = (mixing_lib.mix_dense_sharded if clients_sharded
-                 else mixing_lib.mix_dense)
+    # the per-leaf gossip, and the packed path's gossip without tracking
+    mixer = mixing_lib.make_mixer("dense", cfg.gossip_dtype,
+                                  clients_sharded=clients_sharded)
     early_gossip = clients_sharded and cfg.mixing_impl == "dense"
-    if dynamic_w and not packed and not sparse and not robust and not fused:
-        # validates the impl (ring-style neighbor exchanges cannot realize a
-        # per-round arbitrary W) and gives us mix(tree, w) with w traced
-        traced_mix = mixing_lib.make_traced_mixer(
-            cfg.mixing_impl, cfg.gossip_dtype)
     if cfg.topology_cycle:
         # time-varying gossip: W selected per round from the cycle
         ws = jnp.stack([
             jnp.asarray(topo_lib.mixing_matrix(t, cfg.num_clients), jnp.float32)
             for t in cfg.topology_cycle])
-        gd = pack_gd
         get_w = lambda round_idx: ws[round_idx % len(cfg.topology_cycle)]
-
-        def make_mix(round_idx):
-            w_t = get_w(round_idx)
-            return lambda tree: dense_mix(tree, w_t, gossip_dtype=gd)
     else:
         if w is None and not traced_w:
             w = (sparse_lib.sparse_mixing_matrix(cfg.topology, cfg.num_clients)
@@ -348,15 +332,9 @@ def make_round_step(
                      else (w if isinstance(w, sparse_lib.SparseTopology)
                            else sparse_lib.from_dense(np.asarray(w))))
         else:
-            w_arr = None if w is None else jnp.asarray(w, jnp.float32)
+            # a host array: the sharded gossip reads W's client shifts
+            w_arr = None if w is None else np.asarray(w, np.float32)
         get_w = lambda round_idx: w_arr
-        if packed or sparse_w or robust or dynamic_w or fused:
-            make_mix = None  # W is consumed directly, per round
-        else:
-            static_mix = mixing_lib.make_mixer(
-                cfg.topology, cfg.mixing_impl, w, cfg.gossip_dtype,
-                clients_sharded=clients_sharded)
-            make_mix = lambda round_idx: static_mix
     gossip_backend = kernel_ops.resolve_gossip_backend(cfg.gossip_backend)
     algo = cfg.algorithm
     track = algo in ("kgt_minimax", "gt_gda")
@@ -575,10 +553,8 @@ def make_round_step(
                 # gossip of the already-stepped parameters, W(θ + η_s·Δ) —
                 # don't move (n, D) correction buffers through the kernel
                 # just to multiply them by zero
-                xb = dense_mix(packing.pack(state.x, spec_x) + eta_sx * dxb,
-                               w_t, gossip_dtype=pack_gd)
-                yb = dense_mix(packing.pack(state.y, spec_y) + eta_sy * dyb,
-                               w_t, gossip_dtype=pack_gd)
+                xb = mixer(packing.pack(state.x, spec_x) + eta_sx * dxb, w_t)
+                yb = mixer(packing.pack(state.y, spec_y) + eta_sy * dyb, w_t)
                 return KGTState(
                     x=packing.unpack(xb, spec_x), y=packing.unpack(yb, spec_y),
                     cx=state.cx, cy=state.cy, round=state.round + 1,
@@ -607,27 +583,9 @@ def make_round_step(
                 ef_x=efx, ef_y=efy)
 
         # Algorithm 1 communicates two quantities per variable per round:
-        # Δ (lines 7-8) and the parameters (lines 10-11).  The faithful
-        # implementation issues two gossips; the "fused_*" variants PACK both
-        # into one collective per leaf (same bytes, half the collective
-        # launches — beyond-paper, bit-identical).
-        if cfg.mixing_impl.startswith("fused"):
-            def pack_mix(delta, base):
-                pairs = jax.tree.map(
-                    lambda d, b: jnp.stack([d.astype(jnp.float32),
-                                            b.astype(jnp.float32)], axis=1),
-                    delta, base)
-                mixed = mix(pairs)
-                md = jax.tree.map(lambda p: p[:, 0], mixed)
-                mb = jax.tree.map(lambda p: p[:, 1], mixed)
-                return md, mb
-
-            mdx, mx = pack_mix(dx, state.x)
-            mdy, my = pack_mix(dy, state.y)
-        else:
-            mdx, mdy = mix(dx), mix(dy)
-            mx, my = mixed if mixed is not None else (mix(state.x),
-                                                      mix(state.y))
+        # Δ (lines 7-8) and the parameters (lines 10-11), two gossips.
+        mdx, mdy = mix(dx), mix(dy)
+        mx, my = mixed if mixed is not None else (mix(state.x), mix(state.y))
 
         if track:
             # c^x += (Δx − WΔx)/(K η_cx) ;  c^y −= (Δy − WΔy)/(K η_cy)
@@ -646,16 +604,12 @@ def make_round_step(
     def _round(state: KGTState, batches, keys,
                eta_cx, eta_cy, eta_sx, eta_sy, corr_x, corr_y,
                w_t=None, mask=None, adv=None) -> KGTState:
-        if packed or sparse_w or robust or dynamic_w or fused:
-            if w_t is None:
-                w_t = get_w(state.round)
-            if mask is not None:
-                w_t = (sparse_lib.sparse_masked_w(w_t, mask) if sparse_w
-                       else stoch_lib.masked_w(w_t, mask))
-            mix = (None if packed or sparse_w or robust or fused
-                   else (lambda tree: traced_mix(tree, w_t)))
-        else:
-            mix = make_mix(state.round)
+        if w_t is None:
+            w_t = get_w(state.round)
+        if mask is not None:
+            w_t = (sparse_lib.sparse_masked_w(w_t, mask) if sparse_w
+                   else stoch_lib.masked_w(w_t, mask))
+        mix = lambda tree: mixer(tree, w_t)
 
         if fused:
             # the local steps live inside the kernel — skip the scan below

@@ -1,19 +1,16 @@
 """Gossip mixing operators over pytrees with a leading clients dim.
 
-Two lowering strategies for ``Σ_j w_ij T_j``:
-
-* ``dense`` — the full (n, n) mixing matrix W.  Faithful to the paper
-  (arbitrary topology).  Up to ``UNROLL_MAX_CLIENTS`` clients on one device
-  it is a weighted sum of client slices, above that an einsum.  Across a
-  mesh's clients axis (``mix_dense_sharded``) a static W that weighs only
-  some client shifts is a weighted sum of the rolled tensor, one
-  collective-permute per weighed shift, and any other W the einsum, whose
-  contraction over the sharded clients dim lowers to an all-gather of the
-  full tensor, (n-1)·|T| bytes in per client.
-* ``ring`` — neighbor-only exchange expressed as ``jnp.roll`` along the
-  clients dim, which GSPMD lowers to collective-permutes over the clients
-  mesh axis (2·|T| bytes in per client).  Valid for the ring topology (and
-  any circulant W via repeated shifts).
+The per-leaf gossip ``Σ_j w_ij T_j`` is ``dense``: the full (n, n) mixing
+matrix W, faithful to the paper (arbitrary topology), whose lowering
+follows from W and from where the clients live.  Up to ``UNROLL_MAX_CLIENTS``
+clients on one device it is a weighted sum of client slices, above that an
+einsum.  Across a mesh's clients axis (``mix_dense_sharded``) a static W
+that weighs only some client shifts (a ring, a torus) is a weighted sum of
+the rolled tensor, one collective-permute per weighed shift, and any other
+W the einsum, whose contraction over the sharded clients dim lowers to an
+all-gather of the full tensor, (n-1)·|T| bytes in per client.
+``mix_packed`` and ``mix_sparse`` ravel the whole tree into one (n, D)
+buffer first; the latter gathers neighbor rows instead of forming W.
 
 ``gossip_dtype`` optionally downcasts the *communicated* values (beyond-paper
 optimization; tracking state stays f32).
@@ -131,34 +128,6 @@ def mix_dense_sharded(tree: Any, w, gossip_dtype=None) -> Any:
                             tree)
     w = jnp.asarray(w, jnp.float32)
     return jax.tree.map(lambda x: _dense_one(x, w, gossip_dtype, False), tree)
-
-
-def mix_ring(tree: Any, w_self: float, w_nbr: float, gossip_dtype=None) -> Any:
-    """Ring mixing: w_self * x_i + w_nbr * (x_{i-1} + x_{i+1}).
-
-    jnp.roll along the clients-sharded dim lowers to collective-permute.
-    """
-
-    def one(x):
-        orig = x.dtype
-        xc = x.astype(gossip_dtype) if gossip_dtype is not None else x
-        n = x.shape[0]
-        if n == 1:
-            return x
-        if n == 2:
-            # single neighbor: w_nbr is already the full off-diagonal weight
-            nbr = jnp.roll(xc, 1, axis=0)
-            mixed = w_self * xc.astype(jnp.float32) + w_nbr * nbr.astype(jnp.float32)
-        else:
-            up = jnp.roll(xc, 1, axis=0)
-            dn = jnp.roll(xc, -1, axis=0)
-            mixed = (
-                w_self * xc.astype(jnp.float32)
-                + w_nbr * (up.astype(jnp.float32) + dn.astype(jnp.float32))
-            )
-        return mixed.astype(orig)
-
-    return jax.tree.map(one, tree)
 
 
 def mix_packed(tree: Any, w, gossip_dtype=None) -> Any:
@@ -293,42 +262,33 @@ def robust_mix_packed(tree: Any, w, *, rule: str, trim: int = 1,
     return packing.unpack(mixed, spec)
 
 
-MIXING_IMPLS = ("dense", "ring", "fused_dense", "fused_ring", "pallas_packed",
-                "sparse_packed", "fused_round") + ROBUST_IMPLS
+MIXING_IMPLS = ("dense", "pallas_packed", "sparse_packed",
+                "fused_round") + ROBUST_IMPLS
 
 
-def make_mixer(topology: str, impl: str, w: np.ndarray,
-               gossip_dtype: str = "float32", *, trim: int = 1,
+def make_mixer(impl: str, gossip_dtype: str = "float32", *, trim: int = 1,
                clients_sharded: bool = False):
-    """Returns mix(tree) -> tree for the configured implementation.
-    ``clients_sharded``: the leaves' client axis is split across devices
-    (the dense gossip then lowers as :func:`mix_dense_sharded`)."""
+    """Returns ``mix(tree, w) -> tree`` for the configured implementation.
+
+    ``w`` is this round's W, whatever made it: a constant array for a
+    static topology, a cycle's member or a per-round sampled or
+    participation-masked matrix traced in the surrounding jit, or, for the
+    ``sparse_*`` forms, a :class:`sparse_topology.SparseTopology`.  The
+    lowering follows from W and from ``clients_sharded`` (the leaves'
+    client axis is split across devices: the dense gossip then lowers as
+    :func:`mix_dense_sharded`)."""
     if impl not in MIXING_IMPLS:
         raise ValueError(f"unknown mixing_impl {impl!r}: {MIXING_IMPLS}")
     gd = None if gossip_dtype in (None, "float32") else jnp.dtype(gossip_dtype)
     if impl in ROBUST_IMPLS:
+        # W is a support here: robust_mix_packed dispatches on its form
         rule = robust_rule(impl)
-        if impl.startswith("sparse_"):
-            w = (w if isinstance(w, sparse_lib.SparseTopology)
-                 else sparse_lib.from_dense(np.asarray(w)))
-        return lambda tree: robust_mix_packed(tree, w, rule=rule, trim=trim,
-                                              gossip_dtype=gd)
-    if impl.endswith("ring"):
-        if topology != "ring":
-            raise ValueError(
-                f"mixing_impl={impl!r} is a neighbor-only exchange, valid "
-                f"only for topology='ring' (got {topology!r}); use 'dense', "
-                f"'fused_dense', or 'pallas_packed' for arbitrary W")
-        n = w.shape[0]
-        w_self = float(w[0, 0])
-        w_nbr = float(w[0, 1 % n]) if n > 1 else 0.0
-        return lambda tree: mix_ring(tree, w_self, w_nbr, gossip_dtype=gd)
+        return lambda tree, w: robust_mix_packed(tree, w, rule=rule,
+                                                 trim=trim, gossip_dtype=gd)
     if impl == "sparse_packed":
-        sp = (w if isinstance(w, sparse_lib.SparseTopology)
-              else sparse_lib.from_dense(np.asarray(w)))
-        return lambda tree: mix_sparse(tree, sp, gossip_dtype=gd)
+        return lambda tree, sp: mix_sparse(tree, sp, gossip_dtype=gd)
     if impl == "pallas_packed":
-        return lambda tree: mix_packed(tree, w, gossip_dtype=gd)
+        return lambda tree, w: mix_packed(tree, w, gossip_dtype=gd)
     if impl == "fused_round":
         # whole-round lowering: there is no standalone mix step — the local
         # steps, gossip, and correction all live inside one kernel call,
@@ -338,47 +298,8 @@ def make_mixer(topology: str, impl: str, w: np.ndarray,
             "mixing_impl='fused_round' has no standalone mixer; it is "
             "routed whole-round by kgt_minimax.make_round_step")
     # looked up when called, so that a replacement of either takes effect
-    return lambda tree: (mix_dense_sharded if clients_sharded
-                         else mix_dense)(tree, w, gossip_dtype=gd)
-
-
-def make_traced_mixer(impl: str, gossip_dtype: str = "float32", *,
-                      trim: int = 1):
-    """Traced-W analogue of :func:`make_mixer`: returns ``mix(tree, w)``
-    where W is an operand of the surrounding jit — a per-round *sampled*
-    matrix (``repro.core.stochastic_topology``) or a participation-masked
-    one — instead of a constant baked into the program.
-
-    The neighbor-only ring impls hard-code the exchange pattern and cannot
-    realize an arbitrary per-round W, so they raise; ``dense``/``fused_dense``
-    lower to the dense gossip and ``pallas_packed`` to the packed tree
-    gossip, both of which already take W as a runtime value.
-    """
-    if impl not in MIXING_IMPLS:
-        raise ValueError(f"unknown mixing_impl {impl!r}: {MIXING_IMPLS}")
-    if impl.endswith("ring"):
-        raise ValueError(
-            f"mixing_impl={impl!r} is a neighbor-only exchange and cannot "
-            "realize a traced (per-round random or participation-masked) W; "
-            "use 'dense', 'fused_dense', or 'pallas_packed'")
-    gd = None if gossip_dtype in (None, "float32") else jnp.dtype(gossip_dtype)
-    if impl in ROBUST_IMPLS:
-        # the traced operand is W-as-support: a SparseTopology pytree for
-        # the sparse_* forms, an (n, n) array otherwise — robust_mix_packed
-        # dispatches on it
-        rule = robust_rule(impl)
-        return lambda tree, w: robust_mix_packed(tree, w, rule=rule,
-                                                 trim=trim, gossip_dtype=gd)
-    if impl == "sparse_packed":
-        # here the traced operand is a SparseTopology pytree, not an array
-        return lambda tree, sp: mix_sparse(tree, sp, gossip_dtype=gd)
-    if impl == "pallas_packed":
-        return lambda tree, w: mix_packed(tree, w, gossip_dtype=gd)
-    if impl == "fused_round":
-        raise ValueError(
-            "mixing_impl='fused_round' has no standalone mixer; it is "
-            "routed whole-round by kgt_minimax.make_round_step")
-    return lambda tree, w: mix_dense(tree, w, gossip_dtype=gd)
+    return lambda tree, w: (mix_dense_sharded if clients_sharded
+                            else mix_dense)(tree, w, gossip_dtype=gd)
 
 
 def consensus_error(tree: Any) -> jnp.ndarray:

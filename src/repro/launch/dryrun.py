@@ -52,31 +52,12 @@ VARIANTS = {
     "baseline": dict(),
     # label-only variants: same config, used to snapshot code-level changes
     "grouped_gqa": dict(),
-    "final": dict(algo=dict(mixing_impl="fused_ring", gossip_dtype="bfloat16")),
     "bf16_gossip": dict(algo=dict(gossip_dtype="bfloat16")),
-    "ring": dict(algo=dict(mixing_impl="ring")),
-    "fused_ring_bf16": dict(
-        algo=dict(mixing_impl="fused_ring", gossip_dtype="bfloat16")),
     "replicated": dict(mesh=dict(param_mode="replicated")),
-    "replicated_fused": dict(
-        algo=dict(mixing_impl="fused_ring", gossip_dtype="bfloat16"),
-        mesh=dict(param_mode="replicated")),
     "expert_parallel": dict(mesh=dict(moe_expert_parallel=True)),
-    "ep_fused": dict(
-        algo=dict(mixing_impl="fused_ring", gossip_dtype="bfloat16"),
-        mesh=dict(moe_expert_parallel=True)),
     "no_remat": dict(mesh=dict(remat=False)),
     "attn_heads": dict(mesh=dict(attn_heads_sharding=True)),
     "batch_residual": dict(mesh=dict(residual_mode="batch")),
-    "ep_batch_residual": dict(
-        algo=dict(mixing_impl="fused_ring", gossip_dtype="bfloat16"),
-        mesh=dict(moe_expert_parallel=True, residual_mode="batch")),
-    "attn_heads_fused": dict(
-        algo=dict(mixing_impl="fused_ring", gossip_dtype="bfloat16"),
-        mesh=dict(attn_heads_sharding=True)),
-    # recommended per-arch optimized config: grouped-GQA is code-level (always
-    # on); MoE additionally wants expert-parallel.  attn_heads/fused_ring were
-    # measured regressions on several archs (see EXPERIMENTS.md §Perf).
     "best": dict(mesh=dict(moe_expert_parallel=True)),
     "moe_sorted": dict(moe=dict(dispatch="sorted")),
     "moe_sorted_ep": dict(moe=dict(dispatch="sorted"),

@@ -15,19 +15,15 @@ elements per client:
 * with gradient tracking (``kgt_minimax``/``gt_gda``) on a packed or robust
   lowering — **two quantities**: the round delta Δ (lines 7–8) and the
   parameters θ (lines 10–11);
-* the per-leaf lowerings (``dense``/``ring``/``fused_*``) always move both
-  (the fused_* variants halve the collective *launches*, not the bytes);
+* the per-leaf lowering (``dense``) always moves both;
 * without tracking on a packed lowering — **one quantity**: the pre-stepped
   ``θ + η_s·Δ``.
 
 How many values cross the wire per gossip is the *links* count ``L``
 (receives summed over clients):
 
-* dense-family lowerings (``dense``/``fused_dense``/``pallas_packed``/
-  ``fused_round``/dense robust) all-gather the full client axis:
-  ``L = n·(n−1)``;
-* ``ring``/``fused_ring`` exchange with the two ring neighbors:
-  ``L = 2n`` (``n`` for n=2, 0 for n=1);
+* dense-family lowerings (``dense``/``pallas_packed``/``fused_round``/
+  dense robust) all-gather the full client axis: ``L = n·(n−1)``;
 * ``sparse_*`` lowerings gather neighbor rows through the padded-CSR
   support: ``L = Σ_i deg_i`` (the directed edge count of the topology).
 
@@ -56,12 +52,11 @@ import numpy as np
 LEDGER_VERSION = 1
 
 # lowerings whose gossip collective touches the full client axis
-_DENSE_FAMILY = ("dense", "fused_dense", "pallas_packed", "fused_round",
-                 "coord_median", "trimmed_mean")
-_RING_FAMILY = ("ring", "fused_ring")
+_DENSE_FAMILY = ("dense", "pallas_packed", "fused_round", "coord_median",
+                 "trimmed_mean")
 _SPARSE_FAMILY = ("sparse_packed", "sparse_coord_median",
                   "sparse_trimmed_mean")
-_PER_LEAF = ("dense", "ring", "fused_dense", "fused_ring")
+_PER_LEAF = ("dense",)
 _TRACKING_ALGOS = ("kgt_minimax", "gt_gda")
 
 
@@ -87,10 +82,6 @@ def links_per_gossip(mixing_impl: str, n: int, *, topology: str = "ring",
     """Values received per gossip, summed over clients, for the lowering."""
     if mixing_impl in _DENSE_FAMILY:
         return n * (n - 1)
-    if mixing_impl in _RING_FAMILY:
-        if n <= 1:
-            return 0
-        return n if n == 2 else 2 * n
     if mixing_impl in _SPARSE_FAMILY:
         if edges is None:
             from repro.core import sparse_topology as sparse_lib
@@ -111,16 +102,14 @@ def _collectives(mixing_impl: str, track: bool,
                  leaves: Sequence[int]) -> int:
     """Collective launches per round.
 
-    Per-leaf lowerings issue one collective per leaf per gossiped quantity
-    (``fused_*`` pack Δ and θ into one launch); the packed lowerings fuse
-    the whole per-variable epilogue into one launch each; ``fused_round``
-    runs the entire round — both variables — as a single kernel pass.
+    The per-leaf lowering issues one collective per leaf per gossiped
+    quantity; the packed lowerings fuse the whole per-variable epilogue
+    into one launch each; ``fused_round`` runs the entire round — both
+    variables — as a single kernel pass.
     """
     num_vars = len(leaves)
-    if mixing_impl in ("dense", "ring"):
+    if mixing_impl in _PER_LEAF:
         return 2 * sum(leaves)
-    if mixing_impl in ("fused_dense", "fused_ring"):
-        return sum(leaves)
     if mixing_impl == "fused_round":
         return 1
     if mixing_impl in ("pallas_packed", "sparse_packed"):

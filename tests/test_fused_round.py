@@ -326,7 +326,7 @@ def test_fused_round_rejects_byzantine():
 
 def test_fused_round_has_no_standalone_mixer():
     with pytest.raises(ValueError, match="fused_round"):
-        mixing.make_mixer("full", "fused_round", np.eye(4, dtype=np.float32))
+        mixing.make_mixer("fused_round")
 
 
 def test_validate_method():

@@ -18,7 +18,8 @@ from repro.core import (
 
 
 def _setup(n=8, K=4, sigma=0.0, heterogeneity=1.0, topology="ring", algo="kgt_minimax",
-           eta_cx=0.01, eta_cy=0.1, eta_s=0.5, mixing_impl="dense"):
+           eta_cx=0.01, eta_cy=0.1, eta_s=0.5, mixing_impl="dense",
+           clients_sharded=False):
     key = jax.random.PRNGKey(0)
     data = make_quadratic_data(key, n, dx=10, dy=5, heterogeneity=heterogeneity)
     prob = quadratic_problem(data, sigma=sigma)
@@ -29,7 +30,8 @@ def _setup(n=8, K=4, sigma=0.0, heterogeneity=1.0, topology="ring", algo="kgt_mi
     kb = jax.tree.map(lambda v: jnp.broadcast_to(v[None], (K, *v.shape)), client_batch)
     st = init_state(prob, cfg, key, init_batch=client_batch,
                     init_keys=jax.random.split(key, n))
-    step = jax.jit(make_round_step(prob, cfg))
+    step = jax.jit(make_round_step(prob, cfg,
+                                   clients_sharded=clients_sharded))
     return prob, cfg, st, step, kb
 
 
@@ -113,29 +115,28 @@ def test_baselines_run_and_stay_finite(algo):
         assert bool(jnp.isfinite(leaf).all())
 
 
-def test_ring_impl_matches_dense_trajectory():
-    """The ppermute-style (roll) gossip is numerically the same algorithm."""
+@pytest.mark.parametrize("topo", ["ring", "exp", "torus", "full"])
+def test_shift_lowered_round_matches_dense(topo):
+    """With the clients axis split across devices, the dense gossip of a W
+    that leaves some client shift unweighed (ring, exp, torus) is rolls
+    over the shifts it weighs, and of the complete graph one contraction,
+    and the parameter gossip is formed at the round's start.  Run on one
+    device, that round is the same algorithm as the unsharded one."""
+    from repro.core import mixing
+
+    n = 9 if topo == "torus" else 8
+    shifts = len(mixing._client_shifts(mixing_matrix(topo, n)))
+    assert (shifts == n) == (topo == "full")
     outs = []
-    for impl in ("dense", "ring"):
-        prob, cfg, st, step, kb = _setup(sigma=0.0, mixing_impl=impl)
-        st = _run(st, step, kb, 4, 8, 30)
-        outs.append(np.asarray(mean_over_clients(st.x)))
-    np.testing.assert_allclose(outs[0], outs[1], rtol=1e-4, atol=1e-5)
-
-
-def test_fused_pack_is_bit_identical_to_two_gossips():
-    """Regression for the claim in kgt_minimax.py: the fused_* variants pack
-    both gossips into one collective per leaf with *bit-identical* results —
-    stacking (Δ, base) along a new axis must not change the contraction."""
-    outs = {}
-    for impl in ("dense", "fused_dense"):
-        prob, cfg, st, step, kb = _setup(sigma=0.3, mixing_impl=impl)
-        outs[impl] = _run(st, step, kb, 4, 8, 10)
+    for sharded in (False, True):
+        prob, cfg, st, step, kb = _setup(n=n, sigma=0.3, topology=topo,
+                                         clients_sharded=sharded)
+        outs.append(_run(st, step, kb, 4, n, 30))
     for name in ("x", "y", "cx", "cy"):
-        for a, b in zip(jax.tree.leaves(getattr(outs["dense"], name)),
-                        jax.tree.leaves(getattr(outs["fused_dense"], name))):
-            np.testing.assert_array_equal(np.asarray(a), np.asarray(b),
-                                          err_msg=name)
+        for a, b in zip(jax.tree.leaves(getattr(outs[0], name)),
+                        jax.tree.leaves(getattr(outs[1], name))):
+            np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                       rtol=1e-5, atol=1e-5, err_msg=name)
 
 
 def doubly_stochastic_w(n: int, seed: int) -> np.ndarray:
@@ -299,8 +300,8 @@ def test_round_step_extras_arity_validated():
 
 
 def test_make_round_step_validates_mixing_impl():
-    """The impl/topology pairing is validated on BOTH branches — including
-    topology_cycle, which lowers gossip densely and ignores make_mixer."""
+    """mixing_impl is validated on every branch — including topology_cycle
+    and the traced-W and participation paths."""
     key = jax.random.PRNGKey(0)
     data = make_quadratic_data(key, 4, dx=4, dy=2)
     prob = quadratic_problem(data, sigma=0.0)
@@ -313,15 +314,15 @@ def test_make_round_step_validates_mixing_impl():
         AlgorithmConfig(num_clients=4, mixing_impl="fused_ring",
                         topology="exp"),
     ):
-        with pytest.raises(ValueError):
+        with pytest.raises(ValueError, match="unknown mixing_impl"):
             make_round_step(prob, cfg)
-    # the churn paths lower gossip densely: ring impls can't realize a
-    # traced/masked W, and traced_w fights a topology_cycle
-    with pytest.raises(ValueError, match="neighbor-only"):
+    # the retired names are refused on the churn paths too, and traced_w
+    # fights a topology_cycle
+    with pytest.raises(ValueError, match="unknown mixing_impl"):
         make_round_step(prob, AlgorithmConfig(num_clients=4,
                                               mixing_impl="ring"),
                         traced_w=True)
-    with pytest.raises(ValueError, match="neighbor-only"):
+    with pytest.raises(ValueError, match="unknown mixing_impl"):
         make_round_step(prob, AlgorithmConfig(num_clients=4,
                                               mixing_impl="fused_ring"),
                         participation=True)

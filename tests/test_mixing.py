@@ -4,6 +4,7 @@ import numpy as np
 import pytest
 
 from repro.core import mixing, topology
+from repro.core import sparse_topology as sparse_lib
 
 
 def _tree(n, key):
@@ -14,12 +15,22 @@ def _tree(n, key):
 
 @pytest.mark.parametrize("n", [2, 3, 4, 8])
 def test_ring_matches_dense(n):
+    """The ring's gossip as the sum of the rolls by each client shift W
+    weighs (self and two neighbors; one at n = 2) equals the dense gossip.
+    From n = 4 on the ring leaves a shift unweighed and that is the form
+    ``mix_dense_sharded`` takes, on one device as across a mesh."""
     w = topology.mixing_matrix("ring", n)
     tree = _tree(n, jax.random.PRNGKey(0))
+    shifts = mixing._client_shifts(w)
+    assert len(shifts) == min(n, 3)
     dense = mixing.mix_dense(tree, w)
-    ring = mixing.mix_ring(tree, float(w[0, 0]), float(w[0, 1 % n]))
-    for d, r in zip(jax.tree.leaves(dense), jax.tree.leaves(ring)):
+    rolled = jax.tree.map(lambda x: mixing._shifted_one(x, shifts, None), tree)
+    for d, r in zip(jax.tree.leaves(dense), jax.tree.leaves(rolled)):
         np.testing.assert_allclose(d, r, rtol=1e-5, atol=1e-6)
+    if n > 3:
+        for r, s in zip(jax.tree.leaves(rolled),
+                        jax.tree.leaves(mixing.mix_dense_sharded(tree, w))):
+            np.testing.assert_array_equal(r, s)
 
 
 @pytest.mark.parametrize("name", ["ring", "full", "exp"])
@@ -53,30 +64,44 @@ def test_bf16_gossip_close_to_f32():
 
 
 def test_make_mixer_dispatch():
+    """Every linear mixer is W @ tree for this round's W, given in the form
+    its lowering reads: an array, or neighbor lists for ``sparse_packed``."""
     w = topology.mixing_matrix("ring", 4)
     tree = _tree(4, jax.random.PRNGKey(4))
-    for impl in ("dense", "ring", "fused_ring", "pallas_packed"):
-        out = mixing.make_mixer("ring", impl, w)(tree)
-        np.testing.assert_allclose(
-            jax.tree.leaves(out)[0], jax.tree.leaves(mixing.mix_dense(tree, w))[0],
-            rtol=1e-5, atol=1e-6)
-
-
-@pytest.mark.parametrize("impl", ["ring", "fused_ring"])
-@pytest.mark.parametrize("topo", ["full", "exp", "torus", "star"])
-def test_make_mixer_rejects_ring_impl_on_non_ring_topology(impl, topo):
-    """Previously this silently fell back to dense — wrong impl, right
-    numbers — masking a misconfiguration.  Now it raises."""
-    n = 4
-    w = topology.mixing_matrix(topo, n)
-    with pytest.raises(ValueError, match="ring"):
-        mixing.make_mixer(topo, impl, w)
+    ref = jax.tree.leaves(mixing.mix_dense(tree, w))
+    for impl, sharded, wi in (
+            ("dense", False, w), ("dense", True, w), ("pallas_packed", False, w),
+            ("sparse_packed", False, sparse_lib.from_dense(np.asarray(w)))):
+        out = mixing.make_mixer(impl, clients_sharded=sharded)(tree, wi)
+        for a, b in zip(jax.tree.leaves(out), ref):
+            np.testing.assert_allclose(a, b, rtol=1e-5, atol=1e-6)
 
 
 def test_make_mixer_rejects_unknown_impl():
-    w = topology.mixing_matrix("ring", 4)
-    with pytest.raises(ValueError, match="unknown mixing_impl"):
-        mixing.make_mixer("ring", "bogus", w)
+    for impl in ("bogus", "ring", "fused_dense", "fused_ring"):
+        with pytest.raises(ValueError, match="unknown mixing_impl"):
+            mixing.make_mixer(impl)
+
+
+MIXER_IMPLS = ["dense", "pallas_packed", "sparse_packed", "coord_median",
+               "trimmed_mean", "sparse_trimmed_mean"]
+
+
+@pytest.mark.parametrize("impl", MIXER_IMPLS)
+def test_mixer_closed_over_and_traced_w_agree(impl):
+    """One factory serves every source of W: the mixer gives the same
+    gossip whether this round's W is a constant of the jitted program (a
+    static topology) or one of its arguments (a sampled or masked W)."""
+    n = 8
+    w = topology.mixing_matrix("exp", n)
+    if impl.startswith("sparse_"):
+        w = sparse_lib.from_dense(np.asarray(w))
+    tree = _tree(n, jax.random.PRNGKey(6))
+    mix = mixing.make_mixer(impl)
+    closed = jax.jit(lambda t: mix(t, w))(tree)
+    traced = jax.jit(mix)(tree, w)
+    for a, b in zip(jax.tree.leaves(closed), jax.tree.leaves(traced)):
+        np.testing.assert_array_equal(a, b)
 
 
 def test_mix_packed_matches_per_leaf_dense():
@@ -251,9 +276,9 @@ out = {}
 for topo in ("full", "ring"):
     w = topology.mixing_matrix(topo, 4)
     for sharded in (False, True):
-        mix = mixing.make_mixer(topo, "dense", w, clients_sharded=sharded)
-        txt = jax.jit(mix, in_shardings=shard, out_shardings=shard).lower(
-            tree).compile().as_text()
+        mix = mixing.make_mixer("dense", clients_sharded=sharded)
+        txt = jax.jit(lambda t: mix(t, w), in_shardings=shard,
+                      out_shardings=shard).lower(tree).compile().as_text()
         out[topo + str(sharded)] = {
             op: len(re.findall(r"= \\S+ " + op + r"(?:-start)?\\(", txt))
             for op in ("all-gather", "collective-permute", "all-reduce")}
